@@ -7,7 +7,7 @@ read zero TRACE_SWITCHES env vars; jit-reachable code is free of host
 effects; lane-cache arena views are never mutated in place outside
 their owner. Each convention is the fossil of a real fixed bug (stale
 sharded programs across switch flips, uncertified static flips,
-blocking tunnel claims from cache lookups) — this package turns them
+backend initialization from cache lookups) — this package turns them
 into CI-gated rules. v2 extends the catalog to the concurrent host
 substrate: lock discipline (LCK — guarded-by inference, lock-order
 cycles, blocking under a lock, commit-step reentrancy), durable

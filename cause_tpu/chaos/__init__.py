@@ -2,7 +2,7 @@
 
 Network-accelerated replication systems treat fault injection as table
 stakes (arXiv:1605.05619): you do not find out how a fleet degrades by
-waiting for the tunnel to corrupt a frame. This engine injects the
+waiting for the network to corrupt a frame. This engine injects the
 substrate's real failure modes ON PURPOSE, from a seeded plan, so every
 recovery path in the repo is exercised deterministically and evidenced
 in the obs stream:

@@ -4,7 +4,8 @@ The runtime around the TPU compute path is native where it is hot: full
 reweaves and merges on the host go through ``weaver.cpp``'s O(n)
 preorder construction instead of the O(n^2) sequential replay. The
 shared library is built lazily with g++ on first use and cached next to
-the source (keyed by source mtime); ``available()`` reports whether the
+the source (keyed by a hash of the source, since a copied tree does not
+keep mtimes reliably); ``available()`` reports whether the
 toolchain produced one, and every caller falls back to the pure weaver
 when it did not.
 """
@@ -12,6 +13,7 @@ when it did not.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -26,7 +28,12 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "weaver.cpp")
 # read-only installs can point the build cache elsewhere
 _CACHE_DIR = os.environ.get("CAUSE_TPU_NATIVE_CACHE", _HERE)
-_SO = os.path.join(_CACHE_DIR, "_ct_weaver.so")
+
+
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_CACHE_DIR, f"_ct_weaver_{digest}.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -34,20 +41,21 @@ _build_failed = False
 
 
 def _build() -> Optional[ctypes.CDLL]:
-    """Compile weaver.cpp to a shared library (cached by mtime). The
-    compile goes to a per-pid temp file and is renamed into place so
-    concurrent first-use across processes never loads a torn .so."""
-    if not (os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
+    """Compile weaver.cpp to a shared library (cached by source hash).
+    The compile goes to a per-pid temp file and is renamed into place
+    so concurrent first-use across processes never loads a torn .so."""
+    so = _so_path()
+    if not os.path.exists(so):
         os.makedirs(_CACHE_DIR, exist_ok=True)
-        tmp = f"{_SO}.{os.getpid()}.tmp"
+        tmp = f"{so}.{os.getpid()}.tmp"
         cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-o", tmp, _SRC]
         try:
             subprocess.run(cmd, check=True, capture_output=True, text=True)
-            os.replace(tmp, _SO)
+            os.replace(tmp, so)
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
-    lib = ctypes.CDLL(_SO)
+    lib = ctypes.CDLL(so)
     i32p = ctypes.POINTER(ctypes.c_int32)
     lib.ct_weave_list.restype = ctypes.c_int32
     lib.ct_weave_list.argtypes = [ctypes.c_int32, i32p, i32p, i32p]

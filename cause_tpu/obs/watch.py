@@ -7,7 +7,7 @@ staleness, lag percentiles with the SLO verdict and burn rate,
 full-bag and fallback rates, waves/sec, dispatch counts, token
 -headroom minima, the last run heartbeat, per-event recency, and
 every alert the rules fired. Curses-free on purpose: plain ANSI
-home-and-clear redraw works over any ssh tunnel, inside tmux, and in
+home-and-clear redraw works over any ssh session, inside tmux, and in
 a CI log (where ``--once`` prints the block exactly once).
 
     python -m cause_tpu.obs watch events.jsonl                # live tail
@@ -453,7 +453,7 @@ def main(argv=None) -> int:
                     "rates, heartbeats), declarative alert rules, "
                     "ANSI-redraw dashboard, optional Prometheus "
                     "endpoint. --once renders a single snapshot and "
-                    "exits (CI, cron, tunnel checks).")
+                    "exits (CI, cron, remote checks).")
     ap.add_argument("jsonl", nargs="*",
                     help="obs event file(s) to tail (JSON lines; "
                          "files may not exist yet in live mode). "
@@ -535,7 +535,7 @@ def main(argv=None) -> int:
                 print(render(snap, monitor.alerts, a.jsonl,
                              clock=snap["ts_us"] / 1e6))
         except BrokenPipeError:
-            # `obs watch ... --once | head` is the normal tunnel
+            # `obs watch ... --once | head` is the normal remote
             # one-liner; a closed pipe is the reader's choice, not
             # an error
             try:

@@ -32,10 +32,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from ..switches import raw_switch_key
 from ..weaver.jaxw import merge_weave_kernel, merge_weave_kernel_v2
 
-try:  # JAX >= 0.4.35 exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 __all__ = [
     "REPLICA_AXIS",

@@ -129,8 +129,7 @@ def delta_domain_ok(view, s: int, anchor: int,
     return True
 
 
-def assemble_delta_window(views, s_arr, anchor_arr, wcap: int,
-                          s_max: int):
+def assemble_delta_window(views, s_arr, anchor_arr, wcap: int):
     """Build the delta wave's ``[B, 2*wcap]`` window batch from cached
     views: per tree, lane 0 is the anchor (presented as the window
     root: cause -1) followed by the divergent-suffix lanes
@@ -139,8 +138,12 @@ def assemble_delta_window(views, s_arr, anchor_arr, wcap: int,
     counts)`` with ``lanes`` the ``benchgen.LANE_KEYS5`` dict and
     ``starts``/``counts`` the [B, 2] per-tree shared-prefix length and
     divergent lane count the splice program consumes; ``lanes`` holds
-    every ``benchgen.LANE_KEYS5`` key. Host cost is O(total window
-    lanes) — the per-wave assembly the delta path is allowed to pay."""
+    every ``benchgen.LANE_KEYS5`` key. The segment tables are sized by
+    the rows' segment counts (pow2), as the full wave and the tree
+    size theirs: tables as wide as the window made the 1024-row delta
+    program take ~25 min to compile on the v5e (PR 21). Host cost is
+    O(total window lanes) — the per-wave assembly the delta path is
+    allowed to pay."""
     from ..weaver.segments import _TABLE_DTYPES, tree_segments
 
     B = len(views)
@@ -151,10 +154,9 @@ def assemble_delta_window(views, s_arr, anchor_arr, wcap: int,
     vc = np.zeros((B, Nw), np.int32)
     valid = np.zeros((B, Nw), bool)
     seg = np.full((B, Nw), -1, np.int32)
-    tables = {k: np.zeros((B, s_max), _TABLE_DTYPES[k])
-              for k in SEG_LANE_KEYS}
     starts = np.zeros((B, 2), np.int32)
     counts = np.zeros((B, 2), np.int32)
+    per_row = []
     for r, (va, vb) in enumerate(views):
         s = int(s_arr[r])
         anchor = int(anchor_arr[r])
@@ -188,6 +190,13 @@ def assemble_delta_window(views, s_arr, anchor_arr, wcap: int,
             per_tree.append((segs, w))
             starts[r, t] = s
             counts[r, t] = d
+        per_row.append(per_tree)
+    s_max = next_pow2(max(
+        sum(sg["sg_len"].shape[0] for sg, _ in per_tree)
+        for per_tree in per_row))
+    tables = {k: np.zeros((B, s_max), _TABLE_DTYPES[k])
+              for k in SEG_LANE_KEYS}
+    for r, per_tree in enumerate(per_row):
         row_out = {k: tables[k][r] for k in SEG_LANE_KEYS}
         _t, bases = concat_seg_tables(per_tree, wcap, s_max,
                                       out=row_out)

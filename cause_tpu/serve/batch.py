@@ -157,7 +157,7 @@ class BatchScheduler:
                       tenants=len(group), rows=n_real):
             with obs.span("serve.batch_assemble"):
                 lanes, starts, counts = assemble_delta_window(
-                    views, s_arr, anchor_arr, wcap, n_w)
+                    views, s_arr, anchor_arr, wcap)
             r0 = s_arr.astype(np.int32) - 1
             rank_w, vis_w, digest, ovf = _recovery.run_dispatch(
                 "session",

@@ -58,10 +58,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-only module; absent on CPU-only jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .arrays import I32_MAX, VCLASS_H_HIDE, VCLASS_HIDE
 
@@ -506,8 +503,6 @@ def row_k4(eye, base_run, hc, h_w, run_id, keep_i, sv_len, sv_vc,
 def _vmem(width):
     shape = (_ROWS, width)
     imap = lambda b: (b, 0)
-    if pltpu is None:  # pragma: no cover - CPU-only jaxlib
-        return pl.BlockSpec(shape, imap)
     return pl.BlockSpec(shape, imap, memory_space=pltpu.VMEM)
 
 

@@ -62,10 +62,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-only module; absent on CPU-only jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .arrays import VCLASS_H_HIDE, VCLASS_HIDE
 
@@ -203,8 +200,6 @@ def _build():
             # the replica axis in 8-row steps
             shape = (_ROWS, width)
             imap = lambda b: (b, 0)
-            if pltpu is None:  # pragma: no cover - CPU-only jaxlib
-                return pl.BlockSpec(shape, imap)
             return pl.BlockSpec(shape, imap,
                                 memory_space=pltpu.VMEM)
 

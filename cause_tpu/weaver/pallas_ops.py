@@ -36,10 +36,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-only module; absent on CPU-only jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["euler_walk", "euler_walk_batch"]
 
@@ -52,15 +49,11 @@ def _interpret() -> bool:
 
 
 def _vmem_spec(R, K):
-    if pltpu is None:  # pragma: no cover - CPU-only jaxlib
-        return pl.BlockSpec((R, K), lambda b: (b, 0))
     return pl.BlockSpec((R, K), lambda b: (b, 0),
                         memory_space=pltpu.VMEM)
 
 
 def _smem_spec(R, K):
-    if pltpu is None:  # pragma: no cover - CPU-only jaxlib
-        return pl.BlockSpec((R, K), lambda b: (b, 0))
     return pl.BlockSpec((R, K), lambda b: (b, 0),
                         memory_space=pltpu.SMEM)
 
@@ -153,11 +146,8 @@ def _euler_walk1(fc, ns, parent_run, run_len):
     path, which skips the (8, 128) blocked-shape rule that rejects a
     squeezed/partial block (verified by the AOT export tests)."""
     K = fc.shape[0]
-    if pltpu is not None:
-        vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
-        smem = pl.BlockSpec(memory_space=pltpu.SMEM)
-    else:  # pragma: no cover - CPU-only jaxlib
-        vmem = smem = pl.BlockSpec()
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out = pl.pallas_call(
         _walk_kernel,
         in_specs=[vmem] * 4,

@@ -40,10 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # TPU-only module; absent on CPU-only jaxlibs
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pallas_bitonic_sort"]
 
@@ -132,11 +129,8 @@ def _build(n_ops: int, num_keys: int):
                 jnp.pad(x, ((0, Bp - B), (0, 0)),
                         constant_values=_I32_MAX if i < num_keys else 0)
                 for i, x in enumerate(ops))
-        if pltpu is not None:
-            spec = pl.BlockSpec((_ROWS, P), lambda b: (b, 0),
-                                memory_space=pltpu.VMEM)
-        else:  # pragma: no cover - CPU-only jaxlib
-            spec = pl.BlockSpec((_ROWS, P), lambda b: (b, 0))
+        spec = pl.BlockSpec((_ROWS, P), lambda b: (b, 0),
+                            memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             kernel,
             grid=(Bp // _ROWS,),
@@ -150,10 +144,7 @@ def _build(n_ops: int, num_keys: int):
     @jax.custom_batching.custom_vmap
     def single(*ops):
         P = ops[0].shape[0]
-        if pltpu is not None:
-            spec = pl.BlockSpec(memory_space=pltpu.VMEM)
-        else:  # pragma: no cover - CPU-only jaxlib
-            spec = pl.BlockSpec()
+        spec = pl.BlockSpec(memory_space=pltpu.VMEM)
         out = pl.pallas_call(
             kernel,
             in_specs=[spec] * n_ops,

@@ -81,6 +81,9 @@ def main():
     ap.add_argument("--tenants", type=int, default=12)
     ap.add_argument("--obs-out", default="/tmp/obs_batched_smoke.jsonl")
     args = ap.parse_args()
+    from cause_tpu.benchgen import enable_compile_cache
+
+    enable_compile_cache()
     n = args.tenants
 
     if os.path.exists(args.obs_out):

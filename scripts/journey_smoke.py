@@ -121,6 +121,9 @@ def main() -> int:
     args = ap.parse_args()
     if args.child:
         return child_main(args)
+    from cause_tpu.benchgen import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     from cause_tpu.net import ReplicationServer
@@ -154,6 +157,8 @@ def main() -> int:
     print(f"journey smoke: serving tenant {uuid} on "
           f"127.0.0.1:{srv.port}; spawning client process", flush=True)
 
+    # the client process stays off JAX (child_main imports no jax):
+    # only this process holds the device
     child = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__), "--child",
          "--port", str(srv.port), "--uuid", uuid,

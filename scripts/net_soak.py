@@ -57,11 +57,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import cause_tpu as c  # noqa: E402
 from cause_tpu import chaos, obs, serde, sync  # noqa: E402
@@ -269,6 +265,9 @@ def main():
                     help="ops each --proc-clients child mints")
     ap.add_argument("--state-dir", default=None)
     args = ap.parse_args()
+    from cause_tpu.benchgen import enable_compile_cache
+
+    enable_compile_cache()
 
     obs.configure(enabled=True, out=args.obs_out)
     obs.set_platform(jax.default_backend())
@@ -345,6 +344,8 @@ def main():
     for p in proc_streams:
         if os.path.exists(p):
             os.unlink(p)
+    # the network clients stay off JAX (journey_smoke's --child half
+    # imports no jax): only this process holds the device
     procs = [subprocess.Popen(
         [sys.executable,
          os.path.join(os.path.dirname(os.path.abspath(__file__)),

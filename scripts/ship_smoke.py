@@ -182,6 +182,8 @@ def main() -> int:
             os.remove(p)
     me = os.path.abspath(__file__)
 
+    # the collector and producer processes stay off JAX (their roles
+    # import no jax): only this process holds the device
     coll = subprocess.Popen(
         [sys.executable, me, "--role", "collector",
          "--obs-out", out + ".collector.jsonl",

@@ -53,8 +53,6 @@ import subprocess
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 from cause_tpu import chaos, obs  # noqa: E402
 from cause_tpu.obs import ledger  # noqa: E402
 
@@ -200,14 +198,15 @@ def dataplane_main(args) -> int:
     all of them — that is the point."""
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     from cause_tpu import serde, sync
+    from cause_tpu.benchgen import enable_compile_cache
     from cause_tpu.net import Backoff
     from cause_tpu.obs import ship
     from cause_tpu.serve import (IngestJournal, IngestQueue,
                                  SyncService)
 
     obs.configure(enabled=True, out=args.obs_out)
+    enable_compile_cache()
     obs.set_platform(jax.default_backend())
 
     exp = None
@@ -307,6 +306,7 @@ def dataplane_main(args) -> int:
         walls_base = [_round(r) for r in range(args.waves)]
     digest = svc.converged_digest(uuid)
     handoff = {
+        "platform": jax.default_backend(),
         "digest": digest,
         "admitted": q.stats["admitted_ops"],
         "median_base_ms": round(
@@ -342,6 +342,9 @@ def _spawn(me, role, **kw):
     argv = [sys.executable, me, "--role", role]
     for k, v in kw.items():
         argv += ["--" + k.replace("_", "-"), str(v)]
+    # one child at a time needs a device: the parent stays off JAX
+    # and so do the collector and producer roles (telemetry only);
+    # the dataplane role owns whatever device JAX gives it
     return subprocess.Popen(
         argv, stdout=subprocess.PIPE,
         stdin=subprocess.PIPE if role == "collector" else None,
@@ -489,8 +492,6 @@ def main() -> int:
     if args.role == "dataplane":
         return dataplane_main(args)
 
-    import jax
-
     out = args.out
     if os.path.isdir(out + ".wal"):
         shutil.rmtree(out + ".wal")
@@ -550,7 +551,7 @@ def main() -> int:
 
     row = ledger.ingest_record(
         {
-            "platform": jax.default_backend(),
+            "platform": oh["platform"],
             "metric": "ship exporter overhead pct of wave wall",
             "value": round(overhead_pct, 4),
             "kernel": "obs",

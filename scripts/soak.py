@@ -58,13 +58,9 @@ import random
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import numpy as np  # noqa: E402
 
 import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 import cause_tpu as c  # noqa: E402
 from cause_tpu import K  # noqa: E402
@@ -725,6 +721,9 @@ def main():
                          "detected; lands a --kind chaos ledger row; "
                          "requires --obs-out")
     args = ap.parse_args()
+    from cause_tpu.benchgen import enable_compile_cache
+
+    enable_compile_cache()
     if args.slo_ms is not None and not args.obs_out:
         ap.error("--slo-ms requires --obs-out (the gate reads the "
                  "sidecar's lag.window records)")

@@ -176,3 +176,29 @@ def test_raw_switch_key_matches_program_cache_shape(monkeypatch):
     gi = switches.TRACE_SWITCHES.index("CAUSE_TPU_GATHER")
     assert key[gi] == "rowgather"
     assert all(v == "" for i, v in enumerate(key) if i != gi)
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The cache follows JAX_COMPILATION_CACHE_DIR when set (JAX reads
+    it; no other directory is set in code), else the checkout's fixed
+    ``.jax_cache``; the CPU is skipped."""
+    import os
+
+    import jax
+
+    assert bg.enable_compile_cache() is None  # the suite runs on CPU
+    before = (jax.config.jax_enable_compilation_cache,
+              jax.config.jax_compilation_cache_dir)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert bg.enable_compile_cache() == bg.CACHE_DIR
+        assert bg.CACHE_DIR == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(
+                bg.__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert bg.enable_compile_cache() == str(tmp_path)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", before[0])
+        jax.config.update("jax_compilation_cache_dir", before[1])

@@ -311,3 +311,28 @@ def test_v5_scatter_hint_parity_tiny(monkeypatch):
     finally:
         monkeypatch.delenv("CAUSE_TPU_SCATTER")
         merge_weave_kernel_v5_jit.clear_cache()
+
+
+def test_graft_entry_compiles_v5_against_pure():
+    """The driver's compile check runs the shipped v5 row kernel, and
+    its weave is the pure weaver's merge of the same pair."""
+    import jax
+
+    import __graft_entry__ as g
+    from cause_tpu.collections.clist import CausalList
+    from cause_tpu.weaver import lanecache
+
+    a, b, cap, row, _u = g.entry_pair()
+    fn, _args = g.entry()
+    rank, _vis, _conflict, overflow = jax.jit(fn)(*row)
+    assert not bool(overflow)
+    rank = np.asarray(rank)
+    keep = np.flatnonzero(rank < 2 * cap)
+    order = keep[np.argsort(rank[keep], kind="stable")]
+    an = lanecache.view_for(a.ct).arena.nodes
+    bn = lanecache.view_for(b.ct).arena.nodes
+    got = [(an[j] if j < cap else bn[j - cap])[0] for j in order]
+    pure = [CausalList(h.ct.evolve(weaver="pure", lanes=None))
+            for h in (a, b)]
+    want = [n[0] for n in pure[0].merge(pure[1]).get_weave()]
+    assert got == want

@@ -81,14 +81,6 @@ def test_map_digests_detect_convergence():
 
 
 def test_sharded_map_merge_agrees_with_batched():
-    # same capability gap as test_wave's mesh tests: no shard_map
-    # replication rule for `while` on this jax build (known issue,
-    # ROADMAP item 3) — skip honestly instead of failing
-    from test_wave import _shardmap_while_supported
-
-    if not _shardmap_while_supported():
-        pytest.skip("this jax build has no shard_map replication rule "
-                    "for `while` (known issue; see ROADMAP item 3)")
     from cause_tpu.parallel import make_mesh
 
     pairs = make_pairs(8, n_keys=4, edits=3)

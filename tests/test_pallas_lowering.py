@@ -4,11 +4,8 @@ Interpret mode (what the CPU suite runs) accepts programs the real
 Mosaic compiler rejects — the original euler_walk design passed every
 CPU test yet failed TPU lowering with "Cannot store scalars to VMEM".
 ``jax.export`` with platforms=["tpu"] runs the Pallas->Mosaic lowering
-from any backend, so these tests pin compilability without a chip.
-(The final Mosaic->TPU codegen still happens on-device; this catches
-the op-support and tiling-rule class of failure.)"""
-
-import functools
+from any backend. Lowering is not compiling: the chip's compiler, which
+refuses kernels for VMEM or tiling, runs in tests/test_tpu_compile.py."""
 
 import numpy as np
 import pytest
@@ -17,26 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from cause_tpu.weaver import pallas_ops
-
-
-@functools.lru_cache(maxsize=1)
-def _jax_export_supported() -> bool:
-    """Capability probe for the cross-platform lowering API: this
-    container's jax build (0.4.37-era) has no ``jax.export`` module at
-    all, so every export-based lowering guard would fail with
-    AttributeError before reaching any Pallas code (known issue since
-    PR 6 — same pattern as test_wave's shard_map-while probe). The
-    lowering guards still run on jax builds that ship the API; the
-    walk-parity test below needs no export and always runs."""
-    return hasattr(jax, "export") and hasattr(
-        getattr(jax, "export"), "export")
-
-
-needs_jax_export = pytest.mark.skipif(
-    not _jax_export_supported(),
-    reason="this jax build has no jax.export module (known issue: "
-           "the Mosaic-lowering guards need the cross-platform "
-           "export API; they run on jax builds that ship it)")
 
 
 def _chain_tables(k, n_runs):
@@ -64,7 +41,6 @@ def _chain_tables(k, n_runs):
             jnp.asarray(w))
 
 
-@needs_jax_export
 def test_euler_walk_exports_for_tpu(monkeypatch):
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     fc, ns, parent, w = _chain_tables(256, 40)
@@ -76,7 +52,6 @@ def test_euler_walk_exports_for_tpu(monkeypatch):
         fc, ns, parent, w)
 
 
-@needs_jax_export
 def test_euler_walk_batch_exports_for_tpu(monkeypatch):
     monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
     fc, ns, parent, w = _chain_tables(256, 40)
@@ -91,7 +66,6 @@ def test_euler_walk_batch_exports_for_tpu(monkeypatch):
     jax.export.export(jax.jit(batched), platforms=["tpu"])(*batch)
 
 
-@needs_jax_export
 def test_v5w_kernel_exports_for_tpu(monkeypatch):
     """The full v5 kernel with euler='walk' must lower for TPU — the
     exact program bench.py dispatches under BENCH_KERNEL=v5w."""
@@ -114,7 +88,6 @@ def test_v5w_kernel_exports_for_tpu(monkeypatch):
     jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
 
 
-@needs_jax_export
 def test_v5_allstream_exports_for_tpu(monkeypatch):
     """The full streaming configuration (rowgather + bitonic + matrix
     search) must lower for TPU — the watcher's headline candidate."""
@@ -142,7 +115,6 @@ def test_v5_allstream_exports_for_tpu(monkeypatch):
         batched_merge_weave_v5.clear_cache()
 
 
-@needs_jax_export
 def test_v5_kernel_exports_for_tpu():
     """The default v5 program (pure XLA) lowers for TPU too — guards
     against a jnp construct with no TPU lowering sneaking in."""
@@ -181,7 +153,6 @@ def test_walk_parity_vs_doubling_after_redesign():
         assert np.array_equal(np.asarray(want), np.asarray(got_b[r]))
 
 
-@needs_jax_export
 def test_v5_scatter_hint_exports_for_tpu(monkeypatch):
     """The annotated-scatter configuration must lower for TPU."""
     monkeypatch.setenv("CAUSE_TPU_SCATTER", "hint")
@@ -206,7 +177,6 @@ def test_v5_scatter_hint_exports_for_tpu(monkeypatch):
         batched_merge_weave_v5.clear_cache()
 
 
-@needs_jax_export
 def test_v5_beststream_combined_exports_for_tpu(monkeypatch):
     """The exact shipped beststream combination (pallas sort +
     rowgather + matrix-table + scatter hints + euler walk) must lower
@@ -241,7 +211,6 @@ def test_v5_beststream_combined_exports_for_tpu(monkeypatch):
         batched_merge_weave_v5.clear_cache()
 
 
-@needs_jax_export
 def test_fphase_kernel_exports_for_tpu(monkeypatch):
     """The fused F-phase expansion (pallas_fphase) must lower via
     Mosaic: dynamic-start window loads from the transposed tables,
@@ -273,7 +242,6 @@ def test_fphase_kernel_exports_for_tpu(monkeypatch):
         *(jnp.asarray(x) for x in (lk, tb, cs, ce, vc, seg, fl)))
 
 
-@needs_jax_export
 def test_v5_fphase_exports_for_tpu(monkeypatch):
     """The full v5 program under CAUSE_TPU_FPHASE=pallas lowers for
     TPU — the exact program the harvest ladder times."""
@@ -301,7 +269,6 @@ def test_v5_fphase_exports_for_tpu(monkeypatch):
         batched_merge_weave_v5.clear_cache()
 
 
-@needs_jax_export
 def test_v5f_pipeline_exports_for_tpu(monkeypatch):
     """The full fused-token-pipeline program (jaxw5f: K1 + K2 +
     euler_walk + K4 + fphase plus the XLA glue) must lower via Mosaic

@@ -14,11 +14,6 @@ from jax import lax
 from cause_tpu.weaver.pallas_sort import pallas_bitonic_sort
 
 
-# the jax.export capability probe (same known-issue skip as
-# tests/test_pallas_lowering.py: this container's jax build has no
-# jax.export module, so the Mosaic-lowering guards cannot run here)
-from test_pallas_lowering import needs_jax_export  # noqa: E402
-
 I32_MAX = np.iinfo(np.int32).max
 
 
@@ -82,7 +77,6 @@ def test_rejects_non_int32():
         pallas_bitonic_sort((jnp.zeros(8, jnp.float32),), num_keys=1)
 
 
-@needs_jax_export
 def test_exports_for_tpu(monkeypatch):
     from cause_tpu.weaver import pallas_sort
 
@@ -96,7 +90,6 @@ def test_exports_for_tpu(monkeypatch):
     jax.export.export(jax.jit(f), platforms=["tpu"])(a, b)
 
 
-@needs_jax_export
 def test_exports_for_tpu_vmapped(monkeypatch):
     from cause_tpu.weaver import pallas_sort
 
@@ -112,7 +105,6 @@ def test_exports_for_tpu_vmapped(monkeypatch):
     jax.export.export(jax.jit(f), platforms=["tpu"])(a, b)
 
 
-@needs_jax_export
 def test_v5_kernel_with_pallas_sort_exports_for_tpu(monkeypatch):
     """The full v5 kernel under CAUSE_TPU_SORT=pallas must lower for
     TPU — the exact program the harvest A/B dispatches."""

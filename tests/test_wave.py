@@ -1,8 +1,6 @@
 """Batched API-level merge waves: device wave == per-pair merge, with
 cached lanes doing the marshal and digests reporting convergence."""
 
-import functools
-
 import numpy as np
 import pytest
 
@@ -12,46 +10,6 @@ from cause_tpu.collections.clist import CausalList
 from cause_tpu.ids import new_site_id
 from cause_tpu.parallel import make_mesh, merge_wave
 from cause_tpu.weaver import lanecache
-
-
-@functools.lru_cache(maxsize=1)
-def _shardmap_while_supported() -> bool:
-    """Capability probe for the sharded wave path: some jax builds
-    (this container's included) lack a shard_map replication rule for
-    ``while``, so every sharded v3/v5 step raises NotImplementedError
-    ("No replication rule for while" — known pre-existing since PR 2).
-    Probed with a tiny while-under-shard_map program (sub-second)
-    instead of letting the mesh tests compile real kernels into a
-    guaranteed failure."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from cause_tpu.parallel import mesh as mesh_mod
-
-    def body(x):
-        return jax.lax.while_loop(
-            lambda s: s[0] < jnp.int32(1),
-            lambda s: (s[0] + 1, s[1] + 1.0),
-            (jnp.int32(0), x),
-        )[1]
-
-    try:
-        f = mesh_mod._shard_map(
-            body, mesh=mesh_mod.make_mesh(8),
-            in_specs=P(mesh_mod.REPLICA_AXIS),
-            out_specs=P(mesh_mod.REPLICA_AXIS))
-        np.asarray(jax.jit(f)(jnp.zeros(8, jnp.float32)))
-        return True
-    except NotImplementedError:
-        return False
-
-
-needs_shardmap_while = pytest.mark.skipif(
-    not _shardmap_while_supported(),
-    reason="this jax build has no shard_map replication rule for "
-           "`while` (known issue: sharded v3/v5 wave steps raise "
-           "NotImplementedError; see ROADMAP item 3)")
 
 
 def warm(cl):
@@ -117,7 +75,6 @@ def test_wave_second_round_reuses_merged_cache():
         assert c.causal_to_edn(res2.merged(i)) == c.causal_to_edn(a.merge(b))
 
 
-@needs_shardmap_while
 def test_wave_sharded_over_mesh():
     mesh = make_mesh(8)
     pairs = make_pairs(8, n_base=40, n_div=4)
@@ -156,7 +113,6 @@ def test_union_views_equals_scratch_union():
                           na.cause_idx[: na.n])
 
 
-@needs_shardmap_while
 def test_wave_mesh_survives_fallback_shrink():
     """A pair that falls back must not break mesh divisibility — the
     live batch pads internally (regression: shard_map requires the
