@@ -1,7 +1,6 @@
 """CLI: the obs toolbox.
 
     python -m cause_tpu.obs events.jsonl -o trace.json   # Perfetto
-    python -m cause_tpu.obs stages [--smoke] [--reps N]  # stage ladder
     python -m cause_tpu.obs ledger --check               # perf ledger
     python -m cause_tpu.obs fleet events.jsonl           # fleet health
     python -m cause_tpu.obs gap [--obs events.jsonl]     # gap report
@@ -12,8 +11,7 @@
 The default (first) form converts an obs JSONL event stream to a
 Perfetto trace — open the output at https://ui.perfetto.dev (or
 chrome://tracing); with ``--summary`` it also prints per-span-name
-aggregate wall times and the final counter values. ``stages`` runs
-the jaxw5 stage-prefix profiler (``cause_tpu.obs.stages``); ``ledger``
+aggregate wall times and the final counter values. ``ledger``
 manages the persistent perf ledger (``cause_tpu.obs.ledger``).
 """
 
@@ -28,11 +26,6 @@ from .perfetto import export_perfetto, load_jsonl, merged_final_counters
 
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    if argv and argv[0] == "stages":
-        # imports jax — resolved only when asked for
-        from .stages import main as stages_main
-
-        return stages_main(argv[1:])
     if argv and argv[0] == "ledger":
         from .ledger import main as ledger_main
 

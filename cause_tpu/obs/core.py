@@ -37,6 +37,15 @@ environment variable is ever read (the snapshot happens only on
 enabled-span close). Enable with ``CAUSE_TPU_OBS=1``; stream with
 ``CAUSE_TPU_OBS_OUT=<path>``; bound the ring with
 ``CAUSE_TPU_OBS_RING`` (default 65536 events).
+
+Spans have a second sink, independent of that switch: whenever a JAX
+profiler session is recording (``jax.profiler.start_trace``), every
+``span()`` also opens a profiler ``TraceMe`` of the same name, with its
+integer and string attributes as the event's stats. The spans then sit
+on the device trace's own clock, obs on or off. With obs off and no
+profiler recording, a span costs one ``TraceMe.is_enabled()`` check on
+top of the no-op; the profiler is looked up only once JAX has loaded
+it, so this module still imports no JAX.
 """
 
 from __future__ import annotations
@@ -45,6 +54,7 @@ import atexit
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -108,6 +118,59 @@ class _NullInstrument:
 
 _NULL_SPAN = _NullSpan()
 _NULL_INSTRUMENT = _NullInstrument()
+
+# the profiler's TraceMe class, found once JAX has loaded it
+_TRACEME = None
+
+
+def _profiler_traceme():
+    """The profiler's ``TraceMe`` class while a profiler session
+    records, else None. Never imports JAX: before JAX is loaded no
+    profiler can be recording."""
+    global _TRACEME
+    tm = _TRACEME
+    if tm is None:
+        mod = sys.modules.get("jaxlib._profiler")
+        if mod is None:
+            return None
+        tm = _TRACEME = mod.TraceMe
+    return tm if tm.is_enabled() else None
+
+
+def _trace_stats(attrs: dict) -> dict:
+    """The attributes a TraceMe carries as stats: integers and strings.
+    The profiler encodes them into the event name as ``#k=v,...#``, so
+    a string loses the characters that would end its value."""
+    out = {}
+    for k, v in attrs.items():
+        if isinstance(v, int):
+            out[k] = int(v)
+        elif isinstance(v, str):
+            out[k] = v.replace("#", "_").replace(",", "_").replace("=", "_")
+    return out
+
+
+class _TraceSpan:
+    """A span while obs is off and a profiler session records: the
+    profiler's TraceMe alone."""
+
+    __slots__ = ("tm",)
+
+    def __init__(self, traceme, name: str, attrs: dict):
+        self.tm = traceme(name, **_trace_stats(attrs))
+
+    def __enter__(self):
+        self.tm.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.tm.__exit__(*exc)
+        return False
+
+    def set(self, **attrs):
+        self.tm.set_metadata(**_trace_stats(attrs))
+        return self
+
 
 # default bound of one in-process subscriber queue (obs.live's
 # monitor drains on its own cadence; a stalled reader must cost the
@@ -373,9 +436,9 @@ class _Span:
     close. ``set(**attrs)`` adds attributes mid-flight."""
 
     __slots__ = ("st", "name", "attrs", "sid", "parent", "depth",
-                 "t0", "ts_us")
+                 "t0", "ts_us", "tm")
 
-    def __init__(self, st: _State, name: str, attrs: dict):
+    def __init__(self, st: _State, name: str, attrs: dict, traceme=None):
         self.st = st
         self.name = name
         self.attrs = attrs
@@ -384,9 +447,13 @@ class _Span:
         self.depth = 0
         self.t0 = 0.0
         self.ts_us = 0
+        self.tm = (traceme(name, **_trace_stats(attrs))
+                   if traceme is not None else None)
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        if self.tm is not None:
+            self.tm.set_metadata(**_trace_stats(attrs))
         return self
 
     def __enter__(self):
@@ -398,12 +465,16 @@ class _Span:
             self.parent = stack[-1]
         self.depth = len(stack)
         stack.append(self.sid)
+        if self.tm is not None:
+            self.tm.__enter__()
         self.ts_us = time.time_ns() // 1000
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur_us = int((time.perf_counter() - self.t0) * 1e6)
+        if self.tm is not None:
+            self.tm.__exit__(exc_type, exc, tb)
         st = self.st
         stack = getattr(st.tls, "stack", None)
         if stack and stack[-1] == self.sid:
@@ -480,11 +551,16 @@ class _Gauge:
 
 
 def span(name: str, **attrs):
-    """A wall-time span. Disabled mode returns the shared no-op."""
+    """A wall-time span: recorded to the ring and sink when obs is on,
+    and to the profiler's trace whenever a profiler session records.
+    With neither, the shared no-op."""
     st = _resolve_state()
+    traceme = _profiler_traceme()
     if not st.enabled:
-        return _NULL_SPAN
-    return _Span(st, name, attrs)
+        if traceme is None:
+            return _NULL_SPAN
+        return _TraceSpan(traceme, name, attrs)
+    return _Span(st, name, attrs, traceme)
 
 
 def event(name: str, **fields) -> None:

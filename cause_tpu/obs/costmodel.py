@@ -27,8 +27,7 @@ that join, in three layers:
 - **the gap report** — ``python -m cause_tpu.obs gap`` reads the
   committed perf ledger plus any obs JSONL stream and renders the
   north-star decomposition: best same-platform headline, the dispatch
-  -floor share, per-phase shares from ``stages.prefix`` events when
-  present, the cost-vs-divergence slope with an explicit
+  -floor share, the cost-vs-divergence slope with an explicit
   O(doc)-vs-O(delta) verdict, and the projected headline if cost
   scaled with the measured divergence.
 
@@ -540,30 +539,6 @@ def _best_bench_rows(rows: Sequence[dict]) -> Dict[str, dict]:
     return best
 
 
-def _stage_shares(events: Sequence[dict]) -> List[dict]:
-    """Per-phase shares from ``stages.prefix`` events (the jaxw5 stage
-    ladder), when the stream carries them: each stage's delta over the
-    FULL prefix's p50. Last ladder wins (streams may hold several)."""
-    ladder: Dict[str, dict] = {}
-    for e in events:
-        if e.get("ev") == "event" and e.get("name") == "stages.prefix":
-            f = e.get("fields") or {}
-            if f.get("stage") and f.get("p50_ms") is not None:
-                ladder[str(f["stage"])] = f
-    if not ladder:
-        return []
-    full = ladder.get("FULL") or max(
-        ladder.values(), key=lambda f: f["p50_ms"])
-    total = float(full["p50_ms"]) or 1.0
-    out = []
-    for name, f in ladder.items():
-        delta = float(f.get("delta_ms") or 0.0)
-        out.append({"stage": name, "delta_ms": round(delta, 3),
-                    "share": round(delta / total, 4)})
-    out.sort(key=lambda d: -d["delta_ms"])
-    return out
-
-
 def tree_decomposition(events: Sequence[dict]) -> Optional[dict]:
     """Per-level cost decomposition of merge-tree convergence runs in
     one obs stream: join each ``tree.level`` semantic event with the
@@ -679,9 +654,6 @@ def gap_report(rows: Sequence[dict],
         }
     else:
         report["headline"] = None
-    stages = _stage_shares(events)
-    if stages:
-        report["stages"] = stages
     tree = tree_decomposition(events)
     if tree:
         report["tree"] = tree
@@ -753,9 +725,6 @@ def render_gap(report: dict) -> str:
                 + (f", {100 * share:.1f}% of a single dispatch"
                    if share is not None else "")
                 + f", {100 * fl['share_of_target']:.0f}% of the target")
-    for st in report.get("stages", []):
-        lines.append(f"  phase {st['stage']}: {st['delta_ms']:g} ms "
-                     f"({100 * st['share']:.1f}%)")
     tree = report.get("tree")
     if tree:
         lines.append(
@@ -815,7 +784,7 @@ def main(argv=None) -> int:
         prog="python -m cause_tpu.obs gap",
         description="Render the north-star gap decomposition from the "
                     "committed perf ledger plus any obs JSONL streams "
-                    "(dispatch-floor share, per-phase shares, the "
+                    "(dispatch-floor share, the "
                     "cost-vs-divergence slope with its O(doc)-vs-"
                     "O(delta) verdict, and the projected headline if "
                     "cost scaled with divergence).")
@@ -823,8 +792,8 @@ def main(argv=None) -> int:
                     help="ledger path (default: CAUSE_TPU_LEDGER or "
                          "measurements/ledger.jsonl)")
     ap.add_argument("--obs", action="append", default=[],
-                    help="obs JSONL stream(s) carrying wave.cost / "
-                         "stages.prefix events (repeatable)")
+                    help="obs JSONL stream(s) carrying wave.cost "
+                         "events (repeatable)")
     ap.add_argument("--json", action="store_true",
                     help="emit the report as JSON instead of text")
     ap.add_argument("--append", action="store_true",

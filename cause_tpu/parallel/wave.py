@@ -43,7 +43,7 @@ from . import recovery as _recovery
 
 __all__ = ["merge_wave", "WaveResult", "WaveBuffers",
            "delta_domain_ok", "assemble_delta_window",
-           "dispatch_full_rows"]
+           "dispatch_full_rows", "last_kernel_hlo"]
 
 
 @lru_cache(maxsize=8)
@@ -564,6 +564,237 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
         # read exactly this pairing
         obs.event("run.heartbeat", stage="wave",
                   uuid=str(pairs[0][0].ct.uuid), pairs=len(pairs))
+    with obs.span("wave.views", pairs=len(pairs)) as sp:
+        views, live, fallback, poisoned = _pair_views(pairs)
+        sp.set(fallback=len(fallback))
+    if not live:
+        B = len(pairs)
+        obs.counter("wave.pairs").inc(B)
+        obs.counter("wave.fallback").inc(len(fallback))
+        obs.counter("wave.poisoned").inc(len(poisoned))
+        if obs.enabled():
+            # the wave still happened; every pair ages (no device
+            # digest converged it against the fleet's modal value)
+            sem = _observe_semantics(pairs, np.zeros(B, np.uint32),
+                                     np.zeros(B, bool), "wave")
+            # a degenerate wave (all pairs host-merged/poisoned) ran
+            # zero device programs: its wave.cost records that — the
+            # "dispatches >= 1" invariant holds for non-degenerate
+            # waves only
+            from ..obs import costmodel as _cm
+
+            _cm.wave_cost(uuid=str(pairs[0][0].ct.uuid), pairs=B,
+                          lanes=0, full_bag=len(fallback),
+                          poisoned=len(poisoned), semantic=sem,
+                          path="full")
+        return WaveResult(pairs, views, 0,
+                          np.zeros((B, 0), np.int32),
+                          np.zeros((B, 0), bool),
+                          np.zeros(B, np.uint32), fallback, "host",
+                          poisoned=poisoned)
+
+    cap = next_pow2(max(
+        max(va.n, vb.n) for i in live for va, vb in [views[i]]
+    ))
+    live_views = [views[i] for i in live]
+    if mesh is not None and len(live_views) % mesh.size:
+        # fallbacks shrank the batch below mesh divisibility: pad with
+        # copies of the first live row and drop their outputs below
+        pad_rows = (-len(live_views)) % mesh.size
+        live_views = live_views + [live_views[0]] * pad_rows
+    with obs.span("wave.assemble", rows=len(live_views), cap=int(cap)):
+        lanes = _assemble_rows(live_views, cap, bufs=ctx)
+
+    from ..benchgen import LANE_KEYS5, v5_token_budget
+
+    # BENCH_KERNEL routes the wave's kernel variant (the api-level
+    # twin of bench.py's forced-kernel knob), for the v5 family only
+    # — the wave path is segment-union by design. Unknown values fail
+    # loudly (bench.py contract): a typo must not silently time v5.
+    import os as _os
+
+    forced = _os.environ.get("BENCH_KERNEL", "").strip()
+    if forced not in ("", "v5", "v5w", "v5f"):
+        raise ValueError(
+            f"merge_wave supports BENCH_KERNEL of v5/v5w/v5f only "
+            f"(the wave path is segment-union); got {forced!r}")
+    pipeline = forced or "v5"
+
+    def dispatch_v5(sub_lanes, u):
+        """Batched v5-family dispatch + device digest, one scalar-free
+        host fetch: upload, kernel and fetch each under a span that
+        ends when its work on the device does."""
+        if pipeline == "v5f":
+            from ..weaver.jaxw5f import batched_merge_weave_v5f as fn
+
+            kw = {"u_max": u, "k_max": u}
+        else:
+            from ..weaver.jaxw5 import batched_merge_weave_v5 as fn
+
+            kw = {"u_max": u, "k_max": u,
+                  "euler": "walk" if pipeline == "v5w" else "doubling"}
+
+        dev = _upload(sub_lanes, LANE_KEYS5)
+        args = [dev[k] for k in LANE_KEYS5]
+        with obs.span("wave.kernel", u_max=int(u),
+                      rows=int(sub_lanes["hi"].shape[0])):
+            r, v, _c, ov = _recovery.run_dispatch(
+                "wave", lambda: fn(*args, **kw))
+            d = _digest_fn()(dev["hi"], dev["lo"], r, v)
+            jax.block_until_ready((r, v, d, ov))
+        _LAST_KERNEL.update(fn=fn, kw=kw, args=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args])
+        if obs.enabled():
+            # dispatch accounting: one kernel invocation plus one
+            # digest invocation per dispatch_v5 call, attributed to
+            # the open wave window
+            from ..obs import costmodel as _cm
+
+            _cm.record_dispatch(f"wave:{pipeline}:u{int(u)}",
+                                site="wave")
+            _cm.record_dispatch("wave:digest", site="wave")
+        return _fetch(r, v, d, ov)
+
+    # pow2-quantized budget: every distinct u_max is a distinct XLA
+    # program, so exact budgets would recompile on every wave whose
+    # divergence shifted slightly
+    u_need = int(v5_token_budget(lanes))
+    u_max = next_pow2(u_need)
+    if obs.enabled():
+        # token-budget headroom: the pow2 slack this fleet has before
+        # a divergence spike overflows the kernel and forces
+        # retries/host fallbacks
+        from ..obs import semantic as _sem
+
+        _sem.token_headroom(int(u_max) - u_need, "wave")
+    with obs.span("wave.dispatch", kernel=pipeline,
+                  rows=len(live_views), u_max=int(u_max),
+                  sharded=mesh is not None):
+        if mesh is not None:
+            from .mesh import sharded_merge_weave_v5
+
+            if pipeline == "v5w":
+                raise ValueError(
+                    "BENCH_KERNEL=v5w has no sharded wave step; use "
+                    "v5 or v5f under a mesh")
+            jl = _upload(lanes, tuple(lanes))
+            with obs.span("wave.kernel", u_max=int(u_max),
+                          rows=len(live_views)):
+                rank, visible, overflow, digest, _tv, _nc, _n_ov = (
+                    sharded_merge_weave_v5(mesh, jl, u_max=u_max,
+                                           k_max=u_max,
+                                           pipeline=pipeline))
+                jax.block_until_ready((rank, visible, overflow, digest))
+            rank, visible, digest, overflow = _fetch(
+                rank, visible, digest, overflow)
+            if obs.enabled():
+                # the sharded step computes kernel + digest in ONE
+                # compiled program
+                from ..obs import costmodel as _cm
+
+                _cm.record_dispatch(
+                    f"wave:sharded:{pipeline}:u{int(u_max)}",
+                    site="wave")
+        else:
+            rank, visible, digest, overflow = dispatch_v5(lanes, u_max)
+    n_retried = 0
+    if overflow.any():
+        # the token budget samples rows; a spiky unsampled row can
+        # overflow. Retry just those rows (unsharded — a handful of
+        # rows doesn't need the mesh) with a doubled budget before
+        # resorting to host merges. np.array: jax host buffers can be
+        # read-only.
+        rows = np.flatnonzero(overflow)
+        n_retried = len(rows)
+        obs.counter("wave.overflow_retry").inc(len(rows))
+        obs.event("wave.overflow_retry", rows=len(rows),
+                  u_max=int(u_max))
+        if obs.enabled():
+            _recovery.step("wave", "full", "double_budget",
+                           "token-overflow",
+                           uuid=str(pairs[0][0].ct.uuid),
+                           rows=n_retried)
+        sub = {k: lanes[k][rows] for k in LANE_KEYS5}
+        with obs.span("wave.dispatch.retry", rows=len(rows),
+                      u_max=int(2 * u_max)):
+            r2, v2, d2, ov2 = dispatch_v5(sub, 2 * u_max)
+        rank = np.array(rank)
+        visible = np.array(visible)
+        digest = np.array(digest)
+        overflow = np.array(overflow)
+        rank[rows] = r2
+        visible[rows] = v2
+        digest[rows] = d2
+        overflow[rows] = ov2
+
+    with obs.span("wave.scatter"):
+        B = len(pairs)
+        full_rank = np.full((B, 2 * cap), 2 * cap, np.int32)
+        full_vis = np.zeros((B, 2 * cap), bool)
+        full_dig = np.zeros(B, np.uint32)
+        dig_valid = np.zeros(B, bool)
+        for j, i in enumerate(live):
+            if bool(overflow[j]):
+                a, b = pairs[i]
+                if obs.enabled():
+                    # the ladder's last rung: still overflowing at the
+                    # doubled budget, this pair runs the host merge
+                    _recovery.step("wave", "double_budget", "host",
+                                   "token-overflow",
+                                   uuid=str(a.ct.uuid), pair=i)
+                try:
+                    # budget blown: host path, correct
+                    fallback[i] = a.merge(b)
+                except s.CausalError as err:  # corrupt AND overflowed
+                    err.info["pair"] = i
+                    poisoned[i] = err
+                views[i] = None
+                continue
+            full_rank[i] = rank[j]
+            full_vis[i] = visible[j]
+            full_dig[i] = digest[j]
+            dig_valid[i] = True
+        result = WaveResult(pairs, views, cap, full_rank, full_vis,
+                            full_dig, fallback, pipeline, dig_valid,
+                            poisoned=poisoned)
+    obs.counter("wave.pairs").inc(B)
+    obs.counter("wave.fallback").inc(len(fallback))
+    obs.counter("wave.poisoned").inc(len(poisoned))
+    if obs.enabled():
+        # semantic layer: digest agreement, staleness aging, and (on
+        # disagreement) one divergence event with site provenance
+        sem = _observe_semantics(pairs, full_dig, dig_valid, "wave")
+        # devprof wave-boundary sample: live device arrays + backend
+        # memory after the dispatch settle, so per-wave residency
+        # renders as a curve next to the dispatch spans
+        from ..obs import devprof
+
+        devprof.sample_device_memory("wave")
+        # the cost-vs-divergence join: ONE wave.cost event carrying
+        # this wave's dispatch count and program identities next to
+        # its token work size (the O(delta) axis), its lane width
+        # (the O(doc) axis) and the semantic digest summary
+        from ..obs import costmodel as _cm
+
+        # lanes/tokens are FLEET totals (lanes: the O(doc) transfer/
+        # scan width; tokens: worst-row estimate × rows — the kernel
+        # pads every row to the budget), same units as delta_ops
+        _cm.wave_cost(uuid=str(pairs[0][0].ct.uuid), pairs=B,
+                      lanes=2 * int(cap) * B,
+                      tokens=int(u_need) * len(live_views),
+                      token_budget=int(u_max) * len(live_views),
+                      full_bag=len(fallback), poisoned=len(poisoned),
+                      overflow_retries=n_retried, semantic=sem,
+                      path="full")
+    return result
+
+
+def _pair_views(pairs):
+    """The merge guards, each pair's cached lane views (rebuilt where
+    the rank generations differ), the host merges of pairs off the
+    device domain, and the sampled body spot-check. Returns ``(views,
+    live, fallback, poisoned)``: ``views[i]`` is None for a pair that
+    does not ride the device wave, ``live`` the indices that do."""
     for a, b in pairs:
         s.check_mergeable(a.ct, b.ct)
 
@@ -635,222 +866,38 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
             poisoned[i] = err
             views[i] = None
         live = [i for i, v in enumerate(views) if v is not None]
-    if not live:
-        B = len(pairs)
-        obs.counter("wave.pairs").inc(B)
-        obs.counter("wave.fallback").inc(len(fallback))
-        obs.counter("wave.poisoned").inc(len(poisoned))
-        if obs.enabled():
-            # the wave still happened; every pair ages (no device
-            # digest converged it against the fleet's modal value)
-            sem = _observe_semantics(pairs, np.zeros(B, np.uint32),
-                                     np.zeros(B, bool), "wave")
-            # a degenerate wave (all pairs host-merged/poisoned) ran
-            # zero device programs: its wave.cost records that — the
-            # "dispatches >= 1" invariant holds for non-degenerate
-            # waves only
-            from ..obs import costmodel as _cm
+    return views, live, fallback, poisoned
 
-            _cm.wave_cost(uuid=str(pairs[0][0].ct.uuid), pairs=B,
-                          lanes=0, full_bag=len(fallback),
-                          poisoned=len(poisoned), semantic=sem,
-                          path="full")
-        return WaveResult(pairs, views, 0,
-                          np.zeros((B, 0), np.int32),
-                          np.zeros((B, 0), bool),
-                          np.zeros(B, np.uint32), fallback, "host",
-                          poisoned=poisoned)
 
-    cap = next_pow2(max(
-        max(va.n, vb.n) for i in live for va, vb in [views[i]]
-    ))
-    live_views = [views[i] for i in live]
-    if mesh is not None and len(live_views) % mesh.size:
-        # fallbacks shrank the batch below mesh divisibility: pad with
-        # copies of the first live row and drop their outputs below
-        pad_rows = (-len(live_views)) % mesh.size
-        live_views = live_views + [live_views[0]] * pad_rows
-    with obs.span("wave.assemble", rows=len(live_views), cap=int(cap)):
-        lanes = _assemble_rows(live_views, cap, bufs=ctx)
+def _upload(lanes, keys):
+    """Host to device of the named lanes, ended by blocking on them:
+    the ``wave.upload`` span times the transfer itself."""
+    with obs.span("wave.upload",
+                  bytes=sum(int(lanes[k].nbytes) for k in keys)):
+        dev = dict(zip(keys, jax.device_put([lanes[k] for k in keys])))
+        jax.block_until_ready(dev)
+    return dev
 
-    from ..benchgen import LANE_KEYS5, v5_token_budget
 
-    # BENCH_KERNEL routes the wave's kernel variant (the api-level
-    # twin of bench.py's forced-kernel knob), for the v5 family only
-    # — the wave path is segment-union by design. Unknown values fail
-    # loudly (bench.py contract): a typo must not silently time v5.
-    import os as _os
+def _fetch(*arrays):
+    """Device to host copies of a wave's outputs (``wave.fetch``)."""
+    with obs.span("wave.fetch") as sp:
+        out = tuple(np.asarray(x) for x in arrays)
+        sp.set(bytes=sum(int(x.nbytes) for x in out))
+    return out
 
-    forced = _os.environ.get("BENCH_KERNEL", "").strip()
-    if forced not in ("", "v5", "v5w", "v5f"):
-        raise ValueError(
-            f"merge_wave supports BENCH_KERNEL of v5/v5w/v5f only "
-            f"(the wave path is segment-union); got {forced!r}")
-    pipeline = forced or "v5"
 
-    def dispatch_v5(sub_lanes, u):
-        """Batched v5-family dispatch + device digest, one scalar-free
-        host fetch."""
-        if pipeline == "v5f":
-            from ..weaver.jaxw5f import batched_merge_weave_v5f
+# the program and argument shapes of the last unsharded kernel dispatch
+_LAST_KERNEL: dict = {}
 
-            def _batched(*a, u_max, k_max):
-                return batched_merge_weave_v5f(
-                    *a, u_max=u_max, k_max=k_max)
-        else:
-            from ..weaver.jaxw5 import batched_merge_weave_v5
 
-            def _batched(*a, u_max, k_max):
-                return batched_merge_weave_v5(
-                    *a, u_max=u_max, k_max=k_max,
-                    euler="walk" if pipeline == "v5w" else "doubling")
-
-        r, v, _c, ov = _recovery.run_dispatch(
-            "wave",
-            lambda: _batched(
-                *(jnp.asarray(sub_lanes[k]) for k in LANE_KEYS5),
-                u_max=u, k_max=u,
-            ))
-        d = _digest_fn()(jnp.asarray(sub_lanes["hi"]),
-                         jnp.asarray(sub_lanes["lo"]), r, v)
-        if obs.enabled():
-            # dispatch accounting: one kernel invocation plus one
-            # digest invocation per dispatch_v5 call, attributed to
-            # the open wave window
-            from ..obs import costmodel as _cm
-
-            _cm.record_dispatch(f"wave:{pipeline}:u{int(u)}",
-                                site="wave")
-            _cm.record_dispatch("wave:digest", site="wave")
-        return (np.asarray(r), np.asarray(v), np.asarray(d),
-                np.asarray(ov))
-
-    # pow2-quantized budget: every distinct u_max is a distinct XLA
-    # program, so exact budgets would recompile on every wave whose
-    # divergence shifted slightly
-    u_need = int(v5_token_budget(lanes))
-    u_max = next_pow2(u_need)
-    if obs.enabled():
-        # token-budget headroom: the pow2 slack this fleet has before
-        # a divergence spike overflows the kernel and forces
-        # retries/host fallbacks
-        from ..obs import semantic as _sem
-
-        _sem.token_headroom(int(u_max) - u_need, "wave")
-    with obs.span("wave.dispatch", kernel=pipeline,
-                  rows=len(live_views), u_max=int(u_max),
-                  sharded=mesh is not None):
-        if mesh is not None:
-            from .mesh import sharded_merge_weave_v5
-
-            if pipeline == "v5w":
-                raise ValueError(
-                    "BENCH_KERNEL=v5w has no sharded wave step; use "
-                    "v5 or v5f under a mesh")
-            jl = {k: jnp.asarray(v) for k, v in lanes.items()}
-            rank, visible, overflow, digest, _tv, _nc, _n_ov = (
-                sharded_merge_weave_v5(mesh, jl, u_max=u_max,
-                                       k_max=u_max, pipeline=pipeline)
-            )
-            rank = np.asarray(rank)
-            visible = np.asarray(visible)
-            digest = np.asarray(digest)
-            overflow = np.asarray(overflow)
-            if obs.enabled():
-                # the sharded step computes kernel + digest in ONE
-                # compiled program
-                from ..obs import costmodel as _cm
-
-                _cm.record_dispatch(
-                    f"wave:sharded:{pipeline}:u{int(u_max)}",
-                    site="wave")
-        else:
-            rank, visible, digest, overflow = dispatch_v5(lanes, u_max)
-    n_retried = 0
-    if overflow.any():
-        # the token budget samples rows; a spiky unsampled row can
-        # overflow. Retry just those rows (unsharded — a handful of
-        # rows doesn't need the mesh) with a doubled budget before
-        # resorting to host merges. np.array: jax host buffers can be
-        # read-only.
-        rows = np.flatnonzero(overflow)
-        n_retried = len(rows)
-        obs.counter("wave.overflow_retry").inc(len(rows))
-        obs.event("wave.overflow_retry", rows=len(rows),
-                  u_max=int(u_max))
-        if obs.enabled():
-            _recovery.step("wave", "full", "double_budget",
-                           "token-overflow",
-                           uuid=str(pairs[0][0].ct.uuid),
-                           rows=n_retried)
-        sub = {k: lanes[k][rows] for k in LANE_KEYS5}
-        with obs.span("wave.dispatch.retry", rows=len(rows),
-                      u_max=int(2 * u_max)):
-            r2, v2, d2, ov2 = dispatch_v5(sub, 2 * u_max)
-        rank = np.array(rank)
-        visible = np.array(visible)
-        digest = np.array(digest)
-        overflow = np.array(overflow)
-        rank[rows] = r2
-        visible[rows] = v2
-        digest[rows] = d2
-        overflow[rows] = ov2
-
-    B = len(pairs)
-    full_rank = np.full((B, 2 * cap), 2 * cap, np.int32)
-    full_vis = np.zeros((B, 2 * cap), bool)
-    full_dig = np.zeros(B, np.uint32)
-    dig_valid = np.zeros(B, bool)
-    for j, i in enumerate(live):
-        if bool(overflow[j]):
-            a, b = pairs[i]
-            if obs.enabled():
-                # the ladder's last rung: still overflowing at the
-                # doubled budget, this pair runs the host merge
-                _recovery.step("wave", "double_budget", "host",
-                               "token-overflow",
-                               uuid=str(a.ct.uuid), pair=i)
-            try:
-                # budget blown: host path, correct
-                fallback[i] = a.merge(b)
-            except s.CausalError as err:  # corrupt AND overflowed
-                err.info["pair"] = i
-                poisoned[i] = err
-            views[i] = None
-            continue
-        full_rank[i] = rank[j]
-        full_vis[i] = visible[j]
-        full_dig[i] = digest[j]
-        dig_valid[i] = True
-    obs.counter("wave.pairs").inc(B)
-    obs.counter("wave.fallback").inc(len(fallback))
-    obs.counter("wave.poisoned").inc(len(poisoned))
-    if obs.enabled():
-        # semantic layer: digest agreement, staleness aging, and (on
-        # disagreement) one divergence event with site provenance
-        sem = _observe_semantics(pairs, full_dig, dig_valid, "wave")
-        # devprof wave-boundary sample: live device arrays + backend
-        # memory after the dispatch settle, so per-wave residency
-        # renders as a curve next to the dispatch spans
-        from ..obs import devprof
-
-        devprof.sample_device_memory("wave")
-        # the cost-vs-divergence join: ONE wave.cost event carrying
-        # this wave's dispatch count and program identities next to
-        # its token work size (the O(delta) axis), its lane width
-        # (the O(doc) axis) and the semantic digest summary
-        from ..obs import costmodel as _cm
-
-        # lanes/tokens are FLEET totals (lanes: the O(doc) transfer/
-        # scan width; tokens: worst-row estimate × rows — the kernel
-        # pads every row to the budget), same units as delta_ops
-        _cm.wave_cost(uuid=str(pairs[0][0].ct.uuid), pairs=B,
-                      lanes=2 * int(cap) * B,
-                      tokens=int(u_need) * len(live_views),
-                      token_budget=int(u_max) * len(live_views),
-                      full_bag=len(fallback), poisoned=len(poisoned),
-                      overflow_retries=n_retried, semantic=sem,
-                      path="full")
-    return WaveResult(pairs, views, cap, full_rank, full_vis, full_dig,
-                      fallback, pipeline, dig_valid,
-                      poisoned=poisoned)
+def last_kernel_hlo() -> str:
+    """The optimized HLO text of the v5 program the last unsharded
+    merge wave dispatched ("" before any). Its instructions' ``op_name``
+    metadata carry the kernel's phase scopes (``v5.A`` ... ``v5.F``),
+    which name a device trace's ops by phase. A compile-cache lookup:
+    call it outside any timed window."""
+    if not _LAST_KERNEL:
+        return ""
+    return _LAST_KERNEL["fn"].lower(
+        *_LAST_KERNEL["args"], **_LAST_KERNEL["kw"]).compile().as_text()

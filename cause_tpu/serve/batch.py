@@ -74,7 +74,15 @@ class BatchScheduler:
         bit-identical to per-tenant ``wave()`` calls.
         ``traces_by_uuid`` (PR 19, obs-on ticks) maps tenants to the
         trace ids riding this tick so each fused bucket span fans out
-        per-tenant "wave" child hops."""
+        per-tenant "wave" child hops. The ``serve.waves`` span's
+        ``full`` counts the tenants waved full-width one at a time; the
+        rest rode the delta buckets."""
+        with obs.span("serve.waves", tenants=len(sessions)) as sp:
+            digests = self._wave_fleet(sessions, traces_by_uuid)
+            sp.set(full=self.last_fallbacks)
+        return digests
+
+    def _wave_fleet(self, sessions, traces_by_uuid):
         self._traces_by_uuid = traces_by_uuid or {}
         digests: Dict[str, np.ndarray] = {}
         fallback: List[str] = []

@@ -217,6 +217,20 @@ class SyncService:
             raise s.CausalError(
                 "serve: batch for unknown tenant",
                 {"causes": {"unknown-tenant"}, "uuid": uuid})
+        with obs.span("serve.apply", ops=sum(e.ops for e in entries),
+                      batches=len(entries)):
+            self._coalesce_apply(sess, entries)
+        if wave:
+            sess.wave()
+        self.tenants[uuid]["applied_seq"] = max(
+            self.tenants[uuid]["applied_seq"],
+            max(e.seq for e in entries))
+        return sess
+
+    def _coalesce_apply(self, sess, entries: List) -> None:
+        """Coalesce one tenant's drained batches into one delta per
+        side, apply both through ``sync.apply_delta`` and splice them
+        into the session (``_apply_batches``' first half)."""
         left, right = sess.pairs[0]
         sides = [left, right]
         side_ids = (str(left.ct.site_id), str(right.ct.site_id))
@@ -246,12 +260,6 @@ class SyncService:
             if not pending:
                 break
         sess.update([(sides[0], sides[1])])
-        if wave:
-            sess.wave()
-        self.tenants[uuid]["applied_seq"] = max(
-            self.tenants[uuid]["applied_seq"],
-            max(e.seq for e in entries))
-        return sess
 
     def tick(self, max_ops: Optional[int] = None) -> dict:
         """One service tick: drain → apply/update per touched tenant →
@@ -271,11 +279,22 @@ class SyncService:
         (the queue always yields at least one batch)."""
         if _chaos.enabled() and _chaos.should_crash("serve.tick"):
             raise ServiceCrashed("chaos: crash point at serve.tick")
+        with obs.span("serve.tick") as sp:
+            return self._tick(max_ops, sp)
+
+    def _tick(self, max_ops: Optional[int], sp) -> dict:
         self.ticks += 1
         self.last_tick_us = time.time_ns() // 1000
         self._watchdog_firing = False
         entries = self.queue.drain(self.d_max if max_ops is None
                                    else max_ops)
+        # a batch's queue wait: admission to this drain (a replayed
+        # journal entry without its admission time waits 0)
+        now_us = time.time_ns() // 1000
+        waits = [max(0, now_us - e.ts_us) if e.ts_us else 0
+                 for e in entries]
+        sp.set(batches=len(entries), wait_us_sum=sum(waits),
+               wait_us_max=max(waits, default=0))
         by_tenant: Dict[str, List] = {}
         for e in entries:
             by_tenant.setdefault(e.uuid, []).append(e)
@@ -349,6 +368,7 @@ class SyncService:
         if snap is not None:
             self.controller.update(snap)
         ops = sum(e.ops for e in entries)
+        sp.set(ops=ops, tenants=len(by_tenant))
         if obs.enabled():
             obs.counter("serve.ticks").inc()
             obs.event("serve.tick", ops=ops,

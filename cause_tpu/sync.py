@@ -138,6 +138,11 @@ def apply_delta(handle, nodes: dict, _count_as_delta: bool = True):
     else takes the one-pass union."""
     if not nodes:
         return handle
+    with obs.span("sync.apply_delta", ops=len(nodes)):
+        return _apply_delta(handle, nodes, _count_as_delta)
+
+
+def _apply_delta(handle, nodes: dict, _count_as_delta: bool):
     sh = shadow(handle, nodes)
     incremental = (handle.ct.weaver == "pure"
                    and len(nodes) * 8 < len(handle.ct.nodes))

@@ -1,7 +1,7 @@
 """The v3 merge+weave kernel: sparse-irregular linearization.
 
-Profiling the v2 pipeline on a TPU v5e (scripts/profile_phases.py,
-1024 pairs x 10k nodes) showed the cost is NOT the id sort (~97 ms);
+Profiling the v2 pipeline phase by phase on a TPU v5e (1024 pairs x
+10k nodes) showed the cost is NOT the id sort (~97 ms);
 it is every gather/scatter/sort pass that runs at full lane width:
 the 2M-record sort-join cause resolution (~5.3 s), the full-width
 pointer-doubling host jump (~1.9 s), and the full-width visibility

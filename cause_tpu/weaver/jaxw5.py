@@ -68,14 +68,6 @@ __all__ = [
 ]
 
 
-def _stage_ck(*xs):
-    """Scalar checksum keeping every given array live (profiling)."""
-    total = jnp.float32(0)
-    for x in xs:
-        total = total + jnp.sum(x.astype(jnp.float32))
-    return total
-
-
 def _hint_kw(sorted_: bool = False, unique: bool = False) -> dict:
     """Scatter-annotation kwargs under ``CAUSE_TPU_SCATTER=hint``
     (trace-time A/B switch): an XLA TPU scatter serializes to handle
@@ -178,529 +170,524 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
     and visibility indexed by CONCAT lane (not by sorted position —
     there is no full-width sorted order here).
 
-    ``stage`` (static; profiling only) returns early with one scalar
-    checksum of that phase's live outputs, so a prefix of the pipeline
-    can be timed on hardware without dead-code elimination hiding it:
-    ``"A"`` segment ordering + explode/dedupe, ``"B"`` token
-    construction, ``"C"`` token sort + dedupe, ``"D"`` cause
-    resolution, ``"E"`` token-width ranking + kills. ``None`` (the
-    default, the only non-test caller mode) runs the full kernel.
+    Each phase runs under ``jax.named_scope("v5.<phase>")``: ``A``
+    segment ordering + explode/dedupe, ``B`` token construction, ``C``
+    token sort + dedupe, ``D`` cause resolution, ``E`` token-width
+    ranking + kills, ``F`` expansion to concat lanes. The scopes reach
+    the optimized HLO's ``op_name`` metadata (and nothing else), so a
+    device trace's ops can be named by phase. ``stage="_AB"`` is the
+    fused v5f pipeline's handoff after phase B (jaxw5f); ``None``, the
+    default, runs the full kernel.
     """
-    N = hi.shape[0]
-    S = sg_len.shape[0]
-    sidx = jnp.arange(S, dtype=jnp.int32)
-    BIG = I32_MAX
-
     # ================= A. segment ordering + explode/dedupe =========
-    kh = jnp.where(sg_valid, sg_min_hi, BIG)
-    kl = jnp.where(sg_valid, sg_min_lo, BIG)
-    s_mh, s_ml, s_src = sort_pairs((kh, kl, sidx), num_keys=2)
-    s_Mh = take1d(sg_max_hi, s_src)
-    s_Ml = take1d(sg_max_lo, s_src)
-    s_va = take1d(sg_valid, s_src)
-    s_len = jnp.where(s_va, take1d(sg_len, s_src), 0)
-    s_lane0 = take1d(sg_lane0, s_src)
-    s_dense = take1d(sg_dense, s_src)
-    s_tsp = take1d(sg_tail_special, s_src)
-    s_vsum = take1d(sg_vsum, s_src)
+    with jax.named_scope("v5.A"):
+        N = hi.shape[0]
+        S = sg_len.shape[0]
+        sidx = jnp.arange(S, dtype=jnp.int32)
+        BIG = I32_MAX
 
-    # head body fields (shared by the twin test and the E2 stabs)
-    s_hvc = take1d(vclass, jnp.clip(s_lane0, 0, N - 1))
-    c_lane = take1d(cci, jnp.clip(s_lane0, 0, N - 1))
-    has_c = s_va & (c_lane >= 0)
-    c_hi = jnp.where(has_c, take1d(hi, jnp.clip(c_lane, 0, N - 1)), -1)
-    c_lo = jnp.where(has_c, take1d(lo, jnp.clip(c_lane, 0, N - 1)), -1)
+        kh = jnp.where(sg_valid, sg_min_hi, BIG)
+        kl = jnp.where(sg_valid, sg_min_lo, BIG)
+        s_mh, s_ml, s_src = sort_pairs((kh, kl, sidx), num_keys=2)
+        s_Mh = take1d(sg_max_hi, s_src)
+        s_Ml = take1d(sg_max_lo, s_src)
+        s_va = take1d(sg_valid, s_src)
+        s_len = jnp.where(s_va, take1d(sg_len, s_src), 0)
+        s_lane0 = take1d(sg_lane0, s_src)
+        s_dense = take1d(sg_dense, s_src)
+        s_tsp = take1d(sg_tail_special, s_src)
+        s_vsum = take1d(sg_vsum, s_src)
 
-    # twin groups: adjacent exact-equal dense segments dedupe wholesale.
-    # Equality covers the endpoints, length, density, the head's value
-    # class and cause id, the tail-special flag, and the position
-    # -weighted vclass checksum (sg_vsum) — so a same-id segment whose
-    # INTERIOR body classes differ (a corrupt replica violating
-    # append-only) fails the test, explodes, and the node-level
-    # duplicate check reports the conflict. Host VALUES remain a
-    # host-side check (shared.union_nodes / WaveResult.merged): the
-    # device never sees them.
-    p_mh, p_ml = _shift1(s_mh, -1), _shift1(s_ml, -1)
-    same_prev = (
-        _eq(s_mh, s_ml, p_mh, p_ml)
-        & _eq(s_Mh, s_Ml, _shift1(s_Mh, -1), _shift1(s_Ml, -1))
-        & (s_len == _shift1(s_len, -1))
-        & s_dense & _shift1(s_dense, False)
-        & (s_hvc == _shift1(s_hvc, -1))
-        & (s_tsp == _shift1(s_tsp, False))
-        & (s_vsum == _shift1(s_vsum, -1))
-        & _eq(c_hi, c_lo, _shift1(c_hi, -1), _shift1(c_lo, -1))
-        & s_va & _shift1(s_va, False)
-        & (sidx > 0)
-    )
-    grp_start = ~same_prev
-    grp = jnp.cumsum(grp_start.astype(jnp.int32)) - 1
+        # head body fields (shared by the twin test and the E2 stabs)
+        s_hvc = take1d(vclass, jnp.clip(s_lane0, 0, N - 1))
+        c_lane = take1d(cci, jnp.clip(s_lane0, 0, N - 1))
+        has_c = s_va & (c_lane >= 0)
+        c_hi = jnp.where(has_c, take1d(hi, jnp.clip(c_lane, 0, N - 1)), -1)
+        c_lo = jnp.where(has_c, take1d(lo, jnp.clip(c_lane, 0, N - 1)), -1)
 
-    # per-group interval tables (twins share min/max by construction).
-    # Scatter indices: group ordinals at group starts (strictly
-    # increasing), everything else dumped into its own spread slot past
-    # S — unique by construction, so the scatter needs no duplicate
-    # handling (annotated under CAUSE_TPU_SCATTER=hint).
-    is_start = grp_start & s_va
-    gsl = jnp.where(is_start, grp, S + sidx)
-    uniq = _hint_kw(unique=True)
+        # twin groups: adjacent exact-equal dense segments dedupe wholesale.
+        # Equality covers the endpoints, length, density, the head's value
+        # class and cause id, the tail-special flag, and the position
+        # -weighted vclass checksum (sg_vsum) — so a same-id segment whose
+        # INTERIOR body classes differ (a corrupt replica violating
+        # append-only) fails the test, explodes, and the node-level
+        # duplicate check reports the conflict. Host VALUES remain a
+        # host-side check (shared.union_nodes / WaveResult.merged): the
+        # device never sees them.
+        p_mh, p_ml = _shift1(s_mh, -1), _shift1(s_ml, -1)
+        same_prev = (
+            _eq(s_mh, s_ml, p_mh, p_ml)
+            & _eq(s_Mh, s_Ml, _shift1(s_Mh, -1), _shift1(s_Ml, -1))
+            & (s_len == _shift1(s_len, -1))
+            & s_dense & _shift1(s_dense, False)
+            & (s_hvc == _shift1(s_hvc, -1))
+            & (s_tsp == _shift1(s_tsp, False))
+            & (s_vsum == _shift1(s_vsum, -1))
+            & _eq(c_hi, c_lo, _shift1(c_hi, -1), _shift1(c_lo, -1))
+            & s_va & _shift1(s_va, False)
+            & (sidx > 0)
+        )
+        grp_start = ~same_prev
+        grp = jnp.cumsum(grp_start.astype(jnp.int32)) - 1
 
-    def _gtable(vals, fill):
-        return jnp.full(2 * S, fill, jnp.int32).at[gsl].set(
-            jnp.where(is_start, vals, fill), **uniq)[:S]
+        # per-group interval tables (twins share min/max by construction).
+        # Scatter indices: group ordinals at group starts (strictly
+        # increasing), everything else dumped into its own spread slot past
+        # S — unique by construction, so the scatter needs no duplicate
+        # handling (annotated under CAUSE_TPU_SCATTER=hint).
+        is_start = grp_start & s_va
+        gsl = jnp.where(is_start, grp, S + sidx)
+        uniq = _hint_kw(unique=True)
 
-    g_mh = _gtable(s_mh, BIG)
-    g_ml = _gtable(s_ml, BIG)
-    g_Mh = _gtable(s_Mh, -1)
-    g_Ml = _gtable(s_Ml, -1)
+        def _gtable(vals, fill):
+            return jnp.full(2 * S, fill, jnp.int32).at[gsl].set(
+                jnp.where(is_start, vals, fill), **uniq)[:S]
 
-    # E1: overlap with any earlier group (prefix pair-max of maxes,
-    # exclusive) or the next group (its min is the smallest later min)
-    pmh, pml = _pair_cummax(g_Mh, g_Ml)
-    pmh_e, pml_e = _shift1(pmh, -1), _shift1(pml, -1)
-    gi = jnp.clip(grp, 0, S - 1)
-    ov_before = _le(s_mh, s_ml, take1d(pmh_e, gi), take1d(pml_e, gi))
-    nxt_mh = jnp.concatenate([g_mh[1:], jnp.full((1,), BIG, jnp.int32)])
-    nxt_ml = jnp.concatenate([g_ml[1:], jnp.full((1,), BIG, jnp.int32)])
-    ov_after = _le(take1d(nxt_mh, gi), take1d(nxt_ml, gi), s_Mh, s_Ml)
-    explode = s_va & (ov_before | ov_after)
+        g_mh = _gtable(s_mh, BIG)
+        g_ml = _gtable(s_ml, BIG)
+        g_Mh = _gtable(s_Mh, -1)
+        g_Ml = _gtable(s_Ml, -1)
 
-    # E2: head-cause stabs. Candidate = rightmost group with min <= c.
-    pg = _pair_search_le(g_mh, g_ml, c_hi, c_lo, S)
-    pgc = jnp.clip(pg, 0, S - 1)
-    # group tables for the stabbed group: len/tail-specialness of its
-    # representative member (first of group; twins agree)
-    rep = jnp.full(2 * S, 0, jnp.int32).at[gsl].set(
-        jnp.where(is_start, sidx, 0), **uniq)[:S]
-    rep_pg = take1d(rep, pgc)
-    r_len = take1d(s_len, rep_pg)
-    r_tsp = take1d(s_tsp, rep_pg)
-    gm_h, gm_l = take1d(g_mh, pgc), take1d(g_ml, pgc)
-    gM_h, gM_l = take1d(g_Mh, pgc), take1d(g_Ml, pgc)
-    stab = has_c & (pg >= 0) & _le(gm_h, gm_l, c_hi, c_lo) & (
-        _lt(c_hi, c_lo, gM_h, gM_l)
-        | (_eq(c_hi, c_lo, gM_h, gM_l) & r_tsp & (r_len > 1))
-    )
-    g_stabbed = jnp.zeros(S, bool).at[
-        jnp.where(stab, pgc, S - 1)
-    ].set(True, mode="drop")
-    # make the last slot honest (it may have been used as a dump)
-    g_stabbed = g_stabbed.at[S - 1].set(
-        jnp.any(stab & (pgc == S - 1)))
-    explode = explode | (s_va & take1d(g_stabbed, gi))
+        # E1: overlap with any earlier group (prefix pair-max of maxes,
+        # exclusive) or the next group (its min is the smallest later min)
+        pmh, pml = _pair_cummax(g_Mh, g_Ml)
+        pmh_e, pml_e = _shift1(pmh, -1), _shift1(pml, -1)
+        gi = jnp.clip(grp, 0, S - 1)
+        ov_before = _le(s_mh, s_ml, take1d(pmh_e, gi), take1d(pml_e, gi))
+        nxt_mh = jnp.concatenate([g_mh[1:], jnp.full((1,), BIG, jnp.int32)])
+        nxt_ml = jnp.concatenate([g_ml[1:], jnp.full((1,), BIG, jnp.int32)])
+        ov_after = _le(take1d(nxt_mh, gi), take1d(nxt_ml, gi), s_Mh, s_Ml)
+        explode = s_va & (ov_before | ov_after)
 
-    twin_drop = same_prev & ~explode
-    survive = s_va & ~explode & ~twin_drop
-    if stage == "A":
-        return _stage_ck(explode, survive, grp)
+        # E2: head-cause stabs. Candidate = rightmost group with min <= c.
+        pg = _pair_search_le(g_mh, g_ml, c_hi, c_lo, S)
+        pgc = jnp.clip(pg, 0, S - 1)
+        # group tables for the stabbed group: len/tail-specialness of its
+        # representative member (first of group; twins agree)
+        rep = jnp.full(2 * S, 0, jnp.int32).at[gsl].set(
+            jnp.where(is_start, sidx, 0), **uniq)[:S]
+        rep_pg = take1d(rep, pgc)
+        r_len = take1d(s_len, rep_pg)
+        r_tsp = take1d(s_tsp, rep_pg)
+        gm_h, gm_l = take1d(g_mh, pgc), take1d(g_ml, pgc)
+        gM_h, gM_l = take1d(g_Mh, pgc), take1d(g_Ml, pgc)
+        stab = has_c & (pg >= 0) & _le(gm_h, gm_l, c_hi, c_lo) & (
+            _lt(c_hi, c_lo, gM_h, gM_l)
+            | (_eq(c_hi, c_lo, gM_h, gM_l) & r_tsp & (r_len > 1))
+        )
+        g_stabbed = jnp.zeros(S, bool).at[
+            jnp.where(stab, pgc, S - 1)
+        ].set(True, mode="drop")
+        # make the last slot honest (it may have been used as a dump)
+        g_stabbed = g_stabbed.at[S - 1].set(
+            jnp.any(stab & (pgc == S - 1)))
+        explode = explode | (s_va & take1d(g_stabbed, gi))
+
+        twin_drop = same_prev & ~explode
+        survive = s_va & ~explode & ~twin_drop
 
     # ================= B. token construction ========================
-    tok_cnt = jnp.where(survive, 1, jnp.where(s_va & explode, s_len, 0))
-    tc_cum = jnp.cumsum(tok_cnt)
-    tb = tc_cum - tok_cnt  # exclusive: first token slot per sorted seg
-    n_tok = tc_cum[-1]
-    U = u_max
-    uidx = jnp.arange(U, dtype=jnp.int32)
-    u_ok = uidx < jnp.minimum(n_tok, U)
-    overflow_u = n_tok > U
+    with jax.named_scope("v5.B"):
+        tok_cnt = jnp.where(survive, 1, jnp.where(s_va & explode, s_len, 0))
+        tc_cum = jnp.cumsum(tok_cnt)
+        tb = tc_cum - tok_cnt  # exclusive: first token slot per sorted seg
+        n_tok = tc_cum[-1]
+        U = u_max
+        uidx = jnp.arange(U, dtype=jnp.int32)
+        u_ok = uidx < jnp.minimum(n_tok, U)
+        overflow_u = n_tok > U
 
-    owner = searchsorted_iota_right(tc_cum, U)
-    oc = jnp.clip(owner, 0, S - 1)
-    off = uidx - take1d(tb, oc)
-    o_expl = take1d(s_va, oc) & (~take1d(survive, oc))
-    t_lane = jnp.clip(
-        take1d(s_lane0, oc) + jnp.where(o_expl, off, 0), 0, N - 1
-    )
-    t_hi = jnp.where(u_ok, take1d(hi, t_lane), BIG)
-    t_lo = jnp.where(u_ok, take1d(lo, t_lane), BIG)
-    t_len = jnp.where(u_ok, jnp.where(o_expl, 1, take1d(s_len, oc)), 0)
-    t_vc = jnp.where(u_ok, take1d(vclass, t_lane), 0)
-    t_tail_lane = t_lane + t_len - 1
-    t_tsp = jnp.where(
-        o_expl, t_vc > 0, take1d(s_tsp, oc)
-    ) & u_ok
-    if stage == "B":
-        return _stage_ck(t_hi, t_lo, t_len, t_tsp)
+        owner = searchsorted_iota_right(tc_cum, U)
+        oc = jnp.clip(owner, 0, S - 1)
+        off = uidx - take1d(tb, oc)
+        o_expl = take1d(s_va, oc) & (~take1d(survive, oc))
+        t_lane = jnp.clip(
+            take1d(s_lane0, oc) + jnp.where(o_expl, off, 0), 0, N - 1
+        )
+        t_hi = jnp.where(u_ok, take1d(hi, t_lane), BIG)
+        t_lo = jnp.where(u_ok, take1d(lo, t_lane), BIG)
+        t_len = jnp.where(u_ok, jnp.where(o_expl, 1, take1d(s_len, oc)), 0)
+        t_vc = jnp.where(u_ok, take1d(vclass, t_lane), 0)
+        t_tail_lane = t_lane + t_len - 1
+        t_tsp = jnp.where(
+            o_expl, t_vc > 0, take1d(s_tsp, oc)
+        ) & u_ok
 
-    # token_of_lane machinery (PRESORT token ids). A cause lane inside
-    # a twin-DROPPED segment copy (tree B's own copy of the shared
-    # base) must resolve to the KEPT twin's token: group-start fill
-    # gsp redirects any twin member to its group's first (kept) member.
-    inv_s = jnp.zeros(S, jnp.int32).at[s_src].set(sidx, **uniq)
-    seg_expl_sorted = s_va & explode
-    gsp = lax.cummax(jnp.where(grp_start, sidx, -1))
+        # token_of_lane machinery (PRESORT token ids). A cause lane inside
+        # a twin-DROPPED segment copy (tree B's own copy of the shared
+        # base) must resolve to the KEPT twin's token: group-start fill
+        # gsp redirects any twin member to its group's first (kept) member.
+        inv_s = jnp.zeros(S, jnp.int32).at[s_src].set(sidx, **uniq)
+        seg_expl_sorted = s_va & explode
+        gsp = lax.cummax(jnp.where(grp_start, sidx, -1))
 
-    def token_of_lane(p):
-        pc = jnp.clip(p, 0, N - 1)
-        m = jnp.clip(take1d(seg, pc), 0, S - 1)
-        ss2 = take1d(inv_s, m)
-        ex = take1d(seg_expl_sorted, ss2)
-        owner_ss = jnp.where(ex, ss2, take1d(gsp, ss2))
-        return (take1d(tb, owner_ss)
-                + jnp.where(ex, pc - take1d(sg_lane0, m), 0)).astype(jnp.int32)
+        def token_of_lane(p):
+            pc = jnp.clip(p, 0, N - 1)
+            m = jnp.clip(take1d(seg, pc), 0, S - 1)
+            ss2 = take1d(inv_s, m)
+            ex = take1d(seg_expl_sorted, ss2)
+            owner_ss = jnp.where(ex, ss2, take1d(gsp, ss2))
+            return (take1d(tb, owner_ss)
+                    + jnp.where(ex, pc - take1d(sg_lane0, m), 0)
+                    ).astype(jnp.int32)
 
-    if stage == "_AB":
-        # internal handoff for the fused v5f pipeline (jaxw5f): every
-        # phase-A/B product the token kernels consume, plus the
-        # coverage inputs the F glue needs. Not a profiling stage —
-        # returns a namespace of traced arrays, so only jaxw5f calls
-        # it (inside its own jit), never the jitted entry points.
-        from types import SimpleNamespace
+        if stage == "_AB":
+            # internal handoff for the fused v5f pipeline (jaxw5f): every
+            # phase-A/B product the token kernels consume, plus the
+            # coverage inputs the F glue needs. Not a profiling stage —
+            # returns a namespace of traced arrays, so only jaxw5f calls
+            # it (inside its own jit), never the jitted entry points.
+            from types import SimpleNamespace
 
-        return SimpleNamespace(
-            t_hi=t_hi, t_lo=t_lo, t_len=t_len, t_vc=t_vc,
-            t_tsp=t_tsp, t_lane=t_lane, token_of_lane=token_of_lane,
-            overflow_u=overflow_u, survive=survive, inv_s=inv_s,
-            uidx=uidx)
+            return SimpleNamespace(
+                t_hi=t_hi, t_lo=t_lo, t_len=t_len, t_vc=t_vc,
+                t_tsp=t_tsp, t_lane=t_lane, token_of_lane=token_of_lane,
+                overflow_u=overflow_u, survive=survive, inv_s=inv_s,
+                uidx=uidx)
 
     # ================= C. sort tokens, dedupe =======================
-    # With a network sort (bitonic/pallas) the payload fields RIDE the
-    # sort — one roll+select per stage each, all streaming — instead of
-    # five post-sort permutation gathers (the expensive primitive the
-    # strategy exists to avoid). The matrix rank-sort rides too: its
-    # payload apply is a streaming rowgather per operand, so carrying
-    # payloads keeps a sort=matrix-only A/B free of per-element
-    # gathers. With the default comparator sort, extra variadic
-    # operands slow the comparator, so the gather form stays.
-    # Identical results either way: same keys, same implicit-iota
-    # stability, and payload-carry == gather-by-permutation.
-    su_src_in = uidx
-    ride = resolve("CAUSE_TPU_SORT") in ("bitonic", "pallas", "matrix")
-    if ride:
-        (st_hi, st_lo, t_src, sv_len, sv_vc, sv_tsp_i,
-         sv_lane) = sort_pairs(
-            (t_hi, t_lo, su_src_in, t_len, t_vc,
-             t_tsp.astype(jnp.int32), t_lane), num_keys=2)
-        sv_tsp = sv_tsp_i.astype(bool)
-        sv_tail_lane = sv_lane + sv_len - 1  # == permuted t_tail_lane
-    else:
-        st_hi, st_lo, t_src = sort_pairs((t_hi, t_lo, su_src_in),
-                                         num_keys=2)
-        g = lambda arr: take1d(arr, t_src)  # presort -> sorted order
-        sv_len, sv_vc, sv_tsp = g(t_len), g(t_vc), g(t_tsp)
-        sv_lane, sv_tail_lane = g(t_lane), g(t_tail_lane)
-    inv_t = jnp.zeros(U, jnp.int32).at[t_src].set(uidx, **uniq)
+    with jax.named_scope("v5.C"):
+        # With a network sort (bitonic/pallas) the payload fields RIDE the
+        # sort — one roll+select per stage each, all streaming — instead of
+        # five post-sort permutation gathers (the expensive primitive the
+        # strategy exists to avoid). The matrix rank-sort rides too: its
+        # payload apply is a streaming rowgather per operand, so carrying
+        # payloads keeps a sort=matrix-only A/B free of per-element
+        # gathers. With the default comparator sort, extra variadic
+        # operands slow the comparator, so the gather form stays.
+        # Identical results either way: same keys, same implicit-iota
+        # stability, and payload-carry == gather-by-permutation.
+        su_src_in = uidx
+        ride = resolve("CAUSE_TPU_SORT") in ("bitonic", "pallas", "matrix")
+        if ride:
+            (st_hi, st_lo, t_src, sv_len, sv_vc, sv_tsp_i,
+             sv_lane) = sort_pairs(
+                (t_hi, t_lo, su_src_in, t_len, t_vc,
+                 t_tsp.astype(jnp.int32), t_lane), num_keys=2)
+            sv_tsp = sv_tsp_i.astype(bool)
+            sv_tail_lane = sv_lane + sv_len - 1  # == permuted t_tail_lane
+        else:
+            st_hi, st_lo, t_src = sort_pairs((t_hi, t_lo, su_src_in),
+                                             num_keys=2)
+            g = lambda arr: take1d(arr, t_src)  # presort -> sorted order
+            sv_len, sv_vc, sv_tsp = g(t_len), g(t_vc), g(t_tsp)
+            sv_lane, sv_tail_lane = g(t_lane), g(t_tail_lane)
+        inv_t = jnp.zeros(U, jnp.int32).at[t_src].set(uidx, **uniq)
 
-    tva = ~((st_hi == BIG) & (st_lo == BIG))
-    sdup = (
-        _eq(st_hi, st_lo, _shift1(st_hi, -1), _shift1(st_lo, -1))
-        & (uidx > 0) & tva
-    )
-    keep_t = tva & ~sdup
-    if stage == "C":
-        return _stage_ck(st_hi, keep_t, sv_lane, inv_t)
+        tva = ~((st_hi == BIG) & (st_lo == BIG))
+        sdup = (
+            _eq(st_hi, st_lo, _shift1(st_hi, -1), _shift1(st_lo, -1))
+            & (uidx > 0) & tva
+        )
+        keep_t = tva & ~sdup
 
     # ================= D. token cause resolution ====================
-    cl = jnp.where(tva, take1d(cci, jnp.clip(sv_lane, 0, N - 1)), -1)
-    cause_u = token_of_lane(cl)
-    cause_su_raw = take1d(inv_t, jnp.clip(cause_u, 0, U - 1))
-    # redirect to the kept head of a duplicate token group: dups are
-    # adjacent after the sort, so a kept-head fill redirects them
-    thead = lax.cummax(jnp.where(keep_t, uidx, -1))
-    cause_su = jnp.where(
-        cl >= 0, take1d(thead, jnp.clip(cause_su_raw, 0, U - 1)), 0
-    ).astype(jnp.int32)
+    with jax.named_scope("v5.D"):
+        cl = jnp.where(tva, take1d(cci, jnp.clip(sv_lane, 0, N - 1)), -1)
+        cause_u = token_of_lane(cl)
+        cause_su_raw = take1d(inv_t, jnp.clip(cause_u, 0, U - 1))
+        # redirect to the kept head of a duplicate token group: dups are
+        # adjacent after the sort, so a kept-head fill redirects them
+        thead = lax.cummax(jnp.where(keep_t, uidx, -1))
+        cause_su = jnp.where(
+            cl >= 0, take1d(thead, jnp.clip(cause_su_raw, 0, U - 1)), 0
+        ).astype(jnp.int32)
 
-    special_t = keep_t & (sv_vc > 0)
-    is_root_t = keep_t & (uidx == 0)
-    rel_t = keep_t & ~is_root_t
+        special_t = keep_t & (sv_vc > 0)
+        is_root_t = keep_t & (uidx == 0)
+        rel_t = keep_t & ~is_root_t
 
-    # host walk (lane-level, at token width): first non-special lane
-    # on the cause chain
-    def wcond(c):
-        p, i = c
-        pc = jnp.clip(p, 0, N - 1)
-        on = rel_t & ~special_t & (p >= 0) & (take1d(vclass, pc) > 0)
-        return (i < N) & jnp.any(on)
+        # host walk (lane-level, at token width): first non-special lane
+        # on the cause chain
+        def wcond(c):
+            p, i = c
+            pc = jnp.clip(p, 0, N - 1)
+            on = rel_t & ~special_t & (p >= 0) & (take1d(vclass, pc) > 0)
+            return (i < N) & jnp.any(on)
 
-    def wbody(c):
-        p, i = c
-        pc = jnp.clip(p, 0, N - 1)
-        on = rel_t & ~special_t & (p >= 0) & (take1d(vclass, pc) > 0)
-        return jnp.where(on, take1d(cci, pc), p), i + 1
+        def wbody(c):
+            p, i = c
+            pc = jnp.clip(p, 0, N - 1)
+            on = rel_t & ~special_t & (p >= 0) & (take1d(vclass, pc) > 0)
+            return jnp.where(on, take1d(cci, pc), p), i + 1
 
-    host_lane, _ = lax.while_loop(wcond, wbody, (cl, jnp.int32(0)))
-    host_su = jnp.where(
-        host_lane >= 0,
-        take1d(thead,
-               jnp.clip(take1d(inv_t,
-                               jnp.clip(token_of_lane(host_lane),
-                                        0, U - 1)), 0, U - 1)),
-        0,
-    ).astype(jnp.int32)
-    parent_su = jnp.where(special_t, cause_su, host_su)
+        host_lane, _ = lax.while_loop(wcond, wbody, (cl, jnp.int32(0)))
+        host_su = jnp.where(
+            host_lane >= 0,
+            take1d(thead,
+                   jnp.clip(take1d(inv_t,
+                                   jnp.clip(token_of_lane(host_lane),
+                                            0, U - 1)), 0, U - 1)),
+            0,
+        ).astype(jnp.int32)
+        parent_su = jnp.where(special_t, cause_su, host_su)
 
-    conflict = jnp.any(
-        sdup & (
-            (sv_vc != _shift1(sv_vc, 0))
-            | (cause_su != _shift1(cause_su, 0))
-            | (sv_len != _shift1(sv_len, 0))
+        conflict = jnp.any(
+            sdup & (
+                (sv_vc != _shift1(sv_vc, 0))
+                | (cause_su != _shift1(cause_su, 0))
+                | (sv_len != _shift1(sv_len, 0))
+            )
         )
-    )
-    if stage == "D":
-        return _stage_ck(parent_su, cause_su, conflict)
 
     # ================= E. v4 pipeline at token width ================
-    wcum = jnp.cumsum(jnp.where(keep_t, sv_len, 0))
-    wstart = wcum - jnp.where(keep_t, sv_len, 0)
-    n_kept_nodes = wcum[-1]
+    with jax.named_scope("v5.E"):
+        wcum = jnp.cumsum(jnp.where(keep_t, sv_len, 0))
+        wstart = wcum - jnp.where(keep_t, sv_len, 0)
+        n_kept_nodes = wcum[-1]
 
-    sp_pack = lax.cummax(
-        jnp.where(keep_t, uidx * 2 + sv_tsp.astype(jnp.int32), -1)
-    )
-    sp_prev = _shift1(sp_pack, -1)
-    prev_kept = jnp.where(sp_prev >= 0, sp_prev >> 1, -1)
-    prev_kept_tsp = (sp_prev >= 0) & (sp_prev % 2 == 1)
+        sp_pack = lax.cummax(
+            jnp.where(keep_t, uidx * 2 + sv_tsp.astype(jnp.int32), -1)
+        )
+        sp_prev = _shift1(sp_pack, -1)
+        prev_kept = jnp.where(sp_prev >= 0, sp_prev >> 1, -1)
+        prev_kept_tsp = (sp_prev >= 0) & (sp_prev % 2 == 1)
 
-    adj = rel_t & (cause_su == prev_kept) & (prev_kept >= 0)
-    host_case = adj & ~special_t & prev_kept_tsp
-    irregular = rel_t & (~adj | host_case)
+        adj = rel_t & (cause_su == prev_kept) & (prev_kept >= 0)
+        host_case = adj & ~special_t & prev_kept_tsp
+        irregular = rel_t & (~adj | host_case)
 
-    extra = jnp.zeros(U, jnp.int32).at[
-        jnp.where(irregular, parent_su, U - 1)
-    ].add(1, mode="drop")
-    extra = extra.at[U - 1].set(
-        jnp.sum(jnp.where(irregular & (parent_su == U - 1), 1, 0)))
-    ec_pack = lax.cummax(
-        jnp.where(keep_t, uidx * 2 + (extra > 0).astype(jnp.int32), -1)
-    )
-    ec_prev = _shift1(ec_pack, -1)
-    prev_contested = (ec_prev >= 0) & (ec_prev % 2 == 1)
-    glued = adj & ~host_case & ~prev_contested
+        extra = jnp.zeros(U, jnp.int32).at[
+            jnp.where(irregular, parent_su, U - 1)
+        ].add(1, mode="drop")
+        extra = extra.at[U - 1].set(
+            jnp.sum(jnp.where(irregular & (parent_su == U - 1), 1, 0)))
+        ec_pack = lax.cummax(
+            jnp.where(keep_t, uidx * 2 + (extra > 0).astype(jnp.int32), -1)
+        )
+        ec_prev = _shift1(ec_pack, -1)
+        prev_contested = (ec_prev >= 0) & (ec_prev % 2 == 1)
+        glued = adj & ~host_case & ~prev_contested
 
-    run_start = keep_t & ~glued
-    rs_cum = jnp.cumsum(run_start.astype(jnp.int32))
-    run_id = rs_cum - 1
-    n_runs = rs_cum[-1]
-    overflow_k = n_runs > k_max
+        run_start = keep_t & ~glued
+        rs_cum = jnp.cumsum(run_start.astype(jnp.int32))
+        run_id = rs_cum - 1
+        n_runs = rs_cum[-1]
+        overflow_k = n_runs > k_max
 
-    targets = jnp.arange(1, k_max + 1, dtype=jnp.int32)
-    head_tok = searchsorted_targets_left(rs_cum, k_max)
-    r_valid = targets <= jnp.minimum(n_runs, k_max)
-    hc = jnp.clip(head_tok, 0, U - 1)
+        targets = jnp.arange(1, k_max + 1, dtype=jnp.int32)
+        head_tok = searchsorted_targets_left(rs_cum, k_max)
+        r_valid = targets <= jnp.minimum(n_runs, k_max)
+        hc = jnp.clip(head_tok, 0, U - 1)
 
-    h_parent = jnp.where(
-        take1d(irregular, hc), take1d(parent_su, hc),
-        jnp.where(take1d(adj, hc), take1d(prev_kept, hc), -1),
-    )
-    h_parent = jnp.where(r_valid & ~take1d(is_root_t, hc), h_parent, -1)
-    parent_run = jnp.where(
-        h_parent >= 0, take1d(run_id, jnp.clip(h_parent, 0, U - 1)), -1
-    ).astype(jnp.int32)
+        h_parent = jnp.where(
+            take1d(irregular, hc), take1d(parent_su, hc),
+            jnp.where(take1d(adj, hc), take1d(prev_kept, hc), -1),
+        )
+        h_parent = jnp.where(r_valid & ~take1d(is_root_t, hc), h_parent, -1)
+        parent_run = jnp.where(
+            h_parent >= 0, take1d(run_id, jnp.clip(h_parent, 0, U - 1)), -1
+        ).astype(jnp.int32)
 
-    h_special = take1d(special_t, hc)
-    h_w = take1d(wstart, hc)
-    nxt_w = jnp.concatenate([h_w[1:], h_w[:1]])
-    run_w = jnp.where(
-        r_valid,
-        jnp.where(targets == n_runs, n_kept_nodes - h_w, nxt_w - h_w),
-        0,
-    ).astype(jnp.int32)
+        h_special = take1d(special_t, hc)
+        h_w = take1d(wstart, hc)
+        nxt_w = jnp.concatenate([h_w[1:], h_w[:1]])
+        run_w = jnp.where(
+            r_valid,
+            jnp.where(targets == n_runs, n_kept_nodes - h_w, nxt_w - h_w),
+            0,
+        ).astype(jnp.int32)
 
-    parent_sort = jnp.where(r_valid & (parent_run >= 0), parent_run, k_max)
-    packed = parent_sort * 2 + (~h_special).astype(jnp.int32)
-    kidx_r = jnp.arange(k_max, dtype=jnp.int32)
-    sord = sort_pairs((packed, -hc, kidx_r), num_keys=2)[2]
-    fc, ns = _link_children(sord, parent_sort)
-    parent_up = jnp.where(r_valid & (parent_run >= 0), parent_run, -1)
-    if euler == "walk":
-        from .pallas_ops import euler_walk
+        parent_sort = jnp.where(r_valid & (parent_run >= 0), parent_run, k_max)
+        packed = parent_sort * 2 + (~h_special).astype(jnp.int32)
+        kidx_r = jnp.arange(k_max, dtype=jnp.int32)
+        sord = sort_pairs((packed, -hc, kidx_r), num_keys=2)[2]
+        fc, ns = _link_children(sord, parent_sort)
+        parent_up = jnp.where(r_valid & (parent_run >= 0), parent_run, -1)
+        if euler == "walk":
+            from .pallas_ops import euler_walk
 
-        base_run = euler_walk(fc, ns, parent_up, run_w, k_max)
-    else:
-        base_run, _ = _euler_rank(fc, ns, parent_up, run_w)
+            base_run = euler_walk(fc, ns, parent_up, run_w, k_max)
+        else:
+            base_run, _ = _euler_rank(fc, ns, parent_up, run_w)
 
-    # expand run bases to token bases (node units): delta-scatter at
-    # run-head tokens + one cumsum over U, then add within-run offset
-    delta = jnp.where(
-        r_valid,
-        base_run - jnp.concatenate([jnp.zeros((1,), base_run.dtype),
-                                    base_run[:-1]]),
-        0,
-    )
-    # valid targets are a prefix with strictly increasing head tokens;
-    # invalid ones dump into spread slots past U — the index stream is
-    # globally sorted AND unique by construction (no shared dump slot,
-    # no collision fix-up needed)
-    scat_du = jnp.where(r_valid, hc, U + kidx_r)
-    delta_u = jnp.zeros(U + k_max, jnp.int32).at[scat_du].set(
-        delta.astype(jnp.int32),
-        **_hint_kw(sorted_=True, unique=True))[:U]
-    base_ff = jnp.cumsum(delta_u)
-    ffw = lax.cummax(jnp.where(run_start, wstart, -1))
-    rank_tok = jnp.where(
-        keep_t, base_ff + (wstart - ffw), N
-    ).astype(jnp.int32)
+        # expand run bases to token bases (node units): delta-scatter at
+        # run-head tokens + one cumsum over U, then add within-run offset
+        delta = jnp.where(
+            r_valid,
+            base_run - jnp.concatenate([jnp.zeros((1,), base_run.dtype),
+                                        base_run[:-1]]),
+            0,
+        )
+        # valid targets are a prefix with strictly increasing head tokens;
+        # invalid ones dump into spread slots past U — the index stream is
+        # globally sorted AND unique by construction (no shared dump slot,
+        # no collision fix-up needed)
+        scat_du = jnp.where(r_valid, hc, U + kidx_r)
+        delta_u = jnp.zeros(U + k_max, jnp.int32).at[scat_du].set(
+            delta.astype(jnp.int32),
+            **_hint_kw(sorted_=True, unique=True))[:U]
+        base_ff = jnp.cumsum(delta_u)
+        ffw = lax.cummax(jnp.where(run_start, wstart, -1))
+        rank_tok = jnp.where(
+            keep_t, base_ff + (wstart - ffw), N
+        ).astype(jnp.int32)
 
-    # -------- token-level kills (victims as lanes) ------------------
-    hideish = (sv_vc == VCLASS_HIDE) | (sv_vc == VCLASS_H_HIDE)
-    kg = glued & hideish
-    vict_inrun = jnp.where(
-        kg, take1d(sv_tail_lane, jnp.clip(prev_kept, 0, U - 1)), N
-    )
+        # -------- token-level kills (victims as lanes) ------------------
+        hideish = (sv_vc == VCLASS_HIDE) | (sv_vc == VCLASS_H_HIDE)
+        kg = glued & hideish
+        vict_inrun = jnp.where(
+            kg, take1d(sv_tail_lane, jnp.clip(prev_kept, 0, U - 1)), N
+        )
 
-    # preorder-successor run: the run with the next-larger base. base
-    # values are node-unit positions (up to N), so find successors by
-    # sorting runs on base instead of scattering over node positions.
-    bkey = jnp.where(r_valid, base_run, BIG)
-    b_sorted, b_src = sort_pairs((bkey, kidx_r), num_keys=1)
-    succ_in_sorted = jnp.concatenate([
-        b_src[1:], jnp.full((1,), -1, jnp.int32)
-    ])
-    succ_valid = jnp.concatenate([
-        b_sorted[1:] != BIG, jnp.zeros((1,), bool)
-    ])
-    succ_of = jnp.full(k_max, -1, jnp.int32).at[b_src].set(
-        jnp.where(succ_valid, succ_in_sorted, -1), **uniq
-    )
-    succ_run = jnp.where(r_valid, succ_of, -1)
-    s_c = jnp.clip(
-        jnp.where(succ_run >= 0,
-                  take1d(hc, jnp.clip(succ_run, 0, k_max - 1)), 0),
-        0, U - 1,
-    )
-    s_is_hide = (succ_run >= 0) & take1d(hideish, s_c)
-    nxt_head = jnp.concatenate([hc[1:], hc[:1]])
-    tail_tok = jnp.where(
-        targets == n_runs,
-        jnp.maximum(sp_pack[-1] >> 1, 0),
-        take1d(prev_kept, jnp.clip(nxt_head, 0, U - 1)),
-    ).astype(jnp.int32)
-    t_cc = jnp.clip(tail_tok, 0, U - 1)
-    # succ head's cause must BE the run's tail node — compared at
-    # token level (cause_su is duplicate-redirected; a hide arriving
-    # from another replica names its own dropped copy of the tail)
-    kill_tail = r_valid & s_is_hide & (take1d(cause_su, s_c) == tail_tok)
-    vict_tail = jnp.where(kill_tail, take1d(sv_tail_lane, t_cc), N)
-    if stage == "E":
-        # conflict included so prefix increments stay strictly
-        # cumulative over stage D's reduction
-        return _stage_ck(rank_tok, vict_inrun, vict_tail, kill_tail,
-                         conflict)
+        # preorder-successor run: the run with the next-larger base. base
+        # values are node-unit positions (up to N), so find successors by
+        # sorting runs on base instead of scattering over node positions.
+        bkey = jnp.where(r_valid, base_run, BIG)
+        b_sorted, b_src = sort_pairs((bkey, kidx_r), num_keys=1)
+        succ_in_sorted = jnp.concatenate([
+            b_src[1:], jnp.full((1,), -1, jnp.int32)
+        ])
+        succ_valid = jnp.concatenate([
+            b_sorted[1:] != BIG, jnp.zeros((1,), bool)
+        ])
+        succ_of = jnp.full(k_max, -1, jnp.int32).at[b_src].set(
+            jnp.where(succ_valid, succ_in_sorted, -1), **uniq
+        )
+        succ_run = jnp.where(r_valid, succ_of, -1)
+        s_c = jnp.clip(
+            jnp.where(succ_run >= 0,
+                      take1d(hc, jnp.clip(succ_run, 0, k_max - 1)), 0),
+            0, U - 1,
+        )
+        s_is_hide = (succ_run >= 0) & take1d(hideish, s_c)
+        nxt_head = jnp.concatenate([hc[1:], hc[:1]])
+        tail_tok = jnp.where(
+            targets == n_runs,
+            jnp.maximum(sp_pack[-1] >> 1, 0),
+            take1d(prev_kept, jnp.clip(nxt_head, 0, U - 1)),
+        ).astype(jnp.int32)
+        t_cc = jnp.clip(tail_tok, 0, U - 1)
+        # succ head's cause must BE the run's tail node — compared at
+        # token level (cause_su is duplicate-redirected; a hide arriving
+        # from another replica names its own dropped copy of the tail)
+        kill_tail = r_valid & s_is_hide & (take1d(cause_su, s_c) == tail_tok)
+        vict_tail = jnp.where(kill_tail, take1d(sv_tail_lane, t_cc), N)
 
     # ================= F. expansion to concat lanes =================
-    # token base + token lane, in LANE order (sort tokens by lane) so
-    # deltas scatter + cumsum reconstructs per-lane values without any
-    # full-width gather
-    lane_key = jnp.where(keep_t & (rank_tok < N), sv_lane, N)
-    if ride:  # rank rides the lane sort (see phase C note)
-        lk, tok_at, tb_l = sort_pairs((lane_key, uidx, rank_tok),
-                                      num_keys=1)
-    else:
-        lk, tok_at = sort_pairs((lane_key, uidx), num_keys=1)
-        tb_l = take1d(rank_tok, tok_at)
+    with jax.named_scope("v5.F"):
+        # token base + token lane, in LANE order (sort tokens by lane) so
+        # deltas scatter + cumsum reconstructs per-lane values without any
+        # full-width gather
+        lane_key = jnp.where(keep_t & (rank_tok < N), sv_lane, N)
+        if ride:  # rank rides the lane sort (see phase C note)
+            lk, tok_at, tb_l = sort_pairs((lane_key, uidx, rank_tok),
+                                          num_keys=1)
+        else:
+            lk, tok_at = sort_pairs((lane_key, uidx), num_keys=1)
+            tb_l = take1d(rank_tok, tok_at)
 
-    # pieces shared by both F backends: per-lane coverage flags, the
-    # token-level kill scatters (victims can duplicate — genuine
-    # scatters, U-width, stay in XLA either way), and the root lane
-    seg_cov = sg_valid & take1d(survive, inv_s)
-    killed_sc = jnp.zeros(N + 1, bool)
-    killed_sc = killed_sc.at[jnp.where(kg, vict_inrun, N)].set(
-        True, mode="drop")
-    killed_sc = killed_sc.at[jnp.where(kill_tail, vict_tail, N)].set(
-        True, mode="drop")
-    root_lane = jnp.zeros(N, bool).at[
-        jnp.clip(sv_lane[0], 0, N - 1)
-    ].set(keep_t[0])
+        # pieces shared by both F backends: per-lane coverage flags, the
+        # token-level kill scatters (victims can duplicate — genuine
+        # scatters, U-width, stay in XLA either way), and the root lane
+        seg_cov = sg_valid & take1d(survive, inv_s)
+        killed_sc = jnp.zeros(N + 1, bool)
+        killed_sc = killed_sc.at[jnp.where(kg, vict_inrun, N)].set(
+            True, mode="drop")
+        killed_sc = killed_sc.at[jnp.where(kill_tail, vict_tail, N)].set(
+            True, mode="drop")
+        root_lane = jnp.zeros(N, bool).at[
+            jnp.clip(sv_lane[0], 0, N - 1)
+        ].set(keep_t[0])
 
-    if resolve("CAUSE_TPU_FPHASE") == "pallas" and N % 128 == 0:
-        # fused tile-window expansion (pallas_fphase): no scatters, no
-        # cumsums — per-tile compare-select windows in VMEM compute
-        # the fills and coverage; visibility is a vectorized second
-        # pass in the same kernel
-        from .pallas_fphase import fphase_expand
+        if resolve("CAUSE_TPU_FPHASE") == "pallas" and N % 128 == 0:
+            # fused tile-window expansion (pallas_fphase): no scatters, no
+            # cumsums — per-tile compare-select windows in VMEM compute
+            # the fills and coverage; visibility is a vectorized second
+            # pass in the same kernel
+            from .pallas_fphase import fphase_expand
 
-        cov_start = jnp.where(seg_cov, sg_lane0, N).astype(jnp.int32)
-        cov_end = jnp.where(
-            seg_cov, sg_lane0 + sg_len, 0).astype(jnp.int32)
-        cs, ce = sort_pairs((cov_start, cov_end), num_keys=1)
-        killed_ext = killed_sc[:N] | root_lane
-        flags = (valid.astype(jnp.int32)
-                 | (killed_ext.astype(jnp.int32) << 1))
-        rank_lane, visible = fphase_expand(
-            lk, tb_l, cs, ce, vclass, seg, flags)
+            cov_start = jnp.where(seg_cov, sg_lane0, N).astype(jnp.int32)
+            cov_end = jnp.where(
+                seg_cov, sg_lane0 + sg_len, 0).astype(jnp.int32)
+            cs, ce = sort_pairs((cov_start, cov_end), num_keys=1)
+            killed_ext = killed_sc[:N] | root_lane
+            flags = (valid.astype(jnp.int32)
+                     | (killed_ext.astype(jnp.int32) << 1))
+            rank_lane, visible = fphase_expand(
+                lk, tb_l, cs, ce, vclass, seg, flags)
+            overflow = overflow_u | overflow_k
+            return rank_lane, visible, conflict, overflow
+
+        tl_l = jnp.where(lk < N, lk, 0)
+        ok_l = lk < N
+        d_base = jnp.where(
+            ok_l,
+            tb_l - jnp.concatenate([jnp.zeros((1,), jnp.int32), tb_l[:-1]]),
+            0,
+        )
+        d_lane = jnp.where(
+            ok_l,
+            tl_l - jnp.concatenate([jnp.zeros((1,), jnp.int32), tl_l[:-1]]),
+            0,
+        )
+        # kept tokens sit in the sorted prefix with strictly increasing
+        # lanes; the rest dump into spread slots past N — sorted + unique
+        # by construction (annotated under CAUSE_TPU_SCATTER=hint)
+        scat = jnp.where(ok_l, tl_l, N + uidx)
+        su_kw = _hint_kw(sorted_=True, unique=True)
+        bits = (N - 1).bit_length()
+        if 2 * bits <= 30:
+            # base and lane are both < N, so their delta streams pack into
+            # one int32 place-value pair: ONE scatter + ONE cumsum instead
+            # of two of each (deltas may be negative, but the cumsum is
+            # exact and every prefix total is a valid packed (base, lane))
+            d_pack = d_base * (1 << bits) + d_lane
+            pack_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
+                d_pack, **su_kw)[:N]
+            pack_fill = jnp.cumsum(pack_n)
+            base_fill = pack_fill >> bits
+            lane_fill = pack_fill & ((1 << bits) - 1)
+        else:  # concat width N > 32k (per-tree capacity > 16k): packed
+               # pairs would overflow int32
+            base_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
+                d_base, **su_kw)[:N]
+            lane_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
+                d_lane, **su_kw)[:N]
+            base_fill = jnp.cumsum(base_n)
+            lane_fill = jnp.cumsum(lane_n)
+        has_tok = jnp.zeros(N + U, bool).at[scat].set(True, **su_kw)[:N]
+        lane_idx = jnp.arange(N, dtype=jnp.int32)
+
+        # per-lane coverage flags from the segment tables (marshal order =
+        # ascending lane order): covered = lane belongs to a token that is
+        # kept, either via its own token (exploded) or its segment's token
+        # spread dump slots past N keep both index streams unique (segment
+        # starts/ends are distinct for live segments: disjoint ascending)
+        cov_cnt = jnp.zeros(N + 1 + S, jnp.int32)
+        cov_cnt = cov_cnt.at[
+            jnp.where(seg_cov, sg_lane0, N + 1 + sidx)
+        ].add(1, **uniq)
+        cov_cnt = cov_cnt.at[
+            jnp.where(seg_cov, sg_lane0 + sg_len, N + 1 + sidx)
+        ].add(-1, **uniq)
+        in_surviving = jnp.cumsum(cov_cnt[:N]) > 0
+
+        # surviving-segment lanes take the seg token's base + offset (their
+        # own has_tok is only set at the head lane — the fill carries it);
+        # exploded lanes have their own token scatter; everything else
+        # (padding, dropped twins, duplicate tokens) ranks at N
+        rank_lane = jnp.where(
+            valid & (in_surviving | has_tok),
+            base_fill + (lane_idx - lane_fill),
+            N,
+        ).astype(jnp.int32)
+
+        # visibility
+        hideish_l = (vclass == VCLASS_HIDE) | (vclass == VCLASS_H_HIDE)
+        nxt_same_seg = jnp.concatenate([
+            (seg[1:] == seg[:N - 1]) & (seg[:N - 1] >= 0),
+            jnp.zeros((1,), bool),
+        ])
+        nxt_hide = jnp.concatenate([hideish_l[1:], jnp.zeros((1,), bool)])
+        kill_in_seg = in_surviving & nxt_same_seg & nxt_hide
+        killed = killed_sc[:N] | kill_in_seg
+
+        visible = (
+            valid & (rank_lane < N) & (vclass == 0) & ~root_lane & ~killed
+        )
         overflow = overflow_u | overflow_k
         return rank_lane, visible, conflict, overflow
-
-    tl_l = jnp.where(lk < N, lk, 0)
-    ok_l = lk < N
-    d_base = jnp.where(
-        ok_l,
-        tb_l - jnp.concatenate([jnp.zeros((1,), jnp.int32), tb_l[:-1]]),
-        0,
-    )
-    d_lane = jnp.where(
-        ok_l,
-        tl_l - jnp.concatenate([jnp.zeros((1,), jnp.int32), tl_l[:-1]]),
-        0,
-    )
-    # kept tokens sit in the sorted prefix with strictly increasing
-    # lanes; the rest dump into spread slots past N — sorted + unique
-    # by construction (annotated under CAUSE_TPU_SCATTER=hint)
-    scat = jnp.where(ok_l, tl_l, N + uidx)
-    su_kw = _hint_kw(sorted_=True, unique=True)
-    bits = (N - 1).bit_length()
-    if 2 * bits <= 30:
-        # base and lane are both < N, so their delta streams pack into
-        # one int32 place-value pair: ONE scatter + ONE cumsum instead
-        # of two of each (deltas may be negative, but the cumsum is
-        # exact and every prefix total is a valid packed (base, lane))
-        d_pack = d_base * (1 << bits) + d_lane
-        pack_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-            d_pack, **su_kw)[:N]
-        pack_fill = jnp.cumsum(pack_n)
-        base_fill = pack_fill >> bits
-        lane_fill = pack_fill & ((1 << bits) - 1)
-    else:  # concat width N > 32k (per-tree capacity > 16k): packed
-           # pairs would overflow int32
-        base_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-            d_base, **su_kw)[:N]
-        lane_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-            d_lane, **su_kw)[:N]
-        base_fill = jnp.cumsum(base_n)
-        lane_fill = jnp.cumsum(lane_n)
-    has_tok = jnp.zeros(N + U, bool).at[scat].set(True, **su_kw)[:N]
-    lane_idx = jnp.arange(N, dtype=jnp.int32)
-
-    # per-lane coverage flags from the segment tables (marshal order =
-    # ascending lane order): covered = lane belongs to a token that is
-    # kept, either via its own token (exploded) or its segment's token
-    # spread dump slots past N keep both index streams unique (segment
-    # starts/ends are distinct for live segments: disjoint ascending)
-    cov_cnt = jnp.zeros(N + 1 + S, jnp.int32)
-    cov_cnt = cov_cnt.at[
-        jnp.where(seg_cov, sg_lane0, N + 1 + sidx)
-    ].add(1, **uniq)
-    cov_cnt = cov_cnt.at[
-        jnp.where(seg_cov, sg_lane0 + sg_len, N + 1 + sidx)
-    ].add(-1, **uniq)
-    in_surviving = jnp.cumsum(cov_cnt[:N]) > 0
-
-    # surviving-segment lanes take the seg token's base + offset (their
-    # own has_tok is only set at the head lane — the fill carries it);
-    # exploded lanes have their own token scatter; everything else
-    # (padding, dropped twins, duplicate tokens) ranks at N
-    rank_lane = jnp.where(
-        valid & (in_surviving | has_tok),
-        base_fill + (lane_idx - lane_fill),
-        N,
-    ).astype(jnp.int32)
-
-    # visibility
-    hideish_l = (vclass == VCLASS_HIDE) | (vclass == VCLASS_H_HIDE)
-    nxt_same_seg = jnp.concatenate([
-        (seg[1:] == seg[:N - 1]) & (seg[:N - 1] >= 0),
-        jnp.zeros((1,), bool),
-    ])
-    nxt_hide = jnp.concatenate([hideish_l[1:], jnp.zeros((1,), bool)])
-    kill_in_seg = in_surviving & nxt_same_seg & nxt_hide
-    killed = killed_sc[:N] | kill_in_seg
-
-    visible = (
-        valid & (rank_lane < N) & (vclass == 0) & ~root_lane & ~killed
-    )
-    overflow = overflow_u | overflow_k
-    return rank_lane, visible, conflict, overflow
 
 
 def merge_weave_kernel_v5(hi, lo, cci, vclass, valid, seg,
@@ -730,7 +717,7 @@ def merge_weave_kernel_v5(hi, lo, cci, vclass, valid, seg,
 
 merge_weave_kernel_v5_jit = jax.jit(
     merge_weave_kernel_v5,
-    static_argnames=("u_max", "k_max", "stage", "euler"),
+    static_argnames=("u_max", "k_max", "euler"),
 )
 
 

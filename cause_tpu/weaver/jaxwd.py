@@ -129,20 +129,21 @@ def batched_delta_weave(hi, lo, cci, vclass, valid, seg,
         sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
         sg_len, sg_lane0, sg_dense, sg_tail_special, sg_valid, sg_vsum)
 
-    B, Nw = hi.shape
-    wcap = Nw // 2
-    lane = jnp.arange(Nw, dtype=jnp.int32)
-    # the anchor lanes (one copy per tree) belong to the PREFIX digest:
-    # the kept copy ranks 0 in the window but carries the prefix's own
-    # rank/visibility in the full weave; the twin-dropped copy would
-    # contribute zero anyway
-    is_anchor = (lane == 0) | (lane == wcap)
-    kept = (rank_w < Nw) & ~is_anchor[None, :]
-    pos = r0[:, None].astype(jnp.uint32) + rank_w.astype(jnp.uint32)
-    terms = mix32(hi, lo, jnp.where(kept, pos, 0), visible_w)
-    window_sum = jnp.sum(
-        jnp.where(kept, terms, jnp.uint32(0)), axis=1)
-    digest = prefix_digest.astype(jnp.uint32) + window_sum
+    with jax.named_scope("wd.digest"):
+        B, Nw = hi.shape
+        wcap = Nw // 2
+        lane = jnp.arange(Nw, dtype=jnp.int32)
+        # the anchor lanes (one copy per tree) belong to the PREFIX digest:
+        # the kept copy ranks 0 in the window but carries the prefix's own
+        # rank/visibility in the full weave; the twin-dropped copy would
+        # contribute zero anyway
+        is_anchor = (lane == 0) | (lane == wcap)
+        kept = (rank_w < Nw) & ~is_anchor[None, :]
+        pos = r0[:, None].astype(jnp.uint32) + rank_w.astype(jnp.uint32)
+        terms = mix32(hi, lo, jnp.where(kept, pos, 0), visible_w)
+        window_sum = jnp.sum(
+            jnp.where(kept, terms, jnp.uint32(0)), axis=1)
+        digest = prefix_digest.astype(jnp.uint32) + window_sum
     return rank_w, visible_w, digest, overflow
 
 
@@ -160,25 +161,26 @@ def splice_ranks(rank_full, vis_full, rank_w, vis_w, starts, counts,
     maps to full concat lane ``t*cap + starts[t] + j``; dropped window
     lanes (twin copies across the pair) splice the full-width sentinel
     ``2*cap``."""
-    B, N = rank_full.shape
-    cap = N // 2
-    Nw = rank_w.shape[1]
-    wcap = Nw // 2
-    off = jnp.arange(wcap - 1, dtype=jnp.int32)
+    with jax.named_scope("wd.splice"):
+        B, N = rank_full.shape
+        cap = N // 2
+        Nw = rank_w.shape[1]
+        wcap = Nw // 2
+        off = jnp.arange(wcap - 1, dtype=jnp.int32)
 
-    def one_row(rf, vf, rw, vw, st, ct, r0_row):
-        for t in range(2):
-            src = t * wcap + 1 + off           # window D lanes
-            w_rank = rw[src]
-            w_vis = vw[src]
-            val = jnp.where(w_rank < Nw,
-                            r0_row.astype(jnp.int32) + w_rank,
-                            jnp.int32(N))
-            idx = t * cap + st[t] + off
-            idx = jnp.where(off < ct[t], idx, N)  # beyond count: drop
-            rf = rf.at[idx].set(val, mode="drop")
-            vf = vf.at[idx].set(w_vis, mode="drop")
-        return rf, vf
+        def one_row(rf, vf, rw, vw, st, ct, r0_row):
+            for t in range(2):
+                src = t * wcap + 1 + off           # window D lanes
+                w_rank = rw[src]
+                w_vis = vw[src]
+                val = jnp.where(w_rank < Nw,
+                                r0_row.astype(jnp.int32) + w_rank,
+                                jnp.int32(N))
+                idx = t * cap + st[t] + off
+                idx = jnp.where(off < ct[t], idx, N)  # beyond count: drop
+                rf = rf.at[idx].set(val, mode="drop")
+                vf = vf.at[idx].set(w_vis, mode="drop")
+            return rf, vf
 
-    return jax.vmap(one_row)(rank_full, vis_full, rank_w, vis_w,
-                             starts, counts, r0)
+        return jax.vmap(one_row)(rank_full, vis_full, rank_w,
+                                 vis_w, starts, counts, r0)

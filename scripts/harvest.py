@@ -228,7 +228,6 @@ def main() -> None:
         enable_compile_cache,
         merge_wave_scalar,
     )
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5
 
     if a.smoke:
         B, NB, ND, CAP = 8, 800, 100, 1024
@@ -538,9 +537,9 @@ def main() -> None:
         mid-compile; now the baseline digest is a dispatch of an
         already-compiled program and the candidate digest shares its
         compile with the candidate's own bench item. Requires a
-        bench-validated v5 budget (same precondition as stages_item:
-        truncated programs clamp identically and would certify a false
-        MATCH); done only on MATCH with zero overflow on both sides."""
+        bench-validated v5 budget (truncated programs clamp
+        identically and would certify a false MATCH); done only on
+        MATCH with zero overflow on both sides."""
         if mosaic_gate(name, kernel_b, cfg_b):
             return
         if "v5" not in validated_k:
@@ -660,77 +659,6 @@ def main() -> None:
                         save_state(done, results)
             emit(ev="suspects", item=name,
                  suspects=sorted(suspect_values))
-        finally:
-            set_config({})
-
-    def stages_item(name, cfg):
-        """Cumulative-prefix phase attribution ON HARDWARE (jaxw5
-        stage= early returns with live checksums; probe_v5_stages
-        inlined so it shares this process's backend + uploads).
-
-        Token budget: the bench_item-validated v5 budget when one
-        completed earlier in the ladder (bench_v5 runs first, so in
-        practice always) — the stage checksums fold the overflow flag
-        into a float, so an unvalidated budget could silently time a
-        truncated program."""
-        if mosaic_gate(name, "v5", cfg) or suspect_gate(name, "v5", cfg):
-            return
-        if "v5" not in validated_k:
-            # without a bench-validated budget the stage checksums could
-            # silently time a truncated (overflowed) program AND mark
-            # the item done; leave it unrecorded for a later window
-            emit(ev="error", item=name,
-                 error="no bench-validated v5 budget this attempt; "
-                       "skipping stages rather than risk timing a "
-                       "truncated program")
-            return
-        set_config(cfg)
-        u_eff = validated_k["v5"]
-        try:
-            v5args = [dev[k] for k in LANE_KEYS5]
-            prev = 0.0
-            table = {}
-            for stage in ("A", "B", "C", "D", "E", None):
-                sname = stage or "FULL"
-
-                def row(*xs, _stage=stage):
-                    out = merge_weave_kernel_v5(
-                        *xs, u_max=u_eff, k_max=u_eff, stage=_stage
-                    )
-                    if _stage is None:
-                        rank, visible, conflict, overflow = out
-                        return (jnp.sum(rank.astype(jnp.float32))
-                                + jnp.sum(visible.astype(jnp.float32))
-                                + conflict.astype(jnp.float32)
-                                + overflow.astype(jnp.float32))
-                    return out
-
-                p = jax.jit(lambda *xs, _r=row: jnp.sum(jax.vmap(_r)(*xs)))
-                np.asarray(p(*v5args))  # compile + warm
-                ts = []
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    np.asarray(p(*v5args))
-                    ts.append((time.perf_counter() - t0) * 1000)
-                med = float(np.median(ts))
-                table[sname] = {"prefix_ms": round(med, 1),
-                                "incr_ms": round(med - prev, 1)}
-                emit(ev="stage", item=name, stage=sname,
-                     prefix_ms=round(med, 1),
-                     incr_ms=round(med - prev, 1), platform=plat)
-                prev = med
-            label = "+".join(sorted(
-                v for v in cfg.values() if v != "xla"))
-            rec = dict(item=name, stages=table, platform=plat,
-                       config=label or ("xla-baseline" if cfg
-                                        else "shipped-default"),
-                       u_max=int(u_eff), shape=f"{B}x{1+NB+ND}",
-                       run=RUN_ID)
-            emit(ev="result", **rec)
-            if record_state:
-                results[name] = rec
-                done.add(name)
-                save_state(done, results)
         finally:
             set_config({})
 
@@ -1079,9 +1007,6 @@ def main() -> None:
          ("bench_schint", "v5", cfg_of(CAUSE_TPU_SCATTER="hint"))),
         ("bench_sortmatrix", bench_item,
          ("bench_sortmatrix", "v5", cfg_of(CAUSE_TPU_SORT="matrix"))),
-        ("stages_default", stages_item, ("stages_default", XLA_BASE)),
-        ("stages_beststream", stages_item,
-         ("stages_beststream", BESTSTREAM)),
         ("bench_allstream", bench_item,
          ("bench_allstream", "v5", ALLSTREAM)),
         ("bench_bitonic", bench_item,

@@ -348,12 +348,6 @@ def test_gap_report_decomposition():
                     "full_bag": 0, "dispatches": 2, "programs": 2,
                     "wall_ms": 4310.0, "floor_ms": 67.0,
                     "floor_budget_ms": 134.0}},
-        {"ev": "event", "name": "stages.prefix", "pid": 1,
-         "fields": {"stage": "E", "p50_ms": 4000.0,
-                    "delta_ms": 2975.0}},
-        {"ev": "event", "name": "stages.prefix", "pid": 1,
-         "fields": {"stage": "FULL", "p50_ms": 4300.0,
-                    "delta_ms": 300.0}},
     ]
     rep = costmodel.gap_report(rows, waves)
     head = rep["headline"]
@@ -363,8 +357,6 @@ def test_gap_report_decomposition():
     assert fl["dispatches_per_wave"] == 2
     assert fl["floor_budget_ms"] == pytest.approx(134.0)
     assert fl["share_of_single"] == round(67.0 / 4356.0, 4)
-    # stages joined, biggest phase first
-    assert rep["stages"][0]["stage"] == "E"
     # near-flat cost across an 2x divergence spread: O(doc)
     assert rep["cost_vs_divergence"]["verdict"] == "O(doc)"
     # projection: cost ∝ divergence would shrink the headline to its

@@ -3,7 +3,7 @@
 Pins the PR-4 tentpole contract: cost_analysis capture once per
 compiled program (CPU-lowered here), the switch-aware program-identity
 keying of the emitted events, gauge streaming for the memory samples,
-the stage profiler's obs stream, and — load-bearing, like
+and — load-bearing, like
 test_obs.py's disabled-mode pins — that with obs OFF devprof records
 nothing, reads no TRACE_SWITCHES env vars, and leaves the
 program-cache values exactly what they were pre-devprof (plain jit
@@ -207,29 +207,3 @@ def test_arena_footprint_on_a_real_lane_view():
     assert out["arena_lanes"] == view.arena.committed_n
     names = {e["name"] for e in obs.events() if e.get("ev") == "gauge"}
     assert "devprof.arena_bytes.test" in names
-
-
-# ------------------------------------------------------ stage profiler
-
-
-def test_stage_ladder_runs_through_obs_spans(tmp_path, capsys):
-    """The reified probe_v5_stages ladder: every prefix stage lands as
-    a stages.prefix event, the per-rep spans and the traced kernel's
-    own weave.trace.v5 span share the stream, and stdout keeps the
-    historical probe format."""
-    pytest.importorskip("jax")
-    from cause_tpu.obs import stages
-
-    obs.configure(enabled=True)
-    results = stages.run_v5_stage_ladder(reps=1, shape=(2, 30, 6, 64))
-    out = capsys.readouterr().out
-    assert "platform=" in out and "prefix->FULL" in out
-    assert [r["stage"] for r in results] == \
-        ["A", "B", "C", "D", "E", "FULL"]
-    evs = obs.events()
-    prefix = [e for e in evs if e.get("name") == "stages.prefix"]
-    assert [e["fields"]["stage"] for e in prefix] == \
-        ["A", "B", "C", "D", "E", "FULL"]
-    span_names = {e["name"] for e in evs if e.get("ev") == "span"}
-    assert {"stages.marshal", "stages.warm", "stages.rep"} <= span_names
-    assert "weave.trace.v5" in span_names  # same stream as the kernel
