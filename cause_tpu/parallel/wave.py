@@ -23,8 +23,10 @@ to the ordinary per-pair ``merge`` — same trees out, just slower.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
+import threading
 import time
 from functools import lru_cache
 from typing import List, Optional, Sequence, Tuple
@@ -56,11 +58,17 @@ def _digest_fn():
 class WaveBuffers:
     """Reusable host-side assembly buffers for repeated waves.
 
-    Allocating ~0.5 GB of [B, 2*cap] batch arrays dominates assembly
-    cost at north-star scale; steady-state sync runs waves over the
-    same fleet shape every round, so the buffers persist and each wave
-    only rewrites the lanes that exist (plus re-padding the shrink gap
-    when a row got shorter). Pass one via ``merge_wave(ctx=...)``."""
+    Allocating and faulting in the [B, 2*cap] batch arrays dominates
+    assembly cost at north-star scale (about 705 MB at 1024 pairs x
+    16,384 lanes per tree); steady-state sync runs waves over the same
+    fleet shape every round, so the buffers persist and each wave only
+    rewrites the lanes that exist (plus re-padding the shrink gap when
+    a row got shorter, and invalidating the segment-table tail). One
+    set holds one shape, ``(B, cap, s_max)``: a wave of another shape
+    replaces the arrays. ``merge_wave`` without ``ctx`` assembles into
+    one module-level set that lives as long as the process; pass a
+    set of your own via ``merge_wave(ctx=...)`` to keep staging apart
+    (``FleetSession`` does)."""
 
     def __init__(self):
         self.shape = None
@@ -98,6 +106,40 @@ _PAD = {
     "hi": I32_MAX, "lo": I32_MAX, "cci": -1, "vc": 0, "valid": False,
     "seg": -1,
 }
+
+# merge_wave's staging when the caller passes no ctx: one set, held by
+# one wave at a time from assembly until its lanes are off the device
+_STAGING = WaveBuffers()
+_STAGING_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def _staging(ctx: Optional[WaveBuffers]):
+    """The buffers one wave assembles into: ``ctx`` as given, else the
+    shared ``_STAGING``, else (another wave holds it) a fresh set of
+    this wave's own. Counts ``wave.staging.reuse`` when the shared set
+    already had the wave's shape and ``wave.staging.alloc`` when the
+    wave allocated staging."""
+    if ctx is not None:
+        yield ctx
+        return
+    if not _STAGING_LOCK.acquire(blocking=False):
+        obs.counter("wave.staging.alloc").inc()
+        yield WaveBuffers()
+        return
+    try:
+        shape = _STAGING.shape
+        try:
+            yield _STAGING
+        except BaseException:
+            # a wave that failed mid-assembly can leave rows whose
+            # prev_n no longer covers what they hold: start over
+            _STAGING.shape = _STAGING.lanes = None
+            raise
+        obs.counter("wave.staging.reuse" if _STAGING.shape == shape
+                    else "wave.staging.alloc").inc()
+    finally:
+        _STAGING_LOCK.release()
 
 
 def delta_domain_ok(view, s: int, anchor: int,
@@ -388,7 +430,9 @@ def _assemble_rows(views: Sequence[Tuple["lanecache.LaneView",
     """[B, 2*cap] v5 lane batch + segment tables from cached views.
     Pure numpy copies of cached arrays — the per-wave host cost. With
     ``bufs``, batch arrays are reused across waves and only live lanes
-    (plus any shrink gap vs the previous wave) are rewritten."""
+    (plus any shrink gap vs the previous wave) are rewritten. Each lane
+    is written once, straight into its batch row (``out=``): a derived
+    lane makes no per-row temporary."""
     B = len(views)
     per_row_segs = [
         [(va.segments(), va.n), (vb.segments(), vb.n)]
@@ -413,13 +457,20 @@ def _assemble_rows(views: Sequence[Tuple["lanecache.LaneView",
             off = t * cap
             sl = slice(off, off + n)
             hi[r, sl] = a.ts[:n]
-            lo[r, sl] = a.spec.pack_lo(a.site[:n], a.tx[:n])
+            # lo = site << tx_bits | tx (PackSpec.pack_lo), in int32
+            lo_row = lo[r, sl]
+            np.left_shift(a.site[:n], a.spec.tx_bits, out=lo_row,
+                          dtype=np.int32)
+            np.bitwise_or(lo_row, a.tx[:n], out=lo_row, dtype=np.int32)
             ci = a.cause_idx[:n]
-            cci[r, sl] = np.where(ci >= 0, ci + off, -1)
+            cci_row = cci[r, sl]
+            np.add(ci, off, out=cci_row, dtype=np.int32)
+            np.copyto(cci_row, -1, where=ci < 0)
             vc[r, sl] = a.vclass[:n]
             valid[r, sl] = True
             segs = per_row_segs[r][t][0]
-            seg[r, sl] = segs["run_of_lane"][:n] + bases[t]
+            np.add(segs["run_of_lane"][:n], bases[t], out=seg[r, sl],
+                   dtype=np.int32)
             prev = int(bufs.prev_n[r, t])
             if prev > n:  # re-pad the shrink gap
                 gap = slice(off + n, off + prev)
@@ -443,6 +494,8 @@ class WaveResult:
       rows have NO valid result);
     - ``rank``/``visible``: [B, 2*cap] per-concat-lane outputs of the
       v5 kernel (rank == 2*cap for dropped/duplicate/padding lanes);
+      when every pair rode the device these are the fetched arrays
+      themselves, which may be read-only: copy before writing;
     - ``merged(i)``: the converged CausalList of pair i as a host
       handle — identical to ``pairs[i][0].merge(pairs[i][1])``,
       including the append-only body validation (conflicting duplicate
@@ -540,6 +593,14 @@ def merge_wave(pairs: Sequence[Tuple[object, object]],
     size. Body validation between duplicate ids follows the device
     contract (jaxw5 module caveat): run ``shared.union_nodes`` or the
     per-pair ``merge`` path when untrusted replicas are involved.
+
+    Without ``ctx`` the wave assembles its lanes into staging shared by
+    every such call in the process (one ``WaveBuffers``, held by one
+    wave at a time): host memory of about 705 MB at 1024 pairs x 16,384
+    lanes per tree stays allocated after the first call, and a call
+    that finds the staging in use by another thread assembles into
+    buffers of its own. Pass ``ctx`` to keep a set of your own. The
+    result's ``rank``/``visible``/``digest`` may be read-only arrays.
     """
     pairs = list(pairs)
     if not pairs:
@@ -602,9 +663,95 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
         # copies of the first live row and drop their outputs below
         pad_rows = (-len(live_views)) % mesh.size
         live_views = live_views + [live_views[0]] * pad_rows
-    with obs.span("wave.assemble", rows=len(live_views), cap=int(cap)):
-        lanes = _assemble_rows(live_views, cap, bufs=ctx)
+    uuid = str(pairs[0][0].ct.uuid)
+    with _staging(ctx) as bufs:
+        with obs.span("wave.assemble", rows=len(live_views),
+                      cap=int(cap)):
+            lanes = _assemble_rows(live_views, cap, bufs=bufs)
+        rank, visible, digest, overflow, info = _dispatch_wave(
+            lanes, mesh, uuid)
+    pipeline = info["kernel"]
 
+    with obs.span("wave.scatter") as sp:
+        B = len(pairs)
+        copied = 0
+        if len(live) == B and not overflow[:B].any():
+            # every pair rode the device: the fetched lanes are the
+            # result, [B, 2*cap] in pair order already (mesh padding
+            # rows sliced off). They may be read-only.
+            full_rank, full_vis, full_dig = rank[:B], visible[:B], digest[:B]
+            dig_valid = np.ones(B, bool)
+        else:
+            full_rank = np.full((B, 2 * cap), 2 * cap, np.int32)
+            full_vis = np.zeros((B, 2 * cap), bool)
+            full_dig = np.zeros(B, np.uint32)
+            dig_valid = np.zeros(B, bool)
+            for j, i in enumerate(live):
+                if bool(overflow[j]):
+                    a, b = pairs[i]
+                    if obs.enabled():
+                        # the ladder's last rung: still overflowing at
+                        # the doubled budget, this pair runs the host
+                        # merge
+                        _recovery.step("wave", "double_budget", "host",
+                                       "token-overflow",
+                                       uuid=str(a.ct.uuid), pair=i)
+                    try:
+                        # budget blown: host path, correct
+                        fallback[i] = a.merge(b)
+                    except s.CausalError as err:  # corrupt AND overflowed
+                        err.info["pair"] = i
+                        poisoned[i] = err
+                    views[i] = None
+                    continue
+                full_rank[i] = rank[j]
+                full_vis[i] = visible[j]
+                full_dig[i] = digest[j]
+                dig_valid[i] = True
+                copied += 1
+        sp.set(copied=copied)
+        result = WaveResult(pairs, views, cap, full_rank, full_vis,
+                            full_dig, fallback, pipeline, dig_valid,
+                            poisoned=poisoned)
+    obs.counter("wave.pairs").inc(B)
+    obs.counter("wave.fallback").inc(len(fallback))
+    obs.counter("wave.poisoned").inc(len(poisoned))
+    if obs.enabled():
+        # semantic layer: digest agreement, staleness aging, and (on
+        # disagreement) one divergence event with site provenance
+        sem = _observe_semantics(pairs, full_dig, dig_valid, "wave")
+        # devprof wave-boundary sample: live device arrays + backend
+        # memory after the dispatch settle, so per-wave residency
+        # renders as a curve next to the dispatch spans
+        from ..obs import devprof
+
+        devprof.sample_device_memory("wave")
+        # the cost-vs-divergence join: ONE wave.cost event carrying
+        # this wave's dispatch count and program identities next to
+        # its token work size (the O(delta) axis), its lane width
+        # (the O(doc) axis) and the semantic digest summary
+        from ..obs import costmodel as _cm
+
+        # lanes/tokens are FLEET totals (lanes: the O(doc) transfer/
+        # scan width; tokens: worst-row estimate × rows — the kernel
+        # pads every row to the budget), same units as delta_ops
+        _cm.wave_cost(uuid=uuid, pairs=B,
+                      lanes=2 * int(cap) * B,
+                      tokens=info["u_need"] * len(live_views),
+                      token_budget=info["u_max"] * len(live_views),
+                      full_bag=len(fallback), poisoned=len(poisoned),
+                      overflow_retries=info["retried"], semantic=sem,
+                      path="full")
+    return result
+
+
+def _dispatch_wave(lanes, mesh, uuid: str):
+    """The device half of a wave over an assembled [B, 2*cap] batch:
+    upload, the v5-family kernel plus digest (sharded over ``mesh``
+    when given), fetch, and one doubled-budget retry of the rows whose
+    sampled token budget overflowed. Returns ``(rank, visible, digest,
+    overflow, info)`` as host arrays in batch-row order, ``info``
+    holding ``kernel``, ``u_need``, ``u_max`` and ``retried``."""
     from ..benchgen import LANE_KEYS5, v5_token_budget
 
     # BENCH_KERNEL routes the wave's kernel variant (the api-level
@@ -619,6 +766,7 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
             f"merge_wave supports BENCH_KERNEL of v5/v5w/v5f only "
             f"(the wave path is segment-union); got {forced!r}")
     pipeline = forced or "v5"
+    n_rows = int(lanes["hi"].shape[0])
 
     def dispatch_v5(sub_lanes, u):
         """Batched v5-family dispatch + device digest, one scalar-free
@@ -668,7 +816,7 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
 
         _sem.token_headroom(int(u_max) - u_need, "wave")
     with obs.span("wave.dispatch", kernel=pipeline,
-                  rows=len(live_views), u_max=int(u_max),
+                  rows=n_rows, u_max=int(u_max),
                   sharded=mesh is not None):
         if mesh is not None:
             from .mesh import sharded_merge_weave_v5
@@ -678,8 +826,7 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
                     "BENCH_KERNEL=v5w has no sharded wave step; use "
                     "v5 or v5f under a mesh")
             jl = _upload(lanes, tuple(lanes))
-            with obs.span("wave.kernel", u_max=int(u_max),
-                          rows=len(live_views)):
+            with obs.span("wave.kernel", u_max=int(u_max), rows=n_rows):
                 rank, visible, overflow, digest, _tv, _nc, _n_ov = (
                     sharded_merge_weave_v5(mesh, jl, u_max=u_max,
                                            k_max=u_max,
@@ -712,8 +859,7 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
         if obs.enabled():
             _recovery.step("wave", "full", "double_budget",
                            "token-overflow",
-                           uuid=str(pairs[0][0].ct.uuid),
-                           rows=n_retried)
+                           uuid=uuid, rows=n_retried)
         sub = {k: lanes[k][rows] for k in LANE_KEYS5}
         with obs.span("wave.dispatch.retry", rows=len(rows),
                       u_max=int(2 * u_max)):
@@ -727,66 +873,10 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
         digest[rows] = d2
         overflow[rows] = ov2
 
-    with obs.span("wave.scatter"):
-        B = len(pairs)
-        full_rank = np.full((B, 2 * cap), 2 * cap, np.int32)
-        full_vis = np.zeros((B, 2 * cap), bool)
-        full_dig = np.zeros(B, np.uint32)
-        dig_valid = np.zeros(B, bool)
-        for j, i in enumerate(live):
-            if bool(overflow[j]):
-                a, b = pairs[i]
-                if obs.enabled():
-                    # the ladder's last rung: still overflowing at the
-                    # doubled budget, this pair runs the host merge
-                    _recovery.step("wave", "double_budget", "host",
-                                   "token-overflow",
-                                   uuid=str(a.ct.uuid), pair=i)
-                try:
-                    # budget blown: host path, correct
-                    fallback[i] = a.merge(b)
-                except s.CausalError as err:  # corrupt AND overflowed
-                    err.info["pair"] = i
-                    poisoned[i] = err
-                views[i] = None
-                continue
-            full_rank[i] = rank[j]
-            full_vis[i] = visible[j]
-            full_dig[i] = digest[j]
-            dig_valid[i] = True
-        result = WaveResult(pairs, views, cap, full_rank, full_vis,
-                            full_dig, fallback, pipeline, dig_valid,
-                            poisoned=poisoned)
-    obs.counter("wave.pairs").inc(B)
-    obs.counter("wave.fallback").inc(len(fallback))
-    obs.counter("wave.poisoned").inc(len(poisoned))
-    if obs.enabled():
-        # semantic layer: digest agreement, staleness aging, and (on
-        # disagreement) one divergence event with site provenance
-        sem = _observe_semantics(pairs, full_dig, dig_valid, "wave")
-        # devprof wave-boundary sample: live device arrays + backend
-        # memory after the dispatch settle, so per-wave residency
-        # renders as a curve next to the dispatch spans
-        from ..obs import devprof
-
-        devprof.sample_device_memory("wave")
-        # the cost-vs-divergence join: ONE wave.cost event carrying
-        # this wave's dispatch count and program identities next to
-        # its token work size (the O(delta) axis), its lane width
-        # (the O(doc) axis) and the semantic digest summary
-        from ..obs import costmodel as _cm
-
-        # lanes/tokens are FLEET totals (lanes: the O(doc) transfer/
-        # scan width; tokens: worst-row estimate × rows — the kernel
-        # pads every row to the budget), same units as delta_ops
-        _cm.wave_cost(uuid=str(pairs[0][0].ct.uuid), pairs=B,
-                      lanes=2 * int(cap) * B,
-                      tokens=int(u_need) * len(live_views),
-                      token_budget=int(u_max) * len(live_views),
-                      full_bag=len(fallback), poisoned=len(poisoned),
-                      overflow_retries=n_retried, semantic=sem,
-                      path="full")
-    return result
+    return rank, visible, digest, overflow, {
+        "kernel": pipeline, "u_need": u_need, "u_max": int(u_max),
+        "retried": n_retried,
+    }
 
 
 def _pair_views(pairs):

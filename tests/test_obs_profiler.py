@@ -34,7 +34,7 @@ MERGE_SPANS = {
     "wave.upload": {"bytes"},
     "wave.kernel": {"u_max", "rows"},
     "wave.fetch": {"bytes"},
-    "wave.scatter": set(),
+    "wave.scatter": {"copied"},
 }
 SERVE_SPANS = {
     "serve.tick": {"ops", "tenants", "batches", "wait_us_sum",

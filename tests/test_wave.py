@@ -1,15 +1,57 @@
 """Batched API-level merge waves: device wave == per-pair merge, with
 cached lanes doing the marshal and digests reporting convergence."""
 
+import sys
+import threading
+
 import numpy as np
 import pytest
 
 import cause_tpu as c
+from cause_tpu import obs
 from cause_tpu.collections import clist as c_list
 from cause_tpu.collections.clist import CausalList
 from cause_tpu.ids import new_site_id
+from cause_tpu.obs import lag as obs_lag
 from cause_tpu.parallel import make_mesh, merge_wave
+from cause_tpu.parallel import wave as wave_mod
 from cause_tpu.weaver import lanecache
+
+
+@pytest.fixture
+def obs_on(monkeypatch):
+    """Obs recording for one test, from a clean state and back."""
+    for k in ("CAUSE_TPU_OBS", "CAUSE_TPU_OBS_OUT", "CAUSE_TPU_CHAOS"):
+        monkeypatch.delenv(k, raising=False)
+    obs.configure(enabled=True, reset=True)
+    obs_lag.reset()
+    yield
+    obs.reset()
+    obs_lag.reset()
+
+
+@pytest.fixture
+def fresh_staging(monkeypatch):
+    """merge_wave's shared staging, empty for this test."""
+    monkeypatch.setattr(wave_mod, "_STAGING", wave_mod.WaveBuffers())
+
+
+def _scatter_copied():
+    """``copied`` of the last ``wave.scatter`` span in the obs ring."""
+    spans = [e for e in obs.events()
+             if e.get("ev") == "span" and e.get("name") == "wave.scatter"]
+    return spans[-1]["attrs"]["copied"]
+
+
+def _staging_counts():
+    return (obs.counter("wave.staging.reuse").value,
+            obs.counter("wave.staging.alloc").value)
+
+
+def _assert_merges(res, pairs):
+    for i, (a, b) in enumerate(pairs):
+        assert c.causal_to_edn(res.merged(i)) == c.causal_to_edn(
+            a.merge(b)), i
 
 
 def warm(cl):
@@ -84,7 +126,9 @@ def test_wave_sharded_over_mesh():
         assert c.causal_to_edn(res.merged(i)) == c.causal_to_edn(a.merge(b))
 
 
-def test_wave_guards_and_fallbacks():
+@pytest.mark.parametrize("map_pair", [False, True],
+                         ids=["device_only", "with_host_fallback"])
+def test_wave_guards_and_fallbacks(obs_on, map_pair):
     pairs = make_pairs(2)
     # uuid mismatch raises like any merge
     with pytest.raises(c.CausalError):
@@ -93,8 +137,25 @@ def test_wave_guards_and_fallbacks():
     base = c.clist(weaver="pure").extend(["p"] * 10)
     a = CausalList(base.ct.evolve(site_id=new_site_id())).conj("1")
     b = CausalList(base.ct.evolve(site_id=new_site_id())).conj("2")
-    res = merge_wave([(a, b)])
-    assert c.causal_to_edn(res.merged(0)) == c.causal_to_edn(a.merge(b))
+    wave = [(a, b)]
+    if map_pair:
+        # a map pair runs the host merge: the device row lands in the
+        # result by the per-row fill, not by handing the fetch over
+        from cause_tpu.collections.cmap import CausalMap
+
+        m = c.cmap().append(c.K("t"), "x")
+        wave.insert(0, (
+            CausalMap(m.ct.evolve(site_id=new_site_id())).append(
+                c.K("t"), "a"),
+            CausalMap(m.ct.evolve(site_id=new_site_id())).append(
+                c.K("u"), "b")))
+    res = merge_wave(wave)
+    assert res.fallback == ([0] if map_pair else [])
+    assert res.digest_valid.tolist() == ([False, True] if map_pair
+                                         else [True])
+    assert _scatter_copied() == (1 if map_pair else 0)
+    assert res.digest.dtype == np.uint32
+    _assert_merges(res, wave)
 
 
 def test_union_views_equals_scratch_union():
@@ -209,21 +270,40 @@ def test_wave_routes_maps_to_the_correct_path():
         FleetSession([(a, b)])
 
 
-def test_wave_overflow_rows_retry_on_device():
+@pytest.mark.parametrize("fallback_pair", [False, True],
+                         ids=["device_only", "with_host_fallback"])
+def test_wave_overflow_rows_retry_on_device(obs_on, monkeypatch,
+                                            fallback_pair):
     """A spiky row outside the sampled token budget retries with a
     doubled budget instead of silently demoting to the host merge
     (soak-found: session digests diverged from wave digests purely
-    because of budget-sampling fallbacks)."""
+    because of budget-sampling fallbacks). With a host-fallback pair in
+    the same wave, the retried rows still land in their own rows."""
+    from cause_tpu import benchgen
+
+    # a budget sampled at half the tokens: the spiky row overflows it
+    # and fits the doubled one
+    budget = benchgen.v5_token_budget
+    monkeypatch.setattr(benchgen, "v5_token_budget",
+                        lambda lanes: int(budget(lanes)) // 2)
     pairs = make_pairs(5, n_base=40, n_div=6)
     a, b = pairs[2]
     for j in range(12):  # interior tombstones explode pair 2's segments
         a = a.append(list(a)[2 + j][0], c.hide)
     pairs[2] = (a, b)
+    if fallback_pair:
+        # ids beyond the PackSpec ts bound: no lane cache, host merge
+        x, y = make_pairs(1, n_base=40, n_div=6)[0]
+        big = ((1 << 31) - 1, x.get_site_id(), 0)
+        pairs.insert(1, (x.insert((big, c.root_id, "huge-ts")),
+                         y.insert((big, c.root_id, "huge-ts"))))
     res = merge_wave(pairs)
-    assert not res.fallback
-    assert res.digest_valid.all()
-    for i, (x, y) in enumerate(pairs):
-        assert c.causal_to_edn(res.merged(i)) == c.causal_to_edn(x.merge(y))
+    assert obs.counter("wave.overflow_retry").value >= 1
+    assert res.fallback == ([1] if fallback_pair else [])
+    assert res.digest_valid.tolist() == [
+        not (fallback_pair and i == 1) for i in range(len(pairs))]
+    assert _scatter_copied() == (5 if fallback_pair else 0)
+    _assert_merges(res, pairs)
 
 
 def _corrupt_pair(n_base=80, n_div=6):
@@ -354,3 +434,169 @@ def test_corrupt_fallback_pair_poisons_itself(monkeypatch):
     assert ei.value.info["pair"] == 1
     for i, (x, y) in ((0, healthy[0]), (2, healthy[1])):
         assert c.causal_to_edn(res.merged(i)) == c.causal_to_edn(x.merge(y))
+
+
+def _hide_interior(pairs, row, k):
+    """Pair ``row`` with ``k`` interior tombstones on side a: more
+    segments in that row, the same lane capacity."""
+    a, b = pairs[row]
+    for j in range(k):
+        a = a.append(list(a)[2 + 3 * j][0], c.hide)
+    pairs[row] = (a, b)
+    return pairs
+
+
+def _shape(pairs):
+    views = [(lanecache.view_for(a.ct), lanecache.view_for(b.ct))
+             for a, b in pairs]
+    segs = [va.segments()["sg_len"].shape[0] + vb.segments()["sg_len"].shape[0]
+            for va, vb in views]
+    return views, [max(va.n, vb.n) for va, vb in views], segs
+
+
+def test_shared_staging_reuse_across_fleets_is_exact(obs_on, fresh_staging):
+    """Two default-staging waves of one shape on different fleets: the
+    second has shorter rows (the shrink gap) and fewer segments where
+    the first had the most. Its lanes, tables and results are those of
+    assembly into fresh buffers, and the first wave's result still
+    materializes after the second rewrote the staging."""
+    from cause_tpu.weaver.segments import SEG_LANE_KEYS
+
+    first = _hide_interior(make_pairs(3, n_base=100, n_div=8), 0, 6)
+    second = _hide_interior(make_pairs(3, n_base=70, n_div=3), 2, 5)
+    _v1, n1, segs1 = _shape(first)
+    views2, n2, segs2 = _shape(second)
+    assert max(n2) < min(n1) and segs2[0] < segs1[0]
+
+    res1 = merge_wave(first)
+    assert _staging_counts() == (0, 1)
+    shape = wave_mod._STAGING.shape
+    res2 = merge_wave(second)
+    assert wave_mod._STAGING.shape == shape        # one shape, reused
+    assert _staging_counts() == (1, 1)
+
+    cap = res2.capacity
+    fresh = wave_mod._assemble_rows(views2, cap)   # fresh WaveBuffers
+    staged = wave_mod._STAGING.lanes
+    for k in ("hi", "lo", "cci", "vc", "valid", "seg", "sg_valid",
+              "sg_len"):
+        assert np.array_equal(staged[k], fresh[k]), k
+    live = fresh["sg_valid"]
+    for k in SEG_LANE_KEYS:
+        assert np.array_equal(staged[k][live], fresh[k][live]), k
+    # the derived lanes, as the row-by-row expressions write them
+    for r, (va, vb) in enumerate(views2):
+        base = 0
+        for t, v in enumerate((va, vb)):
+            a, n, off = v.arena, v.n, t * cap
+            sl = slice(off, off + n)
+            ci = a.cause_idx[:n]
+            segs = v.segments()
+            assert np.array_equal(staged["lo"][r, sl],
+                                  a.spec.pack_lo(a.site[:n], a.tx[:n]))
+            assert np.array_equal(staged["cci"][r, sl],
+                                  np.where(ci >= 0, ci + off, -1))
+            assert np.array_equal(staged["seg"][r, sl],
+                                  segs["run_of_lane"][:n] + base)
+            base += segs["sg_len"].shape[0]
+
+    ref = merge_wave(second, ctx=wave_mod.WaveBuffers())
+    for k in ("rank", "visible", "digest", "digest_valid"):
+        assert np.array_equal(getattr(res2, k), getattr(ref, k)), k
+    _assert_merges(res2, second)
+    _assert_merges(res1, first)
+
+
+def test_concurrent_waves_never_share_staging(obs_on, fresh_staging,
+                                              monkeypatch):
+    """Two threads wave at once: the one that finds the shared staging
+    taken assembles into buffers of its own (``wave.staging.alloc``),
+    and both results are right."""
+    fleets = [make_pairs(3, n_base=60, n_div=4) for _ in range(2)]
+    merge_wave(make_pairs(3, n_base=60, n_div=4))  # staging takes the shape
+    before = _staging_counts()
+    # both threads must be inside assembly at the same time
+    met = threading.Barrier(2, timeout=120)
+    assemble = wave_mod._assemble_rows
+
+    def meet_then_assemble(*args, **kw):
+        met.wait()
+        return assemble(*args, **kw)
+
+    monkeypatch.setattr(wave_mod, "_assemble_rows", meet_then_assemble)
+    results, errors = [None, None], []
+
+    def run(k):
+        try:
+            results[k] = merge_wave(fleets[k])
+        except Exception as err:  # surfaced by the assert below
+            errors.append(err)
+
+    threads = [threading.Thread(target=run, args=(k,)) for k in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    assert not errors, errors
+    reuse, alloc = _staging_counts()
+    assert (reuse - before[0], alloc - before[1]) == (1, 1)
+    for res, pairs in zip(results, fleets):
+        _assert_merges(res, pairs)
+
+
+def test_staging_under_thread_contention(obs_on, fresh_staging):
+    """More waving threads than cores, switching every few microseconds:
+    every wave is right and every call is counted once."""
+    n_threads, calls = 8, 2
+    fleets = [make_pairs(2, n_base=30, n_div=3) for _ in range(n_threads)]
+    results = [[] for _ in range(n_threads)]
+    errors = []
+
+    def run(k):
+        try:
+            for _ in range(calls):
+                results[k].append(merge_wave(fleets[k]))
+        except Exception as err:  # surfaced by the assert below
+            errors.append(err)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=run, args=(k,))
+                   for k in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    assert sum(_staging_counts()) == n_threads * calls
+    for got, pairs in zip(results, fleets):
+        assert len(got) == calls
+        for res in got:
+            _assert_merges(res, pairs)
+
+
+def test_failed_wave_releases_and_resets_staging(obs_on, fresh_staging,
+                                                 monkeypatch):
+    """A wave that raises after taking the shared staging gives it back
+    emptied (its rows may be half written), and the next wave is right."""
+    pairs = make_pairs(3, n_base=40, n_div=4)
+    merge_wave(pairs)
+    dispatch = wave_mod._dispatch_wave
+
+    def fail(*args, **kw):
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(wave_mod, "_dispatch_wave", fail)
+    with pytest.raises(RuntimeError):
+        merge_wave(pairs)
+    assert wave_mod._STAGING.shape is None
+    assert not wave_mod._STAGING_LOCK.locked()
+    monkeypatch.setattr(wave_mod, "_dispatch_wave", dispatch)
+    res = merge_wave(pairs)
+    assert _staging_counts() == (0, 2)
+    _assert_merges(res, pairs)
