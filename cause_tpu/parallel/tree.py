@@ -211,6 +211,15 @@ def _materialize(sub: _Sub, state: Optional[dict]):
     if sub.handle is not None:
         return sub.handle
     assert state is not None and sub.ranks is not None
+    with obs.span("tree.materialize", members=len(sub.members)) as sp:
+        handle = _build_handle(sub, state)
+        sp.set(nodes=len(handle.ct.nodes))
+    return handle
+
+
+def _build_handle(sub: _Sub, state: dict):
+    """``_materialize``'s work: the weave from the window ranks, the
+    validated node union and the union lane view, set on ``sub``."""
     wcap = sub.wcap
     n_w = 2 * wcap
     rw = np.asarray(sub.ranks)
@@ -426,33 +435,42 @@ def _delta_level(pairs, state, level, uuid, byes, final):
         from ..obs import costmodel as _cm
 
         _cm.wave_begin("tree")
+    # nodes: the real window lanes the kernel reads, each side's pooled
+    # lanes plus its anchor; merged: each union's anchor plus its side
     with obs.span("tree.level", level=level, pairs=P, wcap=int(wcap),
-                  path="delta"):
+                  path="delta", nodes=int(delta_ops + 2 * P)) as lsp:
         with obs.span("tree.assemble", level=level):
             lanes = _assemble_level(sides_pairs, state, wcap)
         pdig = np.full(P, np.uint32(state["pdig"]), np.uint32)
         r0 = np.full(P, state["s"] - 1, np.int32)
-        rank_w, _vis_w, dig, ovf = _recovery.run_dispatch(
-            "tree",
-            lambda: jaxwd.batched_delta_weave(
-                *(jnp.asarray(lanes[k]) for k in LANE_KEYS5),
-                jnp.asarray(pdig), jnp.asarray(r0),
-                u_max=int(n_w), k_max=int(n_w)))
-        if obs.enabled():
-            from ..obs import costmodel as _cm
+        with obs.span("tree.dispatch", level=level, path="delta",
+                      u_max=int(n_w)):
+            rank_w, _vis_w, dig, ovf = _recovery.run_dispatch(
+                "tree",
+                lambda: jaxwd.batched_delta_weave(
+                    *(jnp.asarray(lanes[k]) for k in LANE_KEYS5),
+                    jnp.asarray(pdig), jnp.asarray(r0),
+                    u_max=int(n_w), k_max=int(n_w)))
+            if obs.enabled():
+                from ..obs import costmodel as _cm
 
-            _cm.record_dispatch(f"tree:delta:w{int(wcap)}", site="tree")
-        # pipeline: merge the NEXT level's sides on host while the
-        # window weave executes on device; the digest fetch below is
-        # this level's only sync point
-        new_subs = [
-            _Sub(members=a.members + b.members, views=a.views + b.views,
-                 side=_merge_sides(a.side, b.side), ranks=rank_w[i],
-                 wcap=wcap, sides_lr=(a.side, b.side))
-            for i, (a, b) in enumerate(pairs)
-        ]
-        digests = np.asarray(dig)
-        if bool(np.asarray(ovf).any()):  # pragma: no cover -
+                _cm.record_dispatch(f"tree:delta:w{int(wcap)}",
+                                    site="tree")
+            # pipeline: merge the NEXT level's sides on host while the
+            # window weave executes on device; the digest fetch below
+            # is this level's only sync point
+            with obs.span("tree.sides", level=level):
+                new_subs = [
+                    _Sub(members=a.members + b.members,
+                         views=a.views + b.views,
+                         side=_merge_sides(a.side, b.side),
+                         ranks=rank_w[i], wcap=wcap,
+                         sides_lr=(a.side, b.side))
+                    for i, (a, b) in enumerate(pairs)
+                ]
+            digests = np.asarray(dig)
+            overflow = bool(np.asarray(ovf).any())
+        if overflow:  # pragma: no cover -
             # structurally unreachable at u_max = N_w; a future budget
             # change degrades to the full-width bounce, never to wrong
             if obs.enabled():
@@ -460,6 +478,8 @@ def _delta_level(pairs, state, level, uuid, byes, final):
 
                 _cm.wave_abandon()
             return None
+        lsp.set(merged=sum(1 + len(sub.side) for sub in new_subs))
+    obs.counter("tree.level.delta").inc()
     sem = _observe_level(uuid, level, digests, P, byes, delta_ops,
                          wcap, "delta", 1, final)
     if obs.enabled():
@@ -512,15 +532,23 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
         from ..obs import costmodel as _cm
 
         _cm.wave_begin("tree")
+    # nodes: the real lanes of both sides of every pair; merged: the
+    # real lanes of the winners (each pair's node union)
     with obs.span("tree.level", level=level, pairs=P, cap=int(cap),
-                  path="full"):
+                  path="full",
+                  nodes=sum(va.n + vb.n for va, vb in views_pairs)) as lsp:
         with obs.span("tree.assemble", level=level):
             lanes = _assemble_rows(views_pairs, cap)
-        rank, vis, dig, info = dispatch_full_rows(lanes, site="tree")
+        with obs.span("tree.dispatch", level=level, path="full") as dsp:
+            rank, vis, dig, info = dispatch_full_rows(lanes, site="tree")
+            dsp.set(u_max=info["u_max"])
         res = WaveResult(handles_pairs, views_pairs, cap, rank, vis,
                          dig, {}, "v5",
                          digest_valid=np.ones(P, bool))
-        winners = [res.merged(i) for i in range(P)]
+        with obs.span("tree.winners", level=level, pairs=P):
+            winners = [res.merged(i) for i in range(P)]
+        lsp.set(merged=sum(len(w.ct.nodes) for w in winners))
+    obs.counter("tree.level.full").inc()
     delta_ops = sum(
         (va.n - state["s"]) + (vb.n - state["s"])
         for va, vb in views_pairs
@@ -563,13 +591,15 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
         survivor_views.append(v)
     new_state = None
     if len(new_subs) + len(bye_subs) > 1:
-        new_state = _establish(check_views + bye_views, survivor_views,
-                               rank[0], vis[0], cap)
-        if new_state is not None:
-            sp, anchor = new_state["s"], new_state["anchor"]
-            for sub in new_subs + list(bye_subs):
-                sub.side = _side_of(sub.views[0], sp, anchor)
-        else:
+        with obs.span("tree.establish", level=level) as esp:
+            new_state = _establish(check_views + bye_views,
+                                   survivor_views, rank[0], vis[0], cap)
+            if new_state is not None:
+                sp, anchor = new_state["s"], new_state["anchor"]
+                for sub in new_subs + list(bye_subs):
+                    sub.side = _side_of(sub.views[0], sp, anchor)
+            esp.set(ok=int(new_state is not None))
+        if new_state is None:
             obs.counter("tree.establish_fail").inc()
             if obs.enabled():
                 # the next level cannot ride delta: declared, not
@@ -711,21 +741,30 @@ def merge_all_tree(handles: Sequence):
     body conflicts, type/uuid mismatches), exactly like the fold."""
     handles = list(handles)
     if len(handles) < 4:
-        return None
+        return _fallback("small-fleet")
     if getattr(handles[0].ct, "weaver", "") != "jax":
         # pure/native users picked the host oracle: never drag the
         # device path (and a jax import) into their merge_all
-        return None
+        return _fallback("host-weaver")
     for h in handles:
         if lanecache.view_for(h.ct) is None:
-            return None
+            return _fallback("no-lanes")
     try:
         return merge_tree(handles)
     except s.CausalError as err:
         causes = set(err.info.get("causes") or ())
-        if causes & {"outside-domain", "token-overflow"}:
-            return None
+        for cause in ("outside-domain", "token-overflow"):
+            if cause in causes:
+                return _fallback(cause)
         raise
+
+
+def _fallback(cause: str):
+    """``merge_all_tree``'s None, counted: ``tree.fallback`` and
+    ``tree.fallback.<cause>``."""
+    obs.counter("tree.fallback").inc()
+    obs.counter(f"tree.fallback.{cause}").inc()
+    return None
 
 
 def flat_fold(handles: Sequence, ctx=None):

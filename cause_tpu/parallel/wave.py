@@ -255,8 +255,8 @@ def dispatch_full_rows(lanes, site: str = "tree"):
     """One fused full-width kernel+digest dispatch over an assembled
     ``[B, 2*cap]`` v5 lane batch (``benchgen.LANE_KEYS5`` dict), with
     the pow2-quantized token budget and a doubled-budget retry for
-    spiky unsampled rows — the level primitive the merge reduction
-    tree (``parallel.tree``) shares with the sweep/harvest gates.
+    spiky unsampled rows: the full-width level of the merge reduction
+    tree (``parallel.tree``), its only caller.
 
     Returns ``(rank, visible, digest, info)`` as numpy arrays plus an
     ``info`` dict (``u_need``/``u_max``/``retried`` — the caller's

@@ -52,8 +52,10 @@ dispatch — per pair the two "trees" are pooled subtree sides under
 the shared anchor, and the returned digest is each merged subtree's
 TOTAL document digest, so per-level convergence evidence costs no
 extra dispatch. ``batched_weave_digest`` is the tree's full-width
-level (first contact / window-budget bounce) and the sweep/harvest
-control arm.
+level (first contact / window-budget bounce), through
+``parallel.wave.dispatch_full_rows``; ``bench.py``'s divergence sweep
+and ``scripts/harvest.py``'s delta gate call it directly, as the
+full-width arm they check the window program against.
 """
 
 from __future__ import annotations

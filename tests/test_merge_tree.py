@@ -19,10 +19,16 @@ Pins the tentpole contract:
   the delta path, and ``obs gap``'s tree decomposition renders;
 - obs-off invariance: identical convergence with zero records;
 - ``FleetSession.converge`` delegates to the tree (flat fold behind
-  ``tree=False``) without disturbing the resident wave state.
+  ``tree=False``) without disturbing the resident wave state;
+- many editors of one document: mid-document edits run every level at
+  full width (establishment fails, ``tree.establish`` carries
+  ``ok=0``), tail appends one full level then delta; ``tree.level``'s
+  ``nodes``/``merged`` are the handles' lane counts; a forced token
+  overflow is a counted ``tree.fallback``.
 """
 
 import functools
+import random
 
 import numpy as np
 import pytest
@@ -309,3 +315,137 @@ def test_tree_fleet_handles_generator():
     root, rep = tree_mod.merge_tree_report(fleet)
     assert_identical(root, fold(fleet))
     assert len(rep["levels"]) == tree_mod.tree_rounds(5)
+
+
+# ------------------------------------------- many editors, one document
+
+
+def make_editors(base, n, ops, seed, hide_share=0.3):
+    """``n`` editors off ``base``, each typing ``ops`` ops at cursors
+    drawn from anywhere in the shared document: inserts, and hides of a
+    document value (never twice by one editor). Every edit lies outside
+    the delta domain."""
+    rng = random.Random(seed)
+    ids = [nd[0] for nd in base.ct.weave][1:]
+    fleet = []
+    for r in range(n):
+        h = CausalList(base.ct.evolve(site_id=new_site_id()))
+        hidden = set()
+        for i in range(ops):
+            target = rng.choice(ids)
+            if rng.random() < hide_share and target not in hidden:
+                hidden.add(target)
+                h = h.append(target, c.hide)
+            else:
+                h = h.append(target, f"e{r}.{i}")
+        fleet.append(h)
+    return fleet
+
+
+def pure_fold(handles):
+    """The pairwise fold on the pure weaver, the semantics' oracle."""
+    return fold([CausalList(h.ct.evolve(weaver="pure", lanes=None))
+                 for h in handles])
+
+
+def observed_tree(fleet):
+    """The tree's root and report, with its spans and counters."""
+    obs.configure(enabled=True)
+    root, rep = tree_mod.merge_tree_report(fleet)
+    spans = [e for e in obs.events() if e.get("ev") == "span"]
+    counters = obs.counters_snapshot()["counters"]
+    obs.configure(enabled=False)
+    return root, rep, spans, counters
+
+
+def level_counts(fleet, paths, prefix):
+    """Per level, (nodes, merged) as the node sets give them: the lanes
+    both sides of each pair hold and their union's, at a delta level
+    each side's lanes past the shared ``prefix`` plus its anchor."""
+    groups = [set(h.ct.nodes) for h in fleet]
+    out = []
+    for path in paths:
+        pairs = [(groups[i], groups[i + 1])
+                 for i in range(0, len(groups) - 1, 2)]
+        if path == "full":
+            out.append((sum(len(a) + len(b) for a, b in pairs),
+                        sum(len(a | b) for a, b in pairs)))
+        else:
+            out.append((sum(2 + len(a) + len(b) - 2 * prefix
+                            for a, b in pairs),
+                        sum(1 + len(a | b) - prefix for a, b in pairs)))
+        groups = [a | b for a, b in pairs] + groups[2 * len(pairs):]
+    return out
+
+
+@pytest.mark.parametrize("n", [13, 100])
+def test_mid_document_editors_run_every_level_full_width(n):
+    base = make_base(30)
+    fleet = make_editors(base, n, 2, seed=n)
+    root, rep, spans, counters = observed_tree(fleet)
+    assert_identical(root, pure_fold(fleet))
+    rounds = tree_mod.tree_rounds(n)
+    assert [lv["path"] for lv in rep["levels"]] == ["full"] * rounds
+    assert counters["tree.level.full"] == rounds
+    assert "tree.level.delta" not in counters
+    ok = [e["attrs"]["ok"] for e in spans if e["name"] == "tree.establish"]
+    assert len(ok) == rounds - 1 and set(ok) == {0}
+    winners = [e["attrs"] for e in spans if e["name"] == "tree.winners"]
+    assert [w["pairs"] for w in winners] == \
+        [lv["pairs"] for lv in rep["levels"]]
+
+
+def test_tail_appends_ride_one_full_level_then_delta():
+    base = make_base(30)
+    fleet = make_fleet(base, 13, n_div=3, hide_every=2)
+    root, rep, spans, counters = observed_tree(fleet)
+    assert_identical(root, pure_fold(fleet))
+    rounds = tree_mod.tree_rounds(13)
+    assert [lv["path"] for lv in rep["levels"]] == \
+        ["full"] + ["delta"] * (rounds - 1)
+    assert counters["tree.level.full"] == 1
+    assert counters["tree.level.delta"] == rounds - 1
+    names = [e["name"] for e in spans]
+    assert names.count("tree.sides") == rounds - 1
+    assert [e["attrs"]["ok"] for e in spans
+            if e["name"] == "tree.establish"] == [1]
+    paths = [e["attrs"]["path"] for e in spans
+             if e["name"] == "tree.dispatch"]
+    assert paths == ["full"] + ["delta"] * (rounds - 1)
+    # the root is the one subtree built from its window ranks; its
+    # members are level 0's winners and its bye
+    (mat,) = [e["attrs"] for e in spans if e["name"] == "tree.materialize"]
+    assert mat["members"] == 7 and mat["nodes"] == len(root.ct.nodes)
+
+
+@pytest.mark.parametrize("edits", ["tail", "anywhere"])
+def test_level_node_counts_match_the_handles(edits):
+    base = make_base(30)
+    if edits == "tail":
+        fleet = make_fleet(base, 7, n_div=3, hide_every=2)
+    else:
+        fleet = make_editors(base, 7, 3, seed=7)
+    _root, rep, spans, _counters = observed_tree(fleet)
+    got = [(e["attrs"]["nodes"], e["attrs"]["merged"]) for e in spans
+           if e["name"] == "tree.level"]
+    paths = [lv["path"] for lv in rep["levels"]]
+    assert got == level_counts(fleet, paths, len(base.ct.nodes))
+
+
+def test_a_forced_token_overflow_is_a_counted_fallback(monkeypatch):
+    from cause_tpu import benchgen
+
+    base = make_base(30)
+    # interior edits make many merge tokens a row: more than the
+    # starved budget holds, doubled or not
+    fleet = make_editors(base, 5, 12, seed=5)
+    monkeypatch.setattr(benchgen, "v5_token_budget", lambda lanes: 1)
+    obs.configure(enabled=True)
+    out = c.merge_all(fleet[0], *fleet[1:])
+    spans = {e["name"] for e in obs.events() if e.get("ev") == "span"}
+    counters = obs.counters_snapshot()["counters"]
+    obs.configure(enabled=False)
+    assert counters["tree.fallback"] == 1
+    assert counters["tree.fallback.token-overflow"] == 1
+    assert "tree.converge" in spans       # the tree ran, then gave up
+    assert out.ct.weave == pure_fold(fleet).ct.weave

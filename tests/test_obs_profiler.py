@@ -3,8 +3,9 @@
 Every ``obs.span`` also opens a profiler ``TraceMe`` whenever a JAX
 profiler session records, obs on or off, with its integer and string
 attributes as the event's stats. Pins: a trace recorded around a tiny
-``merge_wave`` and a tiny ``SyncService.tick`` holds the spans at each
-layer boundary with their attributes; the obs ring still records only
+``merge_wave`` and a tiny ``SyncService.tick``, or a tiny ``merge_all``
+on the reduction tree, holds the spans at each layer boundary with
+their attributes; the obs ring still records only
 when obs is on; with no profiler recording a disabled span is still
 the shared no-op; ``obs.core`` imports without JAX.
 """
@@ -43,6 +44,17 @@ SERVE_SPANS = {
     "sync.apply_delta": {"ops"},
     "serve.waves": {"tenants", "full"},
     "serve.batch_wave": {"bucket", "tenants", "rows"},
+}
+
+TREE_SPANS = {
+    "tree.converge": {"n", "rounds"},
+    "tree.level": {"level", "pairs", "path", "nodes", "merged"},
+    "tree.assemble": {"level"},
+    "tree.dispatch": {"level", "path", "u_max"},
+    "tree.winners": {"level", "pairs"},
+    "tree.establish": {"level", "ok"},
+    "tree.sides": {"level"},
+    "tree.materialize": {"members", "nodes"},
 }
 
 
@@ -140,6 +152,39 @@ def test_spans_reach_the_profiler_trace(tmp_path, obs_on):
     ring = {e["name"] for e in obs.events() if e.get("ev") == "span"}
     if obs_on:
         assert set(MERGE_SPANS) | set(SERVE_SPANS) <= ring
+    else:
+        assert ring == set() and obs.events() == []
+
+
+@pytest.mark.parametrize("obs_on", [False, True], ids=["obs_off", "obs_on"])
+def test_tree_spans_reach_the_profiler_trace(tmp_path, obs_on):
+    """A merge_all over five replicas that append at the tail: a full
+    level 0, two delta levels, a bye and the root built from its window
+    ranks, each under its span."""
+    import jax
+
+    obs.configure(enabled=obs_on)
+    base = _base()
+    fleet = [CausalList(base.ct.evolve(site_id=new_site_id())).conj(f"r{r}")
+             for r in range(5)]
+    c.merge_all(*fleet)                      # warm: compiles outside
+    trace_dir = str(tmp_path / "trace")
+    jax.profiler.start_trace(trace_dir)
+    try:
+        c.merge_all(*fleet)
+    finally:
+        jax.profiler.stop_trace()
+    evs = _host_events(trace_dir)
+    for name, attrs in TREE_SPANS.items():
+        assert name in evs, (name, sorted(evs))
+        assert attrs <= set(evs[name][-1]), (name, evs[name][-1])
+    assert [e["path"] for e in evs["tree.level"]] == \
+        ["full", "delta", "delta"]
+    assert evs["tree.establish"] == [{"level": 0, "ok": 1}]
+    assert evs["tree.materialize"][-1]["members"] == 3
+    ring = {e["name"] for e in obs.events() if e.get("ev") == "span"}
+    if obs_on:
+        assert set(TREE_SPANS) <= ring
     else:
         assert ring == set() and obs.events() == []
 
