@@ -64,7 +64,8 @@ from ..weaver.segments import SEG_LANE_KEYS, concat_seg_tables
 from . import recovery as _recovery
 from .wave import (WaveBuffers, _PAD, _assemble_rows, _digest_fn,
                    _observe_semantics, _sampled_body_spotcheck,
-                   assemble_delta_window, delta_domain_ok)
+                   assemble_delta_window, count_run_width,
+                   delta_domain_ok)
 
 __all__ = ["FleetSession"]
 
@@ -527,12 +528,12 @@ class FleetSession:
                       uuid=str(self.pairs[0][0].ct.uuid),
                       pairs=len(self.pairs))
         with obs.span("session.wave", pairs=len(self.pairs),
-                      u_max=int(self.u_max)):
-            r, v, _c, ov = _recovery.run_dispatch(
+                      u_max=int(self.u_max)) as sp:
+            r, v, _c, ov, width = _recovery.run_dispatch(
                 "session",
                 lambda: batched_merge_weave_v5(
                     *(self.dev[k] for k in LANE_KEYS5),
-                    u_max=self.u_max, k_max=self.u_max,
+                    u_max=self.u_max, k_max=self.u_max, with_k=True,
                 ))
             digest = _digest_fn()(self.dev["hi"], self.dev["lo"], r, v)
             if obs.enabled():
@@ -545,6 +546,7 @@ class FleetSession:
             self.last_visible = v
             self.last_overflow = ov
             out = np.asarray(digest)
+            sp.set(k=count_run_width(int(width), int(self.u_max)))
         if obs.enabled():
             # wave-boundary devprof sample: the session's whole point
             # is device residency, so its growth must be a curve

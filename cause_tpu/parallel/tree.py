@@ -541,7 +541,7 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
             lanes = _assemble_rows(views_pairs, cap)
         with obs.span("tree.dispatch", level=level, path="full") as dsp:
             rank, vis, dig, info = dispatch_full_rows(lanes, site="tree")
-            dsp.set(u_max=info["u_max"])
+            dsp.set(u_max=info["u_max"], k=info["k"])
         res = WaveResult(handles_pairs, views_pairs, cap, rank, vis,
                          dig, {}, "v5",
                          digest_valid=np.ones(P, bool))

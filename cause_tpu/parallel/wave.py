@@ -260,7 +260,8 @@ def dispatch_full_rows(lanes, site: str = "tree"):
 
     Returns ``(rank, visible, digest, info)`` as numpy arrays plus an
     ``info`` dict (``u_need``/``u_max``/``retried`` — the caller's
-    ``wave.cost`` evidence). Raises ``CausalError`` if a row still
+    ``wave.cost`` evidence — and ``k``, the run width the kernel ran
+    at). Raises ``CausalError`` if a row still
     overflows at the doubled budget (unlike ``merge_wave`` there is no
     per-pair host fallback here: the caller owns the batch)."""
     from ..benchgen import LANE_KEYS5, v5_token_budget
@@ -274,14 +275,16 @@ def dispatch_full_rows(lanes, site: str = "tree"):
             site,
             lambda: batched_weave_digest(
                 *(jnp.asarray(sub[k]) for k in LANE_KEYS5),
-                u_max=int(u), k_max=int(u)))
+                u_max=int(u), k_max=int(u), with_k=True))
         if obs.enabled():
             from ..obs import costmodel as _cm
 
             _cm.record_dispatch(f"{site}:full:u{int(u)}", site=site)
-        return tuple(np.asarray(x) for x in out)
+        *out, k = (np.asarray(x) for x in out)
+        count_run_width(int(k), int(u))
+        return (*out, int(k))
 
-    rank, visible, digest, overflow = _run(lanes, u_max)
+    rank, visible, digest, overflow, k = _run(lanes, u_max)
     retried = 0
     if overflow.any():
         rows = np.flatnonzero(overflow)
@@ -293,7 +296,7 @@ def dispatch_full_rows(lanes, site: str = "tree"):
             _recovery.step(site, "full", "double_budget",
                            "token-overflow", rows=retried)
         sub = {k: lanes[k][rows] for k in LANE_KEYS5}
-        r2, v2, d2, ov2 = _run(sub, 2 * u_max)
+        r2, v2, d2, ov2, _k2 = _run(sub, 2 * u_max)
         if ov2.any():
             raise s.CausalError(
                 "full-width level overflowed its doubled token budget",
@@ -307,7 +310,7 @@ def dispatch_full_rows(lanes, site: str = "tree"):
         visible[rows] = v2
         digest[rows] = d2
     return rank, visible, digest, {
-        "u_need": u_need, "u_max": int(u_max), "retried": retried,
+        "u_need": u_need, "u_max": int(u_max), "retried": retried, "k": k,
     }
 
 
@@ -751,7 +754,9 @@ def _dispatch_wave(lanes, mesh, uuid: str):
     when given), fetch, and one doubled-budget retry of the rows whose
     sampled token budget overflowed. Returns ``(rank, visible, digest,
     overflow, info)`` as host arrays in batch-row order, ``info``
-    holding ``kernel``, ``u_need``, ``u_max`` and ``retried``."""
+    holding ``kernel``, ``u_need``, ``u_max``, ``retried`` and ``k``
+    (the run width the kernel ran at, on the ``wave.kernel`` span as
+    well)."""
     from ..benchgen import LANE_KEYS5, v5_token_budget
 
     # BENCH_KERNEL routes the wave's kernel variant (the api-level
@@ -780,16 +785,23 @@ def _dispatch_wave(lanes, mesh, uuid: str):
             from ..weaver.jaxw5 import batched_merge_weave_v5 as fn
 
             kw = {"u_max": u, "k_max": u,
-                  "euler": "walk" if pipeline == "v5w" else "doubling"}
+                  "euler": "walk" if pipeline == "v5w" else "doubling",
+                  "with_k": True}
 
         dev = _upload(sub_lanes, LANE_KEYS5)
         args = [dev[k] for k in LANE_KEYS5]
         with obs.span("wave.kernel", u_max=int(u),
-                      rows=int(sub_lanes["hi"].shape[0])):
-            r, v, _c, ov = _recovery.run_dispatch(
+                      rows=int(sub_lanes["hi"].shape[0])) as ksp:
+            r, v, _c, ov, *ran_at = _recovery.run_dispatch(
                 "wave", lambda: fn(*args, **kw))
             d = _digest_fn()(dev["hi"], dev["lo"], r, v)
             jax.block_until_ready((r, v, d, ov))
+            # v5f returns no width: it ranks its runs at the cap. The
+            # width is a scalar of the finished program, read with no
+            # wait
+            width = count_run_width(
+                int(ran_at[0]) if ran_at else int(u), int(u))
+            ksp.set(k=width)
         _LAST_KERNEL.update(fn=fn, kw=kw, args=[
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args])
         if obs.enabled():
@@ -801,7 +813,7 @@ def _dispatch_wave(lanes, mesh, uuid: str):
             _cm.record_dispatch(f"wave:{pipeline}:u{int(u)}",
                                 site="wave")
             _cm.record_dispatch("wave:digest", site="wave")
-        return _fetch(r, v, d, ov)
+        return (*_fetch(r, v, d, ov), width)
 
     # pow2-quantized budget: every distinct u_max is a distinct XLA
     # program, so exact budgets would recompile on every wave whose
@@ -826,12 +838,15 @@ def _dispatch_wave(lanes, mesh, uuid: str):
                     "BENCH_KERNEL=v5w has no sharded wave step; use "
                     "v5 or v5f under a mesh")
             jl = _upload(lanes, tuple(lanes))
-            with obs.span("wave.kernel", u_max=int(u_max), rows=n_rows):
+            with obs.span("wave.kernel", u_max=int(u_max), rows=n_rows,
+                          k=int(u_max)):
                 rank, visible, overflow, digest, _tv, _nc, _n_ov = (
                     sharded_merge_weave_v5(mesh, jl, u_max=u_max,
                                            k_max=u_max,
                                            pipeline=pipeline))
                 jax.block_until_ready((rank, visible, overflow, digest))
+            # the sharded step ranks its runs at the cap
+            width = count_run_width(int(u_max), int(u_max))
             rank, visible, digest, overflow = _fetch(
                 rank, visible, digest, overflow)
             if obs.enabled():
@@ -843,7 +858,8 @@ def _dispatch_wave(lanes, mesh, uuid: str):
                     f"wave:sharded:{pipeline}:u{int(u_max)}",
                     site="wave")
         else:
-            rank, visible, digest, overflow = dispatch_v5(lanes, u_max)
+            rank, visible, digest, overflow, width = dispatch_v5(lanes,
+                                                                 u_max)
     n_retried = 0
     if overflow.any():
         # the token budget samples rows; a spiky unsampled row can
@@ -863,7 +879,7 @@ def _dispatch_wave(lanes, mesh, uuid: str):
         sub = {k: lanes[k][rows] for k in LANE_KEYS5}
         with obs.span("wave.dispatch.retry", rows=len(rows),
                       u_max=int(2 * u_max)):
-            r2, v2, d2, ov2 = dispatch_v5(sub, 2 * u_max)
+            r2, v2, d2, ov2, _k2 = dispatch_v5(sub, 2 * u_max)
         rank = np.array(rank)
         visible = np.array(visible)
         digest = np.array(digest)
@@ -875,7 +891,7 @@ def _dispatch_wave(lanes, mesh, uuid: str):
 
     return rank, visible, digest, overflow, {
         "kernel": pipeline, "u_need": u_need, "u_max": int(u_max),
-        "retried": n_retried,
+        "retried": n_retried, "k": width,
     }
 
 
@@ -957,6 +973,14 @@ def _pair_views(pairs):
             views[i] = None
         live = [i for i, v in enumerate(views) if v is not None]
     return views, live, fallback, poisoned
+
+
+def count_run_width(k: int, k_max: int) -> int:
+    """Count one v5 dispatch by the run width it ran at: ``v5.runs.
+    narrow`` under the run cap ``k_max``, else ``v5.runs.full``.
+    Returns ``k``, for the dispatch's span."""
+    obs.counter("v5.runs.narrow" if k < k_max else "v5.runs.full").inc()
+    return k
 
 
 def _upload(lanes, keys):

@@ -34,6 +34,14 @@ and v1 (tests/test_jax_v5.py); like v2-v4 the kernel takes static
 budgets (``s`` is the table size, ``u_max`` tokens, ``k_max`` runs)
 and raises an overflow flag instead of corrupting.
 
+``k_max`` is the run cap and overflow line, not the width that always
+runs. Runs are maximal glued token chains, usually far fewer than the
+tokens, so the batched entry points (``batched_v5``) rank the runs at
+the smallest width of a power-of-two ladder from 128 up to ``k_max``
+that holds the batch's largest run count, chosen on the device. A
+single row (``merge_weave_kernel_v5``) and the delta window program
+run at ``k_max``.
+
 Twin-dedupe integrity: the twin test compares endpoints, length,
 density, head vclass + cause, tail-specialness, AND a position
 -weighted vclass checksum (``sg_vsum``), so a same-id twin whose
@@ -54,7 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..switches import resolve
+from ..switches import raw_switch_key, resolve
 from .arrays import I32_MAX, VCLASS_H_HIDE, VCLASS_HIDE
 from .jaxw import _euler_rank, _link_children
 from .jaxw3 import _shift1
@@ -65,6 +73,8 @@ from .gatherops import (searchsorted_iota_right,
 __all__ = [
     "merge_weave_kernel_v5",
     "batched_merge_weave_v5",
+    "batched_v5",
+    "run_widths",
 ]
 
 
@@ -154,6 +164,12 @@ def _pair_search_le(kh, kl, qh, ql, size):
         return lo_b
 
 
+def _rides() -> bool:
+    """Whether payloads ride the token and lane sorts (network and
+    matrix sorts) instead of being gathered by permutation."""
+    return resolve("CAUSE_TPU_SORT") in ("bitonic", "pallas", "matrix")
+
+
 def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
                                 sg_min_hi, sg_min_lo, sg_max_hi,
                                 sg_max_lo, sg_len, sg_lane0, sg_dense,
@@ -178,7 +194,31 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
     device trace's ops can be named by phase. ``stage="_AB"`` is the
     fused v5f pipeline's handoff after phase B (jaxw5f); ``None``, the
     default, runs the full kernel.
+
+    The kernel is three pieces: ``_v5_front`` (phases A-D and phase E
+    up to the run count), ``_v5_run_tail`` (the rest of phase E, at a
+    static run width) and ``_v5_expand`` (phase F). One row runs the
+    tail at ``k_max``; ``batched_v5`` runs it at the batch's width.
     """
+    fr = _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
+                   sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
+                   sg_tail_special, sg_valid, sg_vsum,
+                   u_max=u_max, k_max=k_max, stage=stage)
+    if stage == "_AB":
+        return fr
+    delta_u, vict_tail = _v5_run_tail(fr, k_max, euler)
+    return _v5_expand(fr, delta_u, vict_tail)
+
+
+def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
+              sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
+              sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int,
+              stage: str | None = None):
+    """Phases A-D and the token-width head of phase E for one row, up
+    to the run table's inputs (``rs_cum``, ``n_runs``, ``head_tok``).
+    Returns a dict of the arrays the run tail and phase F read, or the
+    ``stage="_AB"`` handoff. ``k_max`` sets ``overflow_k`` and the width
+    of ``head_tok``."""
     # ================= A. segment ordering + explode/dedupe =========
     with jax.named_scope("v5.A"):
         N = hi.shape[0]
@@ -358,8 +398,7 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
         # Identical results either way: same keys, same implicit-iota
         # stability, and payload-carry == gather-by-permutation.
         su_src_in = uidx
-        ride = resolve("CAUSE_TPU_SORT") in ("bitonic", "pallas", "matrix")
-        if ride:
+        if _rides():
             (st_hi, st_lo, t_src, sv_len, sv_vc, sv_tsp_i,
              sv_lane) = sort_pairs(
                 (t_hi, t_lo, su_src_in, t_len, t_vc,
@@ -461,43 +500,91 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
 
         run_start = keep_t & ~glued
         rs_cum = jnp.cumsum(run_start.astype(jnp.int32))
-        run_id = rs_cum - 1
         n_runs = rs_cum[-1]
         overflow_k = n_runs > k_max
-
-        targets = jnp.arange(1, k_max + 1, dtype=jnp.int32)
+        # each run's head token (a prefix of this serves any narrower
+        # run width), and each token's run-head node offset
         head_tok = searchsorted_targets_left(rs_cum, k_max)
-        r_valid = targets <= jnp.minimum(n_runs, k_max)
+        ffw = lax.cummax(jnp.where(run_start, wstart, -1))
+
+        # token-level kills inside runs (victims as lanes)
+        hideish = (sv_vc == VCLASS_HIDE) | (sv_vc == VCLASS_H_HIDE)
+        kg = glued & hideish
+        vict_inrun = jnp.where(
+            kg, take1d(sv_tail_lane, jnp.clip(prev_kept, 0, U - 1)), N
+        )
+
+    return dict(
+        vclass=vclass, valid=valid, seg=seg, sg_valid=sg_valid,
+        sg_lane0=sg_lane0, sg_len=sg_len, survive=survive, inv_s=inv_s,
+        keep_t=keep_t, sv_lane=sv_lane, sv_tail_lane=sv_tail_lane,
+        cause_su=cause_su, parent_su=parent_su, is_root_t=is_root_t,
+        special_t=special_t, wstart=wstart, n_kept_nodes=n_kept_nodes,
+        sp_pack=sp_pack, prev_kept=prev_kept, adj=adj,
+        irregular=irregular, rs_cum=rs_cum, head_tok=head_tok, ffw=ffw,
+        n_runs=n_runs, hideish=hideish, kg=kg, vict_inrun=vict_inrun,
+        conflict=conflict, overflow_u=overflow_u, overflow_k=overflow_k,
+    )
+
+
+def _v5_run_tail(fr, k: int, euler: str):
+    """The run-width half of phase E for one row at static run width
+    ``k``: the run tables, the sibling sort, the Euler ranking, and the
+    successor kills. Returns ``(delta_u, vict_tail)``: the run bases as
+    deltas at their head tokens (``_v5_expand`` sums them into token
+    ranks), and per run slot the lane of a tail its successor hides
+    (N where none).
+
+    Exact at any ``k >= n_runs``: runs are the first ``n_runs`` slots
+    (``r_valid``), the padding slots carry zero weight and parent no
+    valid run, and they share the root's sibling group (parent ``k``,
+    out of range, so nothing links to it) where the root sorts last.
+    Every valid slot's tour, base, successor and kill are therefore
+    the same at every such ``k``. With ``n_runs > k`` the row has
+    overflowed, as ``overflow_k`` says."""
+    with jax.named_scope("v5.E"):
+        keep_t, wstart = fr["keep_t"], fr["wstart"]
+        prev_kept, n_runs = fr["prev_kept"], fr["n_runs"]
+        U = keep_t.shape[0]
+        N = fr["vclass"].shape[0]
+        BIG = I32_MAX
+        run_id = fr["rs_cum"] - 1
+
+        targets = jnp.arange(1, k + 1, dtype=jnp.int32)
+        head_tok = fr["head_tok"][:k]
+        r_valid = targets <= jnp.minimum(n_runs, k)
         hc = jnp.clip(head_tok, 0, U - 1)
 
         h_parent = jnp.where(
-            take1d(irregular, hc), take1d(parent_su, hc),
-            jnp.where(take1d(adj, hc), take1d(prev_kept, hc), -1),
+            take1d(fr["irregular"], hc), take1d(fr["parent_su"], hc),
+            jnp.where(take1d(fr["adj"], hc), take1d(prev_kept, hc), -1),
         )
-        h_parent = jnp.where(r_valid & ~take1d(is_root_t, hc), h_parent, -1)
+        h_parent = jnp.where(r_valid & ~take1d(fr["is_root_t"], hc),
+                             h_parent, -1)
         parent_run = jnp.where(
             h_parent >= 0, take1d(run_id, jnp.clip(h_parent, 0, U - 1)), -1
         ).astype(jnp.int32)
 
-        h_special = take1d(special_t, hc)
+        h_special = take1d(fr["special_t"], hc)
         h_w = take1d(wstart, hc)
         nxt_w = jnp.concatenate([h_w[1:], h_w[:1]])
         run_w = jnp.where(
             r_valid,
-            jnp.where(targets == n_runs, n_kept_nodes - h_w, nxt_w - h_w),
+            jnp.where(targets == n_runs, fr["n_kept_nodes"] - h_w,
+                      nxt_w - h_w),
             0,
         ).astype(jnp.int32)
 
-        parent_sort = jnp.where(r_valid & (parent_run >= 0), parent_run, k_max)
+        parent_sort = jnp.where(r_valid & (parent_run >= 0), parent_run, k)
         packed = parent_sort * 2 + (~h_special).astype(jnp.int32)
-        kidx_r = jnp.arange(k_max, dtype=jnp.int32)
+        kidx_r = jnp.arange(k, dtype=jnp.int32)
         sord = sort_pairs((packed, -hc, kidx_r), num_keys=2)[2]
         fc, ns = _link_children(sord, parent_sort)
         parent_up = jnp.where(r_valid & (parent_run >= 0), parent_run, -1)
         if euler == "walk":
             from .pallas_ops import euler_walk
 
-            base_run = euler_walk(fc, ns, parent_up, run_w, k_max)
+            base_run = euler_walk(fc, ns, parent_up, run_w, k)
         else:
             base_run, _ = _euler_rank(fc, ns, parent_up, run_w)
 
@@ -514,21 +601,9 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
         # globally sorted AND unique by construction (no shared dump slot,
         # no collision fix-up needed)
         scat_du = jnp.where(r_valid, hc, U + kidx_r)
-        delta_u = jnp.zeros(U + k_max, jnp.int32).at[scat_du].set(
+        delta_u = jnp.zeros(U + k, jnp.int32).at[scat_du].set(
             delta.astype(jnp.int32),
             **_hint_kw(sorted_=True, unique=True))[:U]
-        base_ff = jnp.cumsum(delta_u)
-        ffw = lax.cummax(jnp.where(run_start, wstart, -1))
-        rank_tok = jnp.where(
-            keep_t, base_ff + (wstart - ffw), N
-        ).astype(jnp.int32)
-
-        # -------- token-level kills (victims as lanes) ------------------
-        hideish = (sv_vc == VCLASS_HIDE) | (sv_vc == VCLASS_H_HIDE)
-        kg = glued & hideish
-        vict_inrun = jnp.where(
-            kg, take1d(sv_tail_lane, jnp.clip(prev_kept, 0, U - 1)), N
-        )
 
         # preorder-successor run: the run with the next-larger base. base
         # values are node-unit positions (up to N), so find successors by
@@ -541,36 +616,60 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
         succ_valid = jnp.concatenate([
             b_sorted[1:] != BIG, jnp.zeros((1,), bool)
         ])
-        succ_of = jnp.full(k_max, -1, jnp.int32).at[b_src].set(
-            jnp.where(succ_valid, succ_in_sorted, -1), **uniq
+        succ_of = jnp.full(k, -1, jnp.int32).at[b_src].set(
+            jnp.where(succ_valid, succ_in_sorted, -1),
+            **_hint_kw(unique=True)
         )
         succ_run = jnp.where(r_valid, succ_of, -1)
         s_c = jnp.clip(
             jnp.where(succ_run >= 0,
-                      take1d(hc, jnp.clip(succ_run, 0, k_max - 1)), 0),
+                      take1d(hc, jnp.clip(succ_run, 0, k - 1)), 0),
             0, U - 1,
         )
-        s_is_hide = (succ_run >= 0) & take1d(hideish, s_c)
+        s_is_hide = (succ_run >= 0) & take1d(fr["hideish"], s_c)
         nxt_head = jnp.concatenate([hc[1:], hc[:1]])
         tail_tok = jnp.where(
             targets == n_runs,
-            jnp.maximum(sp_pack[-1] >> 1, 0),
+            jnp.maximum(fr["sp_pack"][-1] >> 1, 0),
             take1d(prev_kept, jnp.clip(nxt_head, 0, U - 1)),
         ).astype(jnp.int32)
         t_cc = jnp.clip(tail_tok, 0, U - 1)
         # succ head's cause must BE the run's tail node — compared at
         # token level (cause_su is duplicate-redirected; a hide arriving
         # from another replica names its own dropped copy of the tail)
-        kill_tail = r_valid & s_is_hide & (take1d(cause_su, s_c) == tail_tok)
-        vict_tail = jnp.where(kill_tail, take1d(sv_tail_lane, t_cc), N)
+        kill_tail = (r_valid & s_is_hide
+                     & (take1d(fr["cause_su"], s_c) == tail_tok))
+        vict_tail = jnp.where(kill_tail,
+                              take1d(fr["sv_tail_lane"], t_cc), N)
+    return delta_u, vict_tail
 
+
+def _v5_expand(fr, delta_u, vict_tail):
+    """The token ranks (the end of phase E), then phase F for one row:
+    token ranks and kills expanded to concat lanes. Returns ``(rank,
+    visible, conflict, overflow)``."""
+    vclass, valid, seg = fr["vclass"], fr["valid"], fr["seg"]
+    sg_valid, sg_lane0, sg_len = fr["sg_valid"], fr["sg_lane0"], fr["sg_len"]
+    keep_t, sv_lane = fr["keep_t"], fr["sv_lane"]
+    N = vclass.shape[0]
+    S = sg_len.shape[0]
+    U = keep_t.shape[0]
+    uniq = _hint_kw(unique=True)
+    conflict = fr["conflict"]
+    with jax.named_scope("v5.E"):
+        rank_tok = jnp.where(
+            keep_t, jnp.cumsum(delta_u) + (fr["wstart"] - fr["ffw"]), N
+        ).astype(jnp.int32)
     # ================= F. expansion to concat lanes =================
     with jax.named_scope("v5.F"):
+        sidx = jnp.arange(S, dtype=jnp.int32)
+        uidx = jnp.arange(U, dtype=jnp.int32)
+        overflow = fr["overflow_u"] | fr["overflow_k"]
         # token base + token lane, in LANE order (sort tokens by lane) so
         # deltas scatter + cumsum reconstructs per-lane values without any
         # full-width gather
         lane_key = jnp.where(keep_t & (rank_tok < N), sv_lane, N)
-        if ride:  # rank rides the lane sort (see phase C note)
+        if _rides():  # rank rides the lane sort (see phase C note)
             lk, tok_at, tb_l = sort_pairs((lane_key, uidx, rank_tok),
                                           num_keys=1)
         else:
@@ -580,12 +679,11 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
         # pieces shared by both F backends: per-lane coverage flags, the
         # token-level kill scatters (victims can duplicate — genuine
         # scatters, U-width, stay in XLA either way), and the root lane
-        seg_cov = sg_valid & take1d(survive, inv_s)
+        seg_cov = sg_valid & take1d(fr["survive"], fr["inv_s"])
         killed_sc = jnp.zeros(N + 1, bool)
-        killed_sc = killed_sc.at[jnp.where(kg, vict_inrun, N)].set(
-            True, mode="drop")
-        killed_sc = killed_sc.at[jnp.where(kill_tail, vict_tail, N)].set(
-            True, mode="drop")
+        killed_sc = killed_sc.at[jnp.where(fr["kg"], fr["vict_inrun"], N)
+                                 ].set(True, mode="drop")
+        killed_sc = killed_sc.at[vict_tail].set(True, mode="drop")
         root_lane = jnp.zeros(N, bool).at[
             jnp.clip(sv_lane[0], 0, N - 1)
         ].set(keep_t[0])
@@ -606,7 +704,6 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
                      | (killed_ext.astype(jnp.int32) << 1))
             rank_lane, visible = fphase_expand(
                 lk, tb_l, cs, ce, vclass, seg, flags)
-            overflow = overflow_u | overflow_k
             return rank_lane, visible, conflict, overflow
 
         tl_l = jnp.where(lk < N, lk, 0)
@@ -686,7 +783,6 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
         visible = (
             valid & (rank_lane < N) & (vclass == 0) & ~root_lane & ~killed
         )
-        overflow = overflow_u | overflow_k
         return rank_lane, visible, conflict, overflow
 
 
@@ -721,23 +817,106 @@ merge_weave_kernel_v5_jit = jax.jit(
 )
 
 
-@partial(jax.jit, static_argnames=("u_max", "k_max", "euler"))
+def run_widths(k_max: int) -> tuple:
+    """The run-width ladder of a batch with run cap ``k_max``: 128 (one
+    lane row), 256, ... while below ``k_max``, then ``k_max`` itself."""
+    ks = []
+    k = 128
+    while k < k_max:
+        ks.append(k)
+        k *= 2
+    return tuple(ks) + (int(k_max),)
+
+
+def batched_v5(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
+               sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
+               sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int,
+               euler: str = "doubling"):
+    """The batched v5 body (traced inside a caller's jit): every row's
+    front, then the run tail of all rows at the smallest width of
+    ``run_widths(k_max)`` that holds the batch's largest run count,
+    chosen on the device by one ``lax.switch`` outside the row
+    ``vmap``, then phase F. Returns ``(rank, visible, conflict,
+    overflow, k)``, ``k`` the width the tail ran at (int32 scalar).
+
+    Bit-identical to every row running at ``k_max`` (see
+    ``_v5_run_tail``); a batch whose run count passes ``k_max`` runs at
+    ``k_max`` and flags ``overflow`` exactly as before."""
+    from ..obs import span as _span
+
+    with _span("weave.trace.v5", n=int(hi.shape[-1]),
+               u_max=int(u_max), k_max=int(k_max), stage="BATCHED",
+               euler=euler):
+        sw = raw_switch_key()
+        # the scopes around the row pieces name what XLA keeps of the
+        # call boundaries (the pieces name their own ops)
+        with jax.named_scope("v5.E"):
+            fr = jax.vmap(partial(_front_row, u_max=u_max, k_max=k_max,
+                                  switches=sw))(
+                hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
+                sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
+                sg_tail_special, sg_valid, sg_vsum)
+        N = hi.shape[-1]
+        ks = run_widths(k_max)
+
+        def tail(k):
+            def run(fr):
+                delta_u, vict = jax.vmap(
+                    partial(_tail_row, k=k, euler=euler, switches=sw))(fr)
+                # pad the per-run kills to k_max with the no-kill lane
+                return delta_u, jnp.pad(vict, ((0, 0), (0, k_max - k)),
+                                        constant_values=N)
+            return run
+
+        with jax.named_scope("v5.E"):
+            widest = jnp.max(fr["n_runs"])
+            i = jnp.sum(jnp.asarray(ks[:-1], jnp.int32) < widest)
+            width = jnp.asarray(ks, jnp.int32)[i]
+            delta_u, vict_tail = lax.switch(i, [tail(k) for k in ks], fr)
+        with jax.named_scope("v5.F"):
+            rank, visible, conflict, overflow = jax.vmap(
+                partial(_expand_row, switches=sw))(fr, delta_u, vict_tail)
+        return rank, visible, conflict, overflow, width
+
+
+# The row pieces of ``batched_v5``, each under its own jit: programs of
+# one row shape (the merge tree's levels, one per batch size) then
+# trace each piece once, where tracing the nine tails again for every
+# batch size doubled a program's trace time. ``switches`` keys each
+# trace on the trace-time strategy snapshot (``raw_switch_key``).
+@partial(jax.jit, static_argnames=("u_max", "k_max", "switches"))
+def _front_row(*lanes, u_max: int, k_max: int, switches: tuple):
+    return _v5_front(*lanes, u_max=u_max, k_max=k_max)
+
+
+@partial(jax.jit, static_argnames=("k", "euler", "switches"))
+def _tail_row(fr, k: int, euler: str, switches: tuple):
+    return _v5_run_tail(fr, k, euler)
+
+
+@partial(jax.jit, static_argnames=("switches",))
+def _expand_row(fr, delta_u, vict_tail, switches: tuple):
+    return _v5_expand(fr, delta_u, vict_tail)
+
+
+@partial(jax.jit, static_argnames=("u_max", "k_max", "euler", "with_k"))
 def batched_merge_weave_v5(hi, lo, cci, vclass, valid, seg,
                            sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
                            sg_len, sg_lane0, sg_dense, sg_tail_special,
                            sg_valid, sg_vsum, u_max: int, k_max: int,
-                           euler: str = "doubling"):
+                           euler: str = "doubling", with_k: bool = False):
     """Segment-union batch: [B, N] node lanes + [B, S] segment tables
     -> per-replica (rank, visible, conflict, overflow), rank/visible
-    indexed by concat lane. ``euler="walk"`` ranks the contracted
-    forest with the sequential Pallas traversal (pallas_ops.euler_walk)
-    instead of log-depth pointer doubling."""
-
-    def row(*a):
-        return merge_weave_kernel_v5(*a, u_max=u_max, k_max=k_max,
-                                     euler=euler)
-
-    return jax.vmap(row)(hi, lo, cci, vclass, valid, seg,
-                         sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
-                         sg_len, sg_lane0, sg_dense, sg_tail_special,
-                         sg_valid, sg_vsum)
+    indexed by concat lane, plus the run width the batch ran at when
+    ``with_k`` (``batched_v5``). ``k_max`` is the run cap, the line
+    past which a row overflows; the run tail runs at the smallest
+    width of ``run_widths(k_max)`` that holds the batch's runs.
+    ``euler="walk"`` ranks the contracted forest with the sequential
+    Pallas traversal (pallas_ops.euler_walk) instead of log-depth
+    pointer doubling."""
+    out = batched_v5(hi, lo, cci, vclass, valid, seg,
+                     sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
+                     sg_len, sg_lane0, sg_dense, sg_tail_special,
+                     sg_valid, sg_vsum, u_max=u_max, k_max=k_max,
+                     euler=euler)
+    return out if with_k else out[:4]

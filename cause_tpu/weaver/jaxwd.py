@@ -65,7 +65,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from .jaxw5 import merge_weave_kernel_v5
+from .jaxw5 import batched_v5, merge_weave_kernel_v5
 
 __all__ = [
     "batched_delta_weave",
@@ -74,28 +74,30 @@ __all__ = [
 ]
 
 
-@partial(jax.jit, static_argnames=("u_max", "k_max"))
+@partial(jax.jit, static_argnames=("u_max", "k_max", "with_k"))
 def batched_weave_digest(hi, lo, cci, vclass, valid, seg,
                          sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
                          sg_len, sg_lane0, sg_dense, sg_tail_special,
-                         sg_valid, sg_vsum, u_max: int, k_max: int):
+                         sg_valid, sg_vsum, u_max: int, k_max: int,
+                         with_k: bool = False):
     """The full-width control program: one fused dispatch running the
-    batched v5 segment-union kernel AND the per-row convergence digest.
-    Returns ``(rank, visible, digest, overflow)``. This is what the
-    divergence sweep and the harvest digest gate time as the
-    full-weave A/B arm — kernel + digest in one program, the same
-    shape of work a session's full wave performs in two."""
+    batched v5 segment-union kernel (``jaxw5.batched_v5``: ``k_max`` is
+    the run cap, the runs rank at the batch's own width) AND the
+    per-row convergence digest. Returns ``(rank, visible, digest,
+    overflow)``, plus the run width the batch ran at when ``with_k``.
+    This is what the divergence sweep and the harvest digest gate time
+    as the full-weave A/B arm — kernel + digest in one program, the
+    same shape of work a session's full wave performs in two."""
     from ..parallel.mesh import replica_digest
 
-    def row(*a):
-        return merge_weave_kernel_v5(*a, u_max=u_max, k_max=k_max)
-
-    rank, visible, conflict, overflow = jax.vmap(row)(
+    rank, visible, _conflict, overflow, k = batched_v5(
         hi, lo, cci, vclass, valid, seg,
         sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
-        sg_len, sg_lane0, sg_dense, sg_tail_special, sg_valid, sg_vsum)
+        sg_len, sg_lane0, sg_dense, sg_tail_special, sg_valid, sg_vsum,
+        u_max=u_max, k_max=k_max)
     digest = jax.vmap(replica_digest)(hi, lo, rank, visible)
-    return rank, visible, digest, overflow
+    out = (rank, visible, digest, overflow)
+    return out + (k,) if with_k else out
 
 
 @partial(jax.jit, static_argnames=("u_max", "k_max"))

@@ -19,9 +19,9 @@ from cause_tpu.weaver.arrays import NodeArrays, SiteInterner
 
 from test_list import rand_node
 
-# Heavy differential-fuzz suite: CI runs it as a dedicated job;
-# the fast default set keeps tiny-shape coverage in test_jax_smoke.py
-pytestmark = pytest.mark.slow
+# The heavy differential fuzz is marked slow (CI runs it as a dedicated
+# job; the fast default set keeps tiny-shape coverage in
+# test_jax_smoke.py); the run-width cases at the end are fast.
 
 
 def v1_concat(args_v1):
@@ -82,6 +82,7 @@ def check_row(row, capacity):
     return c1, c5
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize(
     "nb,nd,cap,he",
     [(40, 12, 64, 3), (100, 40, 256, 5), (5, 3, 16, 2), (0, 4, 16, 0),
@@ -94,6 +95,7 @@ def test_v5_pair_merge_parity(nb, nd, cap, he):
     check_row(row, cap)
 
 
+@pytest.mark.slow
 def test_v5_wholesale_dedupe_actually_happens():
     """The point of v5: the shared base must ride as one token, not
     explode — token estimate for a large-base pair stays divergence-
@@ -146,6 +148,7 @@ def concat_api_rows(handles, cap):
     }
 
 
+@pytest.mark.slow
 def test_v5_fuzz_tree_parity():
     rng = random.Random(0x5E6)
     for _ in range(30):
@@ -157,6 +160,7 @@ def test_v5_fuzz_tree_parity():
         check_row(row, cap)
 
 
+@pytest.mark.slow
 def test_v5_concat_of_two_api_trees():
     rng = random.Random(77)
     base = c.clist(*"abcdef")
@@ -168,6 +172,7 @@ def test_v5_concat_of_two_api_trees():
     check_row(concat_api_rows([ra, rb], 64), 64)
 
 
+@pytest.mark.slow
 def test_v5_hypothesis_random_interactions():
     from hypothesis import given, settings, strategies as st
 
@@ -200,6 +205,7 @@ def test_v5_hypothesis_random_interactions():
     prop()
 
 
+@pytest.mark.slow
 def test_v5_batched_parity_and_overflow():
     B, cap = 5, 64
     batch = benchgen.batched_pair_lanes(
@@ -238,6 +244,7 @@ def test_v5_batched_parity_and_overflow():
     assert np.asarray(ovf).any()
 
 
+@pytest.mark.slow
 def test_v5_conflict_flag():
     """Dup tokens with differing bodies flag a conflict (exploded
     regions only — wholesale-deduped twins are exempt by design)."""
@@ -258,6 +265,7 @@ def test_v5_conflict_flag():
     assert conf
 
 
+@pytest.mark.slow
 def test_v5_three_way_union_parity():
     """K-ary union: three replicas' lanes concatenated — twin groups of
     three (the shared base), multi-interval overlaps, and cross-replica
@@ -275,6 +283,7 @@ def test_v5_three_way_union_parity():
     check_row(concat_api_rows(reps, 32), 32)
 
 
+@pytest.mark.slow
 def test_v5_adversarial_replica_fuzz():
     """Directed fuzz of the segment-union edge logic: random replica
     counts (2-4), random shared-prefix lengths, random multi-site
@@ -298,3 +307,181 @@ def test_v5_adversarial_replica_fuzz():
             reps.append(r)
         cap = 64
         check_row(concat_api_rows(reps, cap), cap)
+
+
+# ------------------------------------------------------ run-width ladder
+#
+# The batched entry points rank the runs at the smallest width of
+# ``jaxw5.run_widths(k_max)`` that holds the batch's largest run count.
+# Every case below checks the batched body against the row kernel at
+# the fixed ``k_max`` (bit for bit, overflow included) and against the
+# pure weaver, at one shape: two trees of CAP lanes a row, ladder 128,
+# 256, 512.
+
+from functools import lru_cache, partial  # noqa: E402
+
+import jax  # noqa: E402
+
+from cause_tpu.collections.clist import CausalList  # noqa: E402
+
+CAP, S_MAX, U_LADDER = 256, 512, 512
+
+
+@lru_cache(maxsize=None)
+def _fixed_kernel(u_max, k_max, euler):
+    return jax.jit(jax.vmap(partial(jaxw5.merge_weave_kernel_v5,
+                                    u_max=u_max, k_max=k_max,
+                                    euler=euler)))
+
+
+def _fork(base):
+    return CausalList(base.ct.evolve(site_id=new_site_id()))
+
+
+def _doc(n):
+    return c.clist(*[f"d{i}" for i in range(n)])
+
+
+def _tail_pastes(base, n, hide_every=8):
+    """A replica pasting ``n`` values at the end, every
+    ``hide_every``-th a hide of the value before it."""
+    h = _fork(base)
+    for i in range(1, n + 1):
+        h = h.conj(c.hide if i % hide_every == 0 else f"p{i}")
+    return h
+
+
+def _typed_anywhere(base, ops, rng):
+    """A replica typing ``ops`` ops in runs of 1-3 at cursors anywhere
+    in the document: 70% inserts (each after the last), 30% hides of
+    the cursor's value, never twice."""
+    h = _fork(base)
+    ids = [n[0] for n in base.ct.weave][1:]
+    hidden = set()
+    done = 0
+    while done < ops:
+        cursor = rng.choice(ids)
+        for _ in range(rng.randint(1, 3)):
+            if rng.random() < 0.3 and cursor in ids \
+                    and cursor not in hidden:
+                hidden.add(cursor)
+                h = h.append(cursor, c.hide)
+            else:
+                h = h.append(cursor, f"t{done}")
+                cursor = h.ct.yarns[h.ct.site_id][-1][0]
+            done += 1
+    return h
+
+
+def _star(base, m):
+    """A replica with ``m`` values typed straight after the document's
+    first one: off a one-value document, with an untouched twin, a row
+    of exactly ``m + 1`` runs."""
+    h = _fork(base)
+    first = base.ct.weave[1][0]
+    for i in range(m):
+        h = h.append(first, f"s{i}")
+    return h
+
+
+def _pure_weave(a, b):
+    """The pure weaver's merge: (weave ids, visible ids in order)."""
+    def pure(h):
+        return CausalList(h.ct.evolve(weaver="pure", lanes=None))
+
+    m = pure(a).merge(pure(b))
+    return [n[0] for n in m.ct.weave], [n[0] for n in m]
+
+
+def _batch(pairs):
+    rows = [benchgen.v5_inputs(concat_api_rows(p, CAP), CAP, s_max=S_MAX)
+            for p in pairs]
+    return [jnp.asarray(np.stack([r[k] for r in rows])) for k in LANE_KEYS5]
+
+
+def _lane_ids(a, b):
+    sites = {i[1] for h in (a, b) for i in h.ct.nodes}
+    it = SiteInterner(sites)
+    ids = []
+    for h in (a, b):
+        na = NodeArrays.from_nodes_map(h.ct.nodes, capacity=CAP,
+                                       interner=it)
+        ids += [n[0] for n in na.nodes] + [None] * (CAP - len(na.nodes))
+    return ids
+
+
+def _check_ladder(pairs, want_k, u_max=U_LADDER, k_max=U_LADDER,
+                  euler="doubling"):
+    """The batched body runs at ``want_k`` and matches the fixed-width
+    kernel bit for bit; rows that did not overflow weave as the pure
+    weaver does. Returns the overflow flags."""
+    args = _batch(pairs)
+    rank, vis, conf, ovf, k = jaxw5.batched_merge_weave_v5(
+        *args, u_max=u_max, k_max=k_max, euler=euler, with_k=True)
+    assert int(k) == want_k
+    fixed = _fixed_kernel(u_max, k_max, euler)(*args)
+    for got, ref in zip((rank, vis, conf, ovf), fixed):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+    rank, vis, ovf = np.asarray(rank), np.asarray(vis), np.asarray(ovf)
+    N = 2 * CAP
+    for i, (a, b) in enumerate(pairs):
+        if ovf[i]:
+            continue
+        ids = _lane_ids(a, b)
+        order = sorted((r, l) for l, r in enumerate(rank[i]) if r < N)
+        weave = [ids[l] for _, l in order]
+        shown = [ids[l] for _, l in order if vis[i][l]]
+        assert (weave, shown) == _pure_weave(a, b)
+    return ovf
+
+
+def test_run_widths_ladder():
+    assert jaxw5.run_widths(64) == (64,)
+    assert jaxw5.run_widths(128) == (128,)
+    assert jaxw5.run_widths(1024) == (128, 256, 512, 1024)
+    assert jaxw5.run_widths(32768)[-2:] == (16384, 32768)
+    assert len(jaxw5.run_widths(32768)) == 9
+
+
+@pytest.mark.parametrize("case", [
+    "tail_pastes", "typed_anywhere", "runs_at_pow2", "runs_past_pow2",
+    "one_wide_row"])
+def test_batched_runs_at_the_batch_width(case):
+    rng = random.Random(case)
+    base = _doc(120)
+    if case == "tail_pastes":
+        pairs = [(_tail_pastes(base, n), _tail_pastes(base, n + 5))
+                 for n in (8, 16, 32)]
+        want = 128
+    elif case == "typed_anywhere":
+        pairs = [(_typed_anywhere(base, n, rng),
+                  _typed_anywhere(base, n, rng)) for n in (8, 16, 24)]
+        want = 128
+    else:
+        m = {"runs_at_pow2": 127, "runs_past_pow2": 128,
+             "one_wide_row": 240}[case]
+        one = _doc(1)
+        pairs = [(_tail_pastes(base, 8), _tail_pastes(base, 8)),
+                 (_star(one, m), _fork(one)),
+                 (_typed_anywhere(base, 6, rng), _fork(base))]
+        want = 128 if m < 128 else 256
+    assert not _check_ladder(pairs, want).any()
+
+
+def test_runs_past_k_max_still_overflow():
+    """A row with more runs than ``k_max`` runs the tail at ``k_max``
+    and is flagged, as at the fixed width; the other rows are exact."""
+    base, one = _doc(120), _doc(1)
+    wide = (_star(one, 200), _star(one, 200))      # 401 runs
+    pairs = [wide, (_tail_pastes(base, 8), _tail_pastes(base, 9)),
+             (_star(one, 20), _fork(one))]
+    ovf = _check_ladder(pairs, 256, k_max=256)
+    assert ovf.tolist() == [True, False, False]
+
+
+def test_walk_runs_at_the_batch_width():
+    base, one = _doc(60), _doc(1)
+    rng = random.Random(5)
+    pairs = [(_star(one, 128), _fork(one)),
+             (_typed_anywhere(base, 10, rng), _tail_pastes(base, 12))]
+    assert not _check_ladder(pairs, 256, euler="walk").any()

@@ -412,6 +412,11 @@ def test_tail_appends_ride_one_full_level_then_delta():
     paths = [e["attrs"]["path"] for e in spans
              if e["name"] == "tree.dispatch"]
     assert paths == ["full"] + ["delta"] * (rounds - 1)
+    # level 0's few runs rank at the cap, the one width it has
+    level0 = next(e["attrs"] for e in spans if e["name"] == "tree.dispatch")
+    assert level0["k"] == level0["u_max"]
+    assert counters["v5.runs.full"] == 1
+    assert "v5.runs.narrow" not in counters
     # the root is the one subtree built from its window ranks; its
     # members are level 0's winners and its bye
     (mat,) = [e["attrs"] for e in spans if e["name"] == "tree.materialize"]
@@ -449,3 +454,15 @@ def test_a_forced_token_overflow_is_a_counted_fallback(monkeypatch):
     assert counters["tree.fallback.token-overflow"] == 1
     assert "tree.converge" in spans       # the tree ran, then gave up
     assert out.ct.weave == pure_fold(fleet).ct.weave
+
+
+def test_full_levels_rank_runs_below_the_token_budget():
+    base = make_base(120)
+    fleet = make_editors(base, 8, 12, seed=8)
+    root, rep, spans, counters = observed_tree(fleet)
+    assert_identical(root, pure_fold(fleet))
+    disp = [e["attrs"] for e in spans if e["name"] == "tree.dispatch"]
+    assert [d["path"] for d in disp] == ["full"] * 3
+    assert all(d["k"] < d["u_max"] for d in disp), disp
+    assert counters["v5.runs.narrow"] == 3
+    assert "v5.runs.full" not in counters

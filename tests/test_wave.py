@@ -1,6 +1,7 @@
 """Batched API-level merge waves: device wave == per-pair merge, with
 cached lanes doing the marshal and digests reporting convergence."""
 
+import random
 import sys
 import threading
 
@@ -600,3 +601,64 @@ def test_failed_wave_releases_and_resets_staging(obs_on, fresh_staging,
     res = merge_wave(pairs)
     assert _staging_counts() == (0, 2)
     _assert_merges(res, pairs)
+
+
+# -------------------------------------------------------- run width
+
+
+def _typed_pairs(n_pairs, n_base=120, ops=24, seed=3):
+    """Pairs whose replicas type at cursors anywhere in the document,
+    inserts and hides: many merge tokens a row, few runs."""
+    rng = random.Random(seed)
+    base = warm(c.clist(weaver="jax").extend(
+        [f"w{i}" for i in range(n_base)]))
+    ids = [n[0] for n in base.ct.weave][1:]
+
+    def typed():
+        h = CausalList(base.ct.evolve(site_id=new_site_id()))
+        for i, target in enumerate(rng.sample(ids, ops)):
+            h = h.append(target, c.hide if i % 3 == 2 else f"t{i}")
+        return h
+
+    return [(typed(), typed()) for _ in range(n_pairs)]
+
+
+def _widths(name):
+    """(k, u_max) of each ``name`` span in the obs ring."""
+    return [(e["attrs"]["k"], e["attrs"]["u_max"]) for e in obs.events()
+            if e.get("ev") == "span" and e.get("name") == name]
+
+
+def _run_counters():
+    got = obs.counters_snapshot()["counters"]
+    return got.get("v5.runs.narrow", 0), got.get("v5.runs.full", 0)
+
+
+def test_typed_wave_ranks_runs_below_the_token_budget(obs_on):
+    pairs = _typed_pairs(4)
+    res = merge_wave(pairs)
+    _assert_merges(res, pairs)
+    ((k, u_max),) = _widths("wave.kernel")
+    assert k == 128 < u_max
+    assert _run_counters() == (1, 0)
+
+
+def test_tail_wave_ranks_runs_at_the_cap(obs_on):
+    pairs = make_pairs(4)            # eight values pasted at each tail
+    res = merge_wave(pairs)
+    _assert_merges(res, pairs)
+    ((k, u_max),) = _widths("wave.kernel")
+    assert k == u_max
+    assert _run_counters() == (0, 1)
+
+
+def test_session_full_wave_records_its_run_width(obs_on):
+    from cause_tpu.parallel.session import FleetSession
+
+    pairs = _typed_pairs(2)
+    sess = FleetSession(pairs, delta=False)
+    digest = sess.wave()
+    assert (digest == merge_wave(pairs).digest).all()
+    ((k, u_max),) = _widths("session.wave")
+    assert k == 128 < u_max
+    assert _run_counters() == (2, 0)
