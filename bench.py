@@ -26,8 +26,7 @@ import os
 import sys
 import time
 
-from cause_tpu import obs  # dependency-light (no jax), like switches
-from cause_tpu.switches import TRACE_SWITCHES  # dependency-free
+from cause_tpu import obs  # dependency-light (no jax)
 
 NORTH_STAR_MS = 100.0
 # bench JSON schema version; v2's "fallback" flag is no longer emitted
@@ -697,31 +696,6 @@ def _flag(name: str) -> bool:
     return os.environ.get(name, "").strip() in ("1", "true", "yes")
 
 
-def _checksum_gate(default_ck, alt_ck, certified: bool) -> bool:
-    """The alt-config correctness gate's DECISION (pure, unit-tested):
-    returns True when the checksums deviate beyond tolerance.
-
-    Asymmetry by design (ADVICE r5 low #3): in the UNCERTIFIED branch
-    the already-timed default is the XLA program and the alt is the
-    untrusted candidate, so a deviation refuses the alt (raise —
-    never time a possibly-wrong program). In the CERTIFIED branch the
-    roles invert — the already-timed default is the certified config
-    and the alt IS the XLA baseline — so the deviation indicts the
-    certified program: return True and let the caller publish the
-    baseline's timing and tag the artifact ``checksum_deviation``."""
-    if default_ck is None or alt_ck is None:
-        return False
-    denom = max(abs(default_ck), 1.0)
-    if abs(alt_ck - default_ck) / denom <= 1e-3:
-        return False
-    if not certified:
-        raise RuntimeError(
-            f"alt checksum {alt_ck!r} deviates from default "
-            f"{default_ck!r}; refusing to time a possibly-wrong "
-            "program")
-    return True
-
-
 def measure(platform: str) -> dict:
     import numpy as np
 
@@ -832,20 +806,14 @@ def measure(platform: str) -> dict:
                 dev[k] = jax.device_put(v5batch[k])
 
     def dispatch(k: int, kernel: str):
-        lanes = (LANE_KEYS5 if kernel in ("v5", "v5w", "v5f")
-                 else LANE_KEYS4 if kernel in ("v4", "v4w")
+        lanes = (LANE_KEYS5 if kernel == "v5"
+                 else LANE_KEYS4 if kernel == "v4"
                  else LANE_KEYS)
         args = [dev[name] for name in lanes]
         return merge_wave_scalar(
             *args, k_max=k, kernel=kernel,
-            u_max=k if kernel in ("v5", "v5w", "v5f") else 0,
+            u_max=k if kernel == "v5" else 0,
         )
-
-    # the most recent step()'s checksum: the alt-config gate compares
-    # it against the default program's (the kernels are semantics
-    # -preserving across strategy switches, so the sums must agree up
-    # to float32 reduction-order noise)
-    last_ck = [None]
 
     def step(k: int, kernel: str) -> None:
         if obs.enabled():
@@ -860,15 +828,14 @@ def measure(platform: str) -> dict:
         out = np.asarray(dispatch(k, kernel))
         if k and out[1]:  # overflowed rows carry garbage ranks
             raise _Overflow()
-        last_ck[0] = float(out[0])
         if obs.enabled():
             from cause_tpu.obs import costmodel as _cm
 
-            v5_family = kernel in ("v5", "v5w", "v5f")
+            v5 = kernel == "v5"
             _cm.wave_cost(
                 uuid="bench", pairs=B, lanes=2 * cap * B,
-                tokens=k * B if v5_family else None,
-                token_budget=k * B if v5_family else 0,
+                tokens=k * B if v5 else None,
+                token_budget=k * B if v5 else 0,
                 delta_ops=2 * n_div * B, path="full")
 
     N_BURST = int(os.environ.get("BENCH_BURST", "8"))
@@ -889,36 +856,21 @@ def measure(platform: str) -> dict:
     # (merge cost ~ divergence), then v4 (marshal-resolved causes at
     # full width), then the chain-compressed v2 with a doubled budget,
     # then the uncompressed v1 (k_max=0, cannot overflow).
-    # BENCH_KERNEL prepends an explicit first choice (e.g. "v5w", the
-    # Pallas-euler-walk variant the measurement queue probes on TPU).
+    # BENCH_KERNEL prepends an explicit first choice.
     ladder = [(u_budget, "v5"), (2 * u_budget, "v5"),
               (budget, "v4"), (2 * budget, "v4"),
               (2 * budget, "v2"), (0, "v1")]
     forced = os.environ.get("BENCH_KERNEL", "").strip()
-    explicit = bool(forced)
-    if not forced:
-        # a chip-certified kernel from a measuring window ships as the
-        # default first rung (switches._tpu_defaults.json, written by
-        # harvest's decide_defaults); v5 is already the ladder head
-        from cause_tpu.switches import measured_kernel
-        forced = measured_kernel()
-        if forced == "v5":
-            forced = ""
     if forced:
-        # budget units differ per family: tokens for v5*, runs for the
-        # contracted kernels; an unknown ENV name must fail loudly, not
-        # silently time v2 under the forced label — a stale defaults
-        # file naming an unknown kernel is ignored instead
-        family = {"v5": u_budget, "v5w": u_budget,
-                  "v5f": u_budget, "v4": budget,
-                  "v4w": budget, "v3": 2 * budget, "v2": 2 * budget}
+        # budget units differ per family: tokens for v5, runs for the
+        # contracted kernels; an unknown name must fail loudly, not
+        # silently time v2 under the forced label
+        family = {"v5": u_budget, "v4": budget, "v3": 2 * budget,
+                  "v2": 2 * budget}
         if forced not in family:
-            if explicit:
-                raise SystemExit(
-                    f"bench: unknown BENCH_KERNEL {forced!r}; "
-                    f"one of {sorted(family)}")
-            forced = ""
-    if forced:
+            raise SystemExit(
+                f"bench: unknown BENCH_KERNEL {forced!r}; "
+                f"one of {sorted(family)}")
         fb = family[forced]
         ladder = [(fb, forced), (2 * fb, forced)] + ladder
     with obs.span("bench.ladder"):
@@ -949,126 +901,6 @@ def measure(platform: str) -> dict:
             [burst(k_max, kernel) for _ in range(burst_reps)]
         ))
 
-    # On real hardware, also try ONE alternative configuration and
-    # keep whichever is faster. With chip-certified defaults on disk
-    # (switches._tpu_defaults.json) the default path above already ran
-    # the winners, so the alternative is the forced-XLA baseline (the
-    # A/B re-confirms the winners on today's chip); with no certified
-    # defaults yet, the alternative is the XLA-only streaming
-    # candidate (rowgather + matrix search + scatter hints) — never a
-    # Mosaic config, whose kernels the chip's compiler refuses today
-    # (CHANGES.md, PR 21). BENCH_NO_ALLSTREAM skips it for isolated
-    # A/B runs.
-    preset = [f"{k.split('_')[-1].lower()}={os.environ[k]}"
-              for k in TRACE_SWITCHES if os.environ.get(k)]
-    config = "+".join(preset) if preset else "default"
-    want_alt = (((platform != "cpu" and not smoke)
-                 or _flag("BENCH_FORCE_ALLSTREAM"))
-                and not _flag("BENCH_NO_ALLSTREAM")
-                and not preset)
-    alt = None
-    checksum_deviation = False
-    if want_alt:
-        from cause_tpu.switches import TPU_DEFAULTS as _certified
-
-        if _certified:
-            # default above = the certified winners; alt = baseline.
-            # The label names the kernel: with a non-v5 certified
-            # kernel this A/B is xla-switches-under-that-kernel, NOT
-            # the v5 XLA baseline the certification was made against
-            # (decide_defaults only ever certifies v5 today, so in
-            # practice this IS the true baseline)
-            for k in TRACE_SWITCHES:
-                os.environ[k] = "xla"
-            alt_label = ("xla-baseline" if kernel == "v5"
-                         else f"xla-switches-{kernel}")
-            config = "measured-defaults"
-        else:
-            # ONE definition of the candidate combination, in
-            # switches.py next to the registry (import, never restate
-            # — a drifted copy here would A/B a different config than
-            # harvest certifies); Mosaic-free by its own contract
-            from cause_tpu.switches import BESTSTREAM_FLIPS
-
-            os.environ.update(BESTSTREAM_FLIPS)
-            alt_label = "beststream"
-        # the switches are read at TRACE time inside module-level
-        # jitted kernels whose caches key on avals only — without a
-        # cache clear the "allstream" attempt would silently re-trace
-        # to the already-cached default program and A/B noise against
-        # itself (the outer merge_wave_scalar key alone is NOT enough)
-        jax.clear_caches()
-        try:
-            default_ck = last_ck[0]
-            with obs.span("bench.alt_compile", config=alt_label):
-                step(k_max, kernel)  # compile + overflow check
-            # correctness gate on the UNGATED self-selection path
-            # (harvest's digest gate is the real certifier). For the
-            # v5 family the scalar is an exact order-independent
-            # avalanche digest, so any wrongness is a huge relative
-            # deviation; the tolerance only matters for the v1-v4
-            # fallback kernels whose scalar is still a float sum with
-            # reduction-order drift between differently-fused programs
-            try:
-                checksum_deviation = _checksum_gate(
-                    default_ck, last_ck[0], bool(_certified))
-            except RuntimeError:
-                # uncertified branch refusal: gate outcome still lands
-                # in the trace before the generic keep-default handler
-                obs.event("bench.checksum_gate", outcome="deviation",
-                          config=alt_label, default_ck=default_ck,
-                          alt_ck=last_ck[0], certified=False)
-                obs.counter("bench.checksum_gate.deviation").inc()
-                raise
-            obs.event(
-                "bench.checksum_gate",
-                outcome="deviation" if checksum_deviation else "match",
-                config=alt_label, default_ck=default_ck,
-                alt_ck=last_ck[0], certified=bool(_certified))
-            obs.counter(
-                "bench.checksum_gate."
-                + ("deviation" if checksum_deviation else "match")
-            ).inc()
-            if checksum_deviation:
-                # certified-defaults branch: see _checksum_gate —
-                # publish the baseline's timing instead of silently
-                # keeping the suspect certified result, and tag the
-                # artifact either way
-                print("bench: checksum deviation under certified "
-                      f"defaults (default {default_ck!r} vs "
-                      f"baseline {last_ck[0]!r}); preferring the "
-                      "XLA baseline timing", file=sys.stderr)
-            with obs.span("bench.alt_measure", config=alt_label):
-                alt_single = float(np.median(
-                    [_timed_once(step, k_max, kernel)
-                     for _ in range(reps)]
-                ))
-                alt_burst_reps = reps if alt_single < 1000.0 else 1
-                alt_amortized = float(np.median(
-                    [burst(k_max, kernel)
-                     for _ in range(alt_burst_reps)]
-                ))
-            # swap only now: every alt measurement succeeded. A
-            # checksum deviation in the certified branch forces the
-            # swap — the suspect certified timing must not headline
-            if alt_amortized < p50_amortized or checksum_deviation:
-                config = alt_label
-                alt = p50_amortized
-                p50_amortized = alt_amortized
-                p50_single = alt_single
-                burst_reps = alt_burst_reps  # the emitted repetition
-                # counts must describe the PUBLISHED headline path
-            else:
-                alt = alt_amortized
-        except Exception as e:  # noqa: BLE001 - keep the default result
-            print(f"bench: alt config ({alt_label}) attempt failed "
-                  f"({type(e).__name__}: {str(e)[:120]}); "
-                  "keeping default", file=sys.stderr)
-        finally:
-            for k in TRACE_SWITCHES:
-                os.environ.pop(k, None)
-            jax.clear_caches()  # stale switch-traced programs
-
     # the 100 ms target is defined at full size on TPU; a smoke-size or
     # CPU run must not claim to beat it
     on_target = not smoke and platform != "cpu"
@@ -1093,21 +925,14 @@ def measure(platform: str) -> dict:
         "reps": reps,
         "burst_reps": burst_reps,
         "kernel": kernel,
-        "config": config,
+        "config": "default",
         "vs_baseline": vs,
         "vs_target": vs,
         "target_ms": NORTH_STAR_MS,
         "platform": platform,
         "schema_version": BENCH_SCHEMA_VERSION,
     }
-    if alt is not None:
-        out["other_config_ms"] = round(alt, 3)
-    if checksum_deviation:
-        # the deviation is evidence against the certified program; the
-        # artifact must carry it even when the baseline timing could
-        # not be published (alt measurement failure kept the default)
-        out["checksum_deviation"] = True
-    obs.flush()  # program-cache + gate counters into the sidecar
+    obs.flush()  # program-cache counters into the sidecar
     return out
 
 

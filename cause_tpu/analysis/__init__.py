@@ -1,14 +1,12 @@
 """causelint — trace-identity and jit-purity static analysis.
 
 The framework's correctness leans on conventions nothing used to
-enforce mechanically: trace-time switches are *imported, never
-restated* and appear in every program-cache key; obs-off code paths
-read zero TRACE_SWITCHES env vars; jit-reachable code is free of host
-effects; lane-cache arena views are never mutated in place outside
-their owner. Each convention is the fossil of a real fixed bug (stale
-sharded programs across switch flips, uncertified static flips,
-backend initialization from cache lookups) — this package turns them
-into CI-gated rules. v2 extends the catalog to the concurrent host
+enforce mechanically: every ``CAUSE_TPU_*`` knob read is a registered
+one, and none reaches a traced program; obs reads only its own knobs;
+jit-reachable code is free of host effects; lane-cache arena views are
+never mutated in place outside their owner. Each convention is the
+fossil of a real fixed bug — this package turns them into CI-gated
+rules. v2 extends the catalog to the concurrent host
 substrate: lock discipline (LCK — guarded-by inference, lock-order
 cycles, blocking under a lock, commit-step reentrancy), durable
 commit protocol (DUR — fsync-before-rename, dir-fsync,

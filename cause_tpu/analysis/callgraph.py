@@ -2,8 +2,8 @@
 
 causelint's rules need one non-local fact: *is this function reachable
 from traced code?* A host helper may read the clock or the environment
-freely; the same read inside anything `jax.jit`/`vmap`/`shard_map`/
-`pallas_call` ultimately traces is a program-identity or purity hazard.
+freely; the same read inside anything `jax.jit`/`vmap`/`shard_map`
+ultimately traces is a program-identity or purity hazard.
 This module computes that fact with stdlib ``ast`` only:
 
 - every scanned file becomes a :class:`ModuleInfo` (dotted name derived
@@ -12,15 +12,15 @@ This module computes that fact with stdlib ``ast`` only:
   each body);
 - **seeding**: any function handed to a tracing wrapper — a
   ``@jax.jit`` / ``@partial(jax.jit, ...)`` / ``@partial(shard_map,
-  ...)`` decorator, a ``jax.jit(f)`` / ``jax.vmap(f)`` /
-  ``pallas_call(kernel, ...)`` call (nested wrappers recurse:
-  ``jax.jit(jax.vmap(f))`` seeds ``f``), or a lambda in any of those
+  ...)`` decorator, a ``jax.jit(f)`` / ``jax.vmap(f)`` call (nested
+  wrappers recurse: ``jax.jit(jax.vmap(f))`` seeds ``f``), or a lambda
+  in any of those
   positions — is a trace root;
 - **reachability**: BFS over name-resolved call edges. Resolution is
   lexical (own nested defs, enclosing functions, module scope) then
   import-based (aliases resolved against the scanned module set, so
-  ``mesh.step -> vmap lambda -> merge_weave_kernel_v3 ->
-  bitonic.sort_pairs -> switches.resolve`` is a real path). Unresolved
+  ``mesh.step -> vmap lambda -> merge_weave_kernel_v5 ->
+  gatherops.take1d`` is a real path). Unresolved
   calls (methods on unknown objects, builtins) drop silently — the
   graph is lint-grade, deliberately best-effort, and biased toward
   under-approximation so rules stay low-noise.
@@ -38,7 +38,7 @@ from typing import Dict, List, Optional, Set, Tuple
 # functions that will be traced: its function-valued arguments (and
 # decorated defs) seed the reachability BFS.
 TRACE_WRAPPERS = frozenset(
-    {"jit", "vmap", "pmap", "shard_map", "pallas_call", "grad",
+    {"jit", "vmap", "pmap", "shard_map", "grad",
      "value_and_grad", "checkpoint", "remat"}
 )
 # partial(...) forwards its function arguments; recurse through it when

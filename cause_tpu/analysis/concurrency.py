@@ -433,8 +433,8 @@ class ConcurrencyModel:
             self.crash_sites[m.name] = crash
 
     # -------------------------------------------------------- threads
-    def resolve(self, info: FuncInfo,
-                parts: List[str]) -> Optional[str]:
+    def resolve_call(self, info: FuncInfo,
+                     parts: List[str]) -> Optional[str]:
         """callgraph resolution plus typed-attribute dispatch:
         ``self.queue.offer`` resolves through the attr-type map.
 
@@ -465,9 +465,9 @@ class ConcurrencyModel:
             parts = dotted_parts(v)
             if parts is None:
                 continue
-            fid = self.resolve(info, parts)
+            fid = self.resolve_call(info, parts)
             if fid is None and len(parts) >= 3 and parts[0] == "self":
-                fid = self.resolve(info, parts)
+                fid = self.resolve_call(info, parts)
             if fid is not None:
                 yield fid
 
@@ -499,7 +499,7 @@ class ConcurrencyModel:
             seen.add(fid)
             info = self.program.funcs[fid]
             for parts, _ln in info.calls:
-                t = self.resolve(info, parts)
+                t = self.resolve_call(info, parts)
                 if t is not None and t not in seen:
                     queue.append(t)
         return seen
@@ -524,7 +524,7 @@ class ConcurrencyModel:
             changed = False
             for fid, info in self.program.funcs.items():
                 for parts, _ln in info.calls:
-                    t = self.resolve(info, parts)
+                    t = self.resolve_call(info, parts)
                     if t is None or t == fid or t not in blocks:
                         continue
                     if _is_locked_name(
@@ -548,7 +548,7 @@ class ConcurrencyModel:
             changed = False
             for fid, info in self.program.funcs.items():
                 for parts, _ln in info.calls:
-                    t = self.resolve(info, parts)
+                    t = self.resolve_call(info, parts)
                     if t is None or t == fid or t not in acq:
                         continue
                     cur = acq.setdefault(fid, set())
@@ -645,7 +645,7 @@ def _lock_edges(model: ConcurrencyModel):
                     if not held:
                         continue
                     parts = dotted_parts(node.func)
-                    t = model.resolve(info, parts) if parts else None
+                    t = model.resolve_call(info, parts) if parts else None
                     if t is None:
                         continue
                     via = model.program.funcs[t].qualname
@@ -741,7 +741,7 @@ def check_lck003(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
                     "move the blocking call outside the region (or "
                     "suppress with the design reason)")
                 continue
-            t = model.resolve(info, parts) if parts else None
+            t = model.resolve_call(info, parts) if parts else None
             if t is None or t not in blocks:
                 continue
             callee = model.program.funcs[t]
@@ -769,7 +769,7 @@ def _error_edges(model: ConcurrencyModel):
                 continue
             node, _held, in_err = rest
             parts = dotted_parts(node.func)
-            t = model.resolve(info, parts) if parts else None
+            t = model.resolve_call(info, parts) if parts else None
             if t is None:
                 continue
             key = (fid, t)

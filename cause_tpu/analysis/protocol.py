@@ -260,7 +260,7 @@ def _emits_evidence(ctx: Context) -> Set[str]:
             if fid in emits:
                 continue
             for parts, _ln in info.calls:
-                t = model.resolve(info, parts)
+                t = model.resolve_call(info, parts)
                 if t is not None and t in emits:
                     emits.add(fid)
                     changed = True
@@ -324,7 +324,7 @@ class _RefusalWalker:
                     continue
                 if _is_evidence_call(parts):
                     return True
-                t = self.model.resolve(self.info, parts)
+                t = self.model.resolve_call(self.info, parts)
                 if t is not None and t in self.emits:
                     return True
         return False

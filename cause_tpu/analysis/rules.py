@@ -1,17 +1,15 @@
 """The causelint rule families, each grounded in a shipped incident.
 
-- **TID** — trace-identity soundness. The CAUSE_TPU_* strategy
-  switches are read at trace time, so they are program identity:
-  every name must be registered (TRACE_SWITCHES or KNOWN_ENV_KNOBS),
-  never restated as a literal outside switches.py, and every host-side
-  cache of a traced program must fold the switch snapshot into its key
-  (the round-4/5 stale-program incidents).
+- **TID** — trace-identity soundness. No environment variable may
+  become a config axis of the shipped programs: every CAUSE_TPU_* name
+  read must be a registered knob (``switches.KNOWN_ENV_KNOBS``), none
+  of which reaches a traced program.
 - **JPH** — jit-purity hazards. Host effects inside jit-reachable
   code run at trace time only (or break retracing): env reads, clock
   reads, print, open, ``.item()``, mutation of module-level state.
-- **OBS** — obs-off invariance. ``cause_tpu/obs`` must read zero
-  TRACE_SWITCHES env vars on any path the disabled mode reaches, and
-  traced code may only touch the guarded no-op instrument factories.
+- **OBS** — obs-off invariance. ``cause_tpu/obs`` reads only its
+  registered knobs, by literal name, and traced code may only touch
+  the guarded no-op instrument factories.
 - **LCA** — lane-cache aliasing. LaneArena columns are shared by
   every view of a tree; in-place stores outside the arena-owning
   ``lanecache`` module corrupt sibling views silently.
@@ -32,11 +30,9 @@ from .callgraph import FuncInfo, ModuleInfo, Program, dotted_parts
 
 # imported from the one authority — the module rule this linter
 # enforces applies to the linter too
-from ..switches import KNOWN_ENV_KNOBS, TRACE_SWITCHES
+from ..switches import KNOWN_ENV_KNOBS
 
-SWITCH_HELPERS = frozenset({"resolve", "raw_key", "raw_switch_key"})
 _ENV_READ_ATTRS = frozenset({"get", "pop", "setdefault", "__getitem__"})
-_CACHE_DECOS = frozenset({"lru_cache", "cache"})
 _OBS_GUARDED = frozenset({"span", "counter", "gauge", "event"})
 _OBS_UNGUARDED = frozenset(
     {"flush", "configure", "reset", "counters_snapshot", "events",
@@ -77,7 +73,7 @@ REGISTRY: dict = {}
 # Bumped whenever rule logic or the rule set changes; the incremental
 # cache (core.cached_run) keys on it so a rule-set change invalidates
 # every cached verdict even when no analyzed file changed.
-RULESET_VERSION = 5  # PR 20: SHP001 gates the telemetry-ship layer
+RULESET_VERSION = 6  # TID002/TID003 retired with the strategy switches
 
 
 def rule(rule_id: str, help_text: str):
@@ -159,158 +155,37 @@ def _in_obs_package(module: ModuleInfo) -> bool:
     return "obs" in module.segments[:-1] or module.segments[-1] == "obs"
 
 
-def _docstring_lines(module: ModuleInfo) -> set:
-    """Line spans of docstring constants (skipped by literal rules)."""
-    out = set()
-    if module.tree is None:
-        return out
-    for n in ast.walk(module.tree):
-        if isinstance(n, (ast.Module, ast.FunctionDef,
-                          ast.AsyncFunctionDef, ast.ClassDef)):
-            body = getattr(n, "body", [])
-            if body and isinstance(body[0], ast.Expr) and isinstance(
-                    body[0].value, ast.Constant) and isinstance(
-                    body[0].value.value, str):
-                c = body[0].value
-                out.update(range(c.lineno, (c.end_lineno or c.lineno) + 1))
-    return out
-
-
 # ----------------------------------------------------------------- TID
 
 @rule("TID001",
-      "trace-reachable read of a CAUSE_TPU_* env var that is not a "
-      "registered TRACE_SWITCHES member")
+      "read of a CAUSE_TPU_* env var that is not a registered "
+      "KNOWN_ENV_KNOBS member")
 def check_tid001(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
-    if _is_switches_module(module):
-        return
-    registered = set(TRACE_SWITCHES) | set(KNOWN_ENV_KNOBS)
-    for info in ctx.reachable_funcs(module):
-        for key in _iter_env_keys(info.body_nodes()):
-            name = _literal(key)
-            if (name and name.startswith("CAUSE_TPU_")
-                    and name not in registered):
-                yield _finding(
-                    "TID001", module, key,
-                    f"jit-reachable code reads {name!r}, which is in "
-                    "neither TRACE_SWITCHES nor KNOWN_ENV_KNOBS — an "
-                    "unregistered trace-time config axis never reaches "
-                    "program-cache keys (import the registry in "
-                    "cause_tpu/switches.py, never invent names)")
-    # helper misuse is a hazard anywhere: resolve()/raw_key() on an
-    # unknown name silently returns "" forever
-    if module.tree is None:
-        return
-    for n in ast.walk(module.tree):
-        if isinstance(n, ast.Call):
-            parts = dotted_parts(n.func)
-            if (parts is not None and parts[-1] in ("resolve", "raw_key")
-                    and n.args):
-                name = _literal(n.args[0])
-                if (name and name.startswith("CAUSE_TPU_")
-                        and name not in TRACE_SWITCHES):
-                    yield _finding(
-                        "TID001", module, n,
-                        f"switch helper called with {name!r}, which is "
-                        "not a TRACE_SWITCHES member — the read can "
-                        "never be part of program identity")
-
-
-@rule("TID002",
-      "TRACE_SWITCHES name restated as a string literal outside "
-      "switches.py (module rule: import, never restate)")
-def check_tid002(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
     if _is_switches_module(module) or module.tree is None:
         return
-    doc_lines = _docstring_lines(module)
-    # literals passed straight to the switch helpers are the sanctioned
-    # read pattern, not a restatement
-    helper_args = set()
-    for n in ast.walk(module.tree):
-        if isinstance(n, ast.Call):
-            parts = dotted_parts(n.func)
-            if parts is not None and parts[-1] in SWITCH_HELPERS:
-                for a in n.args:
-                    helper_args.add(id(a))
-    for n in ast.walk(module.tree):
-        if not isinstance(n, ast.Constant) or not isinstance(n.value, str):
-            continue
-        if n.lineno in doc_lines or id(n) in helper_args:
-            continue
-        head = n.value.split("=", 1)[0]
-        if head in TRACE_SWITCHES:
+    for key in _iter_env_keys(ast.walk(module.tree)):
+        name = _literal(key)
+        if (name and name.startswith("CAUSE_TPU_")
+                and name not in KNOWN_ENV_KNOBS):
             yield _finding(
-                "TID002", module, n,
-                f"switch name {head!r} restated as a literal — a copy "
-                "that drifts from switches.py silently serves/keys a "
-                "different program config; import TRACE_SWITCHES / "
-                "BESTSTREAM_FLIPS instead (or suppress with a reason "
-                "for deliberate A/B flips)")
-
-
-@rule("TID003",
-      "host-side cache of a traced program whose key omits the switch "
-      "snapshot (stale-program hazard)")
-def check_tid003(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
-    for fid, info in module.funcs.items():
-        node = info.node
-        if isinstance(node, ast.Lambda):
-            continue
-        if not any(
-            (dotted_parts(d) or ["?"])[-1] in _CACHE_DECOS
-            or (isinstance(d, ast.Call)
-                and (dotted_parts(d.func) or ["?"])[-1] in _CACHE_DECOS)
-            for d in node.decorator_list
-        ):
-            continue
-        # trace roots lexically inside this cached factory
-        inner = [f for f in ctx.program.roots
-                 if f.startswith(fid + ".")] + (
-            [fid] if fid in ctx.program.roots else [])
-        if not inner:
-            continue
-        traced = ctx.program.reachable_from(inner)
-        reads_switches = False
-        for tfid in traced:
-            tinfo = ctx.program.funcs[tfid]
-            for parts, _ln in tinfo.calls:
-                if parts[-1] in SWITCH_HELPERS:
-                    reads_switches = True
-            for key in _iter_env_keys(tinfo.body_nodes()):
-                name = _literal(key)
-                if name in TRACE_SWITCHES:
-                    reads_switches = True
-        if not reads_switches:
-            continue
-        params = {a.arg for a in (
-            list(node.args.posonlyargs) + list(node.args.args)
-            + list(node.args.kwonlyargs))}
-        if "switches" not in params:
-            yield _finding(
-                "TID003", module, node,
-                f"{info.qualname} caches a traced program that reads "
-                "TRACE_SWITCHES at trace time, but its cache key has "
-                "no `switches` parameter — after a switch flip the "
-                "cache serves the program traced under the OLD config "
-                "(fold switches.raw_switch_key() into the key)")
+                "TID001", module, key,
+                f"reads {name!r}, which is not in KNOWN_ENV_KNOBS — an "
+                "unregistered knob is a config axis nothing documents "
+                "or tests (register it in cause_tpu/switches.py, never "
+                "invent names)")
 
 
 # ----------------------------------------------------------------- JPH
 
-_JPH_EXEMPT_LAST_SEG = frozenset({"switches"})
-
-
 def _jph_applies(module: ModuleInfo) -> bool:
-    # switches.py's resolve/raw_key ARE the sanctioned trace-time env
-    # readers; the obs package's guard discipline is the OBS family's
-    # job (its factories run host-side at trace time by design)
-    return (module.segments[-1] not in _JPH_EXEMPT_LAST_SEG
-            and not _in_obs_package(module))
+    # the obs package's guard discipline is the OBS family's job (its
+    # factories run host-side at trace time by design)
+    return not _in_obs_package(module)
 
 
 @rule("JPH001",
-      "direct os.environ access inside jit-reachable code (route "
-      "trace-time config through switches.resolve/raw_key)")
+      "direct os.environ access inside jit-reachable code (hoist the "
+      "read to host code)")
 def check_jph001(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
     if not _jph_applies(module):
         return
@@ -322,8 +197,7 @@ def check_jph001(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
                 "jit-reachable code reads the environment directly"
                 + (f" ({name!r})" if name else "")
                 + " — the value binds at trace time and never joins "
-                "program identity; use switches.resolve()/raw_key() "
-                "(registered switches) or hoist the read to host code")
+                "program identity; hoist the read to host code")
 
 
 @rule("JPH002",
@@ -465,9 +339,8 @@ def check_jph006(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
 # ----------------------------------------------------------------- OBS
 
 @rule("OBS001",
-      "cause_tpu/obs reads a TRACE_SWITCHES env var (obs-off "
-      "invariance: disabled mode must add zero identity-adjacent "
-      "env reads)")
+      "cause_tpu/obs reads an env var it cannot name by a registered "
+      "literal (obs-off invariance: obs reads its own knobs only)")
 def check_obs001(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
     if not _in_obs_package(module) or module.tree is None:
         return
@@ -477,15 +350,14 @@ def check_obs001(ctx: Context, module: ModuleInfo) -> Iterator[Finding]:
             yield _finding(
                 "OBS001", module, key,
                 "obs reads an env var through a non-literal key — "
-                "causelint cannot prove it is not a TRACE_SWITCHES "
-                "member; read via a literal, or suppress with a "
-                "reason at the one sanctioned enabled-span snapshot")
-        elif name in TRACE_SWITCHES:
+                "causelint cannot prove it is one of obs's own knobs; "
+                "read via a literal")
+        elif name.startswith("CAUSE_TPU_") and name not in KNOWN_ENV_KNOBS:
             yield _finding(
                 "OBS001", module, key,
-                f"obs reads trace switch {name!r} — the obs-off "
-                "contract is ZERO TRACE_SWITCHES reads (program "
-                "identity must not depend on whether obs is on)")
+                f"obs reads {name!r}, which is not a registered knob — "
+                "what obs records must not depend on config it does "
+                "not own")
 
 
 @rule("OBS002",

@@ -204,51 +204,32 @@ def merge_wave_scalar(*args, k_max: int = 0, kernel: str = "v2",
 
     ``k_max`` > 0 selects a compressed kernel — ``kernel`` picks which
     ("v2" chain-compressed, "v3" sparse-irregular, "v4"
-    marshal-resolved causes, "v4w" = v4 with the sequential Pallas
-    euler walk, "v5" segment-union with token budget ``u_max``,
-    "v5w" = v5 with the Pallas euler walk, "v5f" = v5 with the whole
-    token pipeline fused into Pallas kernels — jaxw5f) — with
-    that run budget, returning a length-2 device array ``[checksum,
-    n_overflowed_rows]`` (one transfer fetches both); ``k_max=0`` runs
-    the uncompressed v1 kernel and returns just the checksum. For the
-    v5 family the checksum is an EXACT order-independent avalanche
-    digest of (rank, visibility, lane, conflict): equal integers
-    across strategy configs iff the weaves are bit-identical, so the
-    same scalar program doubles as the on-chip correctness gate
-    (v1-v4 keep the float sum). v1-v3
-    take the ``LANE_KEYS`` lanes, v4/v4w the ``LANE_KEYS4`` lanes, v5
-    the ``LANE_KEYS5`` lanes.
+    marshal-resolved causes, "v5" segment-union with token budget
+    ``u_max``) — with that run budget, returning a length-2 device
+    array ``[checksum, n_overflowed_rows]`` (one transfer fetches
+    both); ``k_max=0`` runs the uncompressed v1 kernel and returns just
+    the checksum. For v5 the checksum is an EXACT order-independent
+    avalanche digest of (rank, visibility, lane, conflict): equal
+    integers iff the weaves are bit-identical, so the same scalar
+    program doubles as the on-chip correctness gate (v1-v4 keep the
+    float sum). v1-v3 take the ``LANE_KEYS`` lanes, v4 the
+    ``LANE_KEYS4`` lanes, v5 the ``LANE_KEYS5`` lanes.
     """
-    # the CAUSE_TPU_* streaming switches are read at TRACE TIME inside
-    # the kernels (via switches.resolve), so they are part of program
-    # identity. The cache key uses the RAW env values, not resolve():
-    # resolve() consults jax.default_backend() once TPU_DEFAULTS is
-    # populated, and this lookup runs on host paths (bench.py's parent,
-    # the wave assembly) that must stay backend-init-free — triggering
-    # backend initialization from a cache lookup was ADVICE r4 #2.
-    # switches.raw_key: raw env values (never resolve() — that would
-    # trigger backend init from this host path), with the safe
-    # "xla"-onto-unset collapse for non-defaulted switches; the
-    # mapping lives in switches.py next to resolve() so key and
-    # trace-time resolution cannot drift.
     from .obs import costmodel as _costmodel
     from .obs import counter as _obs_counter, span as _obs_span
-    from .switches import raw_switch_key
 
-    key = (k_max, kernel if k_max > 0 else "v1", u_max,
-           raw_switch_key())
+    key = (k_max, kernel if k_max > 0 else "v1", u_max)
 
     def _prog_id():
         # the ONE spelling of this program's costmodel identity: the
         # dispatch record (below) and the devprof cost registration
         # (miss branch) must agree byte-for-byte or the wave.cost
         # devprof join silently misses
-        return (f"scalar:{key[1]}:k{int(k_max)}:u{int(u_max)}"
-                f":s{hash(key[3]) & 0xFFFFFFFF:08x}")
+        return f"scalar:{key[1]}:k{int(k_max)}:u{int(u_max)}"
 
     if _costmodel.enabled():
         # dispatch accounting (obs.costmodel): every call here is ONE
-        # device program invocation under this switch-aware identity,
+        # device program invocation under this identity,
         # hit or miss — the wave cost model counts invocations and
         # distinct identities per wave window. Never feeds back into
         # ``key``: the identity contract stays one-way, like the
@@ -257,13 +238,10 @@ def merge_wave_scalar(*args, k_max: int = 0, kernel: str = "v2",
     program = _scalar_programs.get(key)
     if program is None:
         # program-cache provenance: every miss is a fresh trace (and on
-        # TPU a fresh XLA compile) keyed by the raw switch snapshot —
-        # the counters make silent re-trace storms visible in any obs
-        # trace (obs never feeds back into ``key``: the identity
-        # contract is one-way)
+        # TPU a fresh XLA compile) — the counters make silent re-trace
+        # storms visible in any obs trace (obs never feeds back into
+        # ``key``: the identity contract is one-way)
         _obs_counter("program_cache.miss").inc()
-        import functools
-
         import jax
         import jax.numpy as jnp
 
@@ -277,36 +255,21 @@ def merge_wave_scalar(*args, k_max: int = 0, kernel: str = "v2",
                 + jnp.sum(conflict.astype(jnp.float32))
             )
 
-        if k_max > 0 and kernel in ("v5", "v5w", "v5f"):
-            if kernel == "v5f":
-                from .weaver.jaxw5f import batched_merge_weave_v5f
-
-                def batched(*a):
-                    return batched_merge_weave_v5f(
-                        *a, u_max=u_max, k_max=k_max)
-            else:
-                from .weaver.jaxw5 import batched_merge_weave_v5
-
-                _euler = "walk" if kernel == "v5w" else "doubling"
-
-                def batched(*a):
-                    return batched_merge_weave_v5(
-                        *a, u_max=u_max, k_max=k_max, euler=_euler)
+        if k_max > 0 and kernel == "v5":
+            from .weaver.jaxw5 import batched_merge_weave_v5
 
             @jax.jit
             def program(*a):
-                # The v5-family scalar is an EXACT avalanche digest
+                # The v5 scalar is an EXACT avalanche digest
                 # (mesh.replica_digest-style mixing), not a float sum:
                 # uint32 wraparound arithmetic is order-independent, so
-                # the same weave under ANY strategy config yields the
-                # SAME integer — one compiled program per config serves
-                # both timing and the on-chip correctness gate
-                # (harvest's verify items and bench.py's alt-config
-                # gate compare these scalars; two windows were lost to
-                # the separate digest program's fresh compile). A
-                # linear float sum was observed cancelling
-                # compensating errors — the mixing prevents that.
-                rank, visible, conflict, overflow = batched(*a)
+                # the same weave yields the SAME integer — one compiled
+                # program serves both timing and the on-chip
+                # correctness gate. A linear float sum was observed
+                # cancelling compensating errors — the mixing prevents
+                # that.
+                rank, visible, conflict, overflow = batched_merge_weave_v5(
+                    *a, u_max=u_max, k_max=k_max)
                 lane = jax.lax.broadcasted_iota(
                     jnp.uint32, rank.shape, 1)
                 x = (rank.astype(jnp.uint32) * jnp.uint32(0x9E3779B1)
@@ -327,7 +290,7 @@ def merge_wave_scalar(*args, k_max: int = 0, kernel: str = "v2",
                 # per-row errors (row i off by +d, row j by -d, or two
                 # rows swapped) cancelled. Rotating each row digest by
                 # row & 31 breaks that symmetry while keeping the
-                # checksum exact and config-independent; (32-r)&31
+                # checksum exact; (32-r)&31
                 # keeps the r==0 shift in-range.
                 rix = jax.lax.broadcasted_iota(
                     jnp.uint32, row.shape, 0) & jnp.uint32(31)
@@ -340,13 +303,10 @@ def merge_wave_scalar(*args, k_max: int = 0, kernel: str = "v2",
                     jnp.sum(overflow.astype(jnp.int32)),
                 ])
         elif k_max > 0:
-            if kernel in ("v4", "v4w"):
+            if kernel == "v4":
                 from .weaver.jaxw4 import batched_merge_weave_v4
 
-                batched = functools.partial(
-                    batched_merge_weave_v4,
-                    euler="walk" if kernel == "v4w" else "doubling",
-                )
+                batched = batched_merge_weave_v4
             elif kernel == "v3":
                 from .weaver.jaxw3 import batched_merge_weave_v3
 
@@ -655,7 +615,7 @@ def delta_sweep_inputs(
     include_full: bool = True,
 ) -> dict:
     """Paired full-weave / delta-weave inputs for the divergence sweep
-    (BENCH_DIV_SWEEP) and the harvest delta items: the same synthetic
+    (BENCH_DIV_SWEEP): the same synthetic
     workload expressed both as the document-width batch the full v5
     kernel dispatches and as the delta-native WINDOW batch
     (``weaver.jaxwd.batched_delta_weave``'s inputs) plus the frozen
@@ -683,8 +643,7 @@ def delta_sweep_inputs(
 
     ``include_full=False`` skips the full-width v5 marshal (the
     per-row segment extraction is the expensive half at 1024x10k):
-    timing-only delta consumers (harvest's bench_delta items) need
-    just the window arm.
+    timing-only delta consumers need just the window arm.
     """
     batch = batched_pair_lanes(
         n_replicas=n_replicas, n_base=n_base, n_div=n_div,
@@ -770,7 +729,7 @@ def tree_fleet_handles(n_replicas: int, n_base: int, n_div: int,
     Deliberately jax-free: the base weave is computed by the PURE host
     weaver and the trees then evolve to ``weaver="jax"`` (the two
     weavers are semantics-identical — the pure weaver is the oracle),
-    so harvest/bench marshal this fleet without any device work. The
+    so bench marshals this fleet without any device work. The
     first suffix op of every replica is a plain
     value (a tombstone there would target the shared base tail — the
     anchor — which is exactly the delta-domain violation the tree

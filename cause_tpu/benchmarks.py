@@ -240,9 +240,9 @@ def config5_batched_merge(weaver: str = "jax", n_replicas: int = 64,
     sizes here are CLI defaults — bench.py runs the full 1024x10k).
     ``k_max``: None = workload-derived run budget, 0 = the uncompressed
     v1 kernel. ``kernel`` picks the compressed kernel ("v5"
-    segment-union, "v4" marshal-resolved causes, "v4w" v4 + Pallas
-    euler walk, "v3" sparse-irregular, or "v2" chain-compressed); v5
-    consumes the LANE_KEYS5 lanes, v4/v4w LANE_KEYS4, the others
+    segment-union, "v4" marshal-resolved causes, "v3"
+    sparse-irregular, or "v2" chain-compressed); v5 consumes the
+    LANE_KEYS5 lanes, v4 LANE_KEYS4, the others
     LANE_KEYS. bench.py's ladder tries v5 then v4."""
     import numpy as _np
 
@@ -268,7 +268,7 @@ def config5_batched_merge(weaver: str = "jax", n_replicas: int = 64,
             k_max = u_max
     else:
         lane_names = (
-            LANE_KEYS4 if (kernel in ("v4", "v4w") and k_max != 0)
+            LANE_KEYS4 if (kernel == "v4" and k_max != 0)
             else LANE_KEYS
         )
         if k_max is None:

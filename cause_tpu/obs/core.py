@@ -1,16 +1,12 @@
 """The unified trace/metrics substrate (spans, counters, events).
 
 Every timing and diagnostic signal in this repo used to be ad-hoc:
-bench.py, scripts/harvest.py and a dozen probe/profile scripts each
-reinvented timers, log formats and checksum provenance. This module is
+bench.py and a dozen probe/profile scripts each reinvented timers, log formats and checksum provenance. This module is
 the ONE substrate they all report through:
 
-- **spans** — ``with obs.span("weave.sort", strategy="matrix"):`` —
-  record wall time (epoch-anchored, perf_counter-measured), pid/tid,
-  nesting (parent id + depth), the reporting process's platform tag,
-  and the full ``TRACE_SWITCHES`` snapshot as program identity, so a
-  number in a trace can always be tied back to the exact strategy
-  config that produced it;
+- **spans** — ``with obs.span("weave.sort", width=n):`` — record wall
+  time (epoch-anchored, perf_counter-measured), pid/tid, nesting
+  (parent id + depth) and the reporting process's platform tag;
 - a **counter/gauge registry** — program-cache hits/misses, lane-cache
   hits, wave fallbacks, checksum-gate outcomes, certification
   revocations — aggregated in-process and snapshotted into the event
@@ -25,16 +21,13 @@ the ONE substrate they all report through:
   ``python -m cause_tpu.obs``) so any bench or soak run opens in a
   trace viewer.
 
-Dependency-light on purpose (stdlib + ``cause_tpu.switches`` only,
-like switches.py itself): bench.py's parent process and the watcher's
-``certified_env`` path must be able to import it without jax.
+Dependency-light on purpose (stdlib only): bench.py's parent process
+and the watcher must be able to import it without jax.
 
 Off by default: with ``CAUSE_TPU_OBS`` unset (or ``0``), ``span()``
 returns a shared no-op context manager, ``counter()``/``gauge()``
-return a shared no-op instrument, nothing is recorded, no file is
-opened, and — load-bearing for program identity — NO ``TRACE_SWITCHES``
-environment variable is ever read (the snapshot happens only on
-enabled-span close). Enable with ``CAUSE_TPU_OBS=1``; stream with
+return a shared no-op instrument, nothing is recorded and no file is
+opened. Enable with ``CAUSE_TPU_OBS=1``; stream with
 ``CAUSE_TPU_OBS_OUT=<path>``; bound the ring with
 ``CAUSE_TPU_OBS_RING`` (default 65536 events).
 
@@ -59,8 +52,6 @@ import threading
 import time
 from collections import deque
 from typing import Dict, Optional
-
-from ..switches import TRACE_SWITCHES
 
 __all__ = [
     "configure",
@@ -414,23 +405,6 @@ def unsubscribe(sub: Optional[Subscription]) -> None:
         st.subscribers = tuple(s for s in st.subscribers if s is not sub)
 
 
-def _switches_snapshot() -> Dict[str, str]:
-    """The program-identity snapshot stamped on spans: the raw values
-    of every TRACE_SWITCHES env var that is set. Read ONLY on enabled
-    -span close — disabled mode must not add env reads anywhere near
-    trace-time identity."""
-    out = {}
-    for k in TRACE_SWITCHES:
-        # the ONE sanctioned TRACE_SWITCHES read in obs: it runs only
-        # on enabled-span close (disabled mode returns _NULL_SPAN and
-        # never reaches this function), so the obs-off zero-reads
-        # contract holds
-        v = os.environ.get(k, "")  # causelint: disable=OBS001 -- enabled-span close only; obs-off never reaches here
-        if v:
-            out[k] = v
-    return out
-
-
 class _Span:
     """An enabled span: context manager recording one "span" event on
     close. ``set(**attrs)`` adds attributes mid-flight."""
@@ -490,7 +464,6 @@ class _Span:
             "parent": self.parent,
             "depth": self.depth,
             "platform": st.platform,
-            "switches": _switches_snapshot(),
         }
         if self.attrs:
             rec["attrs"] = dict(self.attrs)

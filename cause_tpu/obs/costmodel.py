@@ -33,8 +33,8 @@ that join, in three layers:
 
 Contract (same as the rest of ``cause_tpu.obs``): stdlib + core only,
 importable without jax/numpy; with ``CAUSE_TPU_OBS`` unset every entry
-point returns immediately — no records, no registry state, no
-``TRACE_SWITCHES`` reads, byte-identical program-cache keys (pinned by
+point returns immediately — no records, no registry state,
+byte-identical program-cache keys (pinned by
 tests/test_costmodel.py). On jit-reachable paths, call sites must sit
 behind ``obs.enabled()`` guards — causelint rule OBS005 gates that.
 """

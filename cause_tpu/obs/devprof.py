@@ -12,8 +12,8 @@ at the program caches themselves:
   through jax's AOT path (``lower().compile()``) under a
   ``devprof.compile`` span, and the executable's ``cost_analysis()``
   / ``memory_analysis()`` land ONCE per compiled program as a
-  ``devprof.program`` obs event carrying the same switch-aware
-  program identity the cache key uses. The returned wrapper serves
+  ``devprof.program`` obs event carrying the program identity the
+  cache key uses. The returned wrapper serves
   the AOT executable for matching input shapes and falls back to the
   ordinary jit path otherwise — one compile on the served path (an
   AOT executable that *errors* at call time falls back too, which
@@ -29,8 +29,8 @@ at the program caches themselves:
 Contract (same as the rest of ``cause_tpu.obs``): importable without
 jax — jax is imported lazily inside the enabled paths only. With
 ``CAUSE_TPU_OBS`` unset every entry point returns immediately:
-nothing is recorded, no jax attribute is touched, no ``TRACE_SWITCHES``
-env var is read, and program caches store exactly what they stored
+nothing is recorded, no jax attribute is touched, and program caches
+store exactly what they stored
 before this module existed (pinned by tests/test_devprof.py). On
 traced paths, call sites must sit behind ``obs.enabled()`` guards —
 causelint rule OBS003 gates that.
@@ -151,9 +151,7 @@ def profile_program(jitfn, args, **meta) -> Optional[_ProfiledProgram]:
     span, record its cost analysis once, and return the dispatch
     wrapper — or None (caller keeps the plain jit path) when obs is
     off or anything about the capture fails. ``meta`` is the program
-    identity the cache key carries (kernel, budgets); the emitted
-    event adds the ``TRACE_SWITCHES`` snapshot so a cost row can be
-    tied to the exact strategy config, like any span."""
+    identity the cache key carries (kernel, budgets)."""
     if not core.enabled():
         return None
     try:
@@ -165,7 +163,6 @@ def profile_program(jitfn, args, **meta) -> Optional[_ProfiledProgram]:
             "devprof.program",
             compile_ms=round((time.perf_counter() - t0) * 1000.0, 3),
             cost=cost,
-            switches=core._switches_snapshot(),
             **meta,
         )
         for k, v in cost.items():

@@ -56,8 +56,8 @@ controller can act on.
 
 Contract (same as the rest of ``cause_tpu.obs``): stdlib + core only,
 importable without jax/numpy; with ``CAUSE_TPU_OBS`` unset every entry
-point returns immediately — no records, no registry state, no env or
-``TRACE_SWITCHES`` reads, byte-identical program-cache keys (pinned by
+point returns immediately — no records, no registry state, no env
+reads, byte-identical program-cache keys (pinned by
 tests/test_lag.py). On jit-reachable paths, call sites must sit behind
 ``obs.enabled()`` guards — causelint rule OBS006 gates that. State is
 bounded everywhere: documents LRU-evict past ``_DOC_MAX`` (the

@@ -340,10 +340,8 @@ def backfill(root: Optional[str] = None,
 
 def _partition_key(row: dict) -> Tuple:
     """The ONLY grouping rows are ever compared within: same kind
-    (bench rows never gate against harvest/soak rows), identical
-    platform string, same size class, same kernel, same config (the
-    allstream/beststream A/B flips select different sort/gather
-    algorithms — different flops, different wall time). Anything else
+    (bench rows never gate against soak rows), identical platform
+    string, same size class, same kernel, same config. Anything else
     is a different experiment."""
     return (row.get("kind") or "bench", row.get("platform"),
             bool(row.get("smoke")),

@@ -5,8 +5,7 @@ Chrome Trace Event JSON format that https://ui.perfetto.dev (and
 chrome://tracing) open directly:
 
 - ``span`` events become complete ("ph": "X") slices on a
-  per-process/per-thread track, with the span attributes AND the
-  ``TRACE_SWITCHES`` program-identity snapshot as args;
+  per-process/per-thread track, with the span attributes as args;
 - ``event`` records become instant events ("ph": "i", thread scope);
   events in the CRDT-semantic vocabulary (``semantic.
   SEMANTIC_EVENT_PREFIXES`` — ``sync.*``, ``wave.digest``,
@@ -133,8 +132,6 @@ def merged_final_counters(events: Iterable[dict],
 def _args_of(e: dict) -> dict:
     args = {}
     for k, v in (e.get("attrs") or {}).items():
-        args[k] = v
-    for k, v in (e.get("switches") or {}).items():
         args[k] = v
     if e.get("platform"):
         args["platform"] = e["platform"]

@@ -31,7 +31,7 @@ continuously-checked convergence digests, arXiv:2603.08003):
 Contract (same as the rest of ``cause_tpu.obs``): stdlib-only at
 import time, importable without jax/numpy; with ``CAUSE_TPU_OBS``
 unset every entry point returns immediately — no records, no state,
-no ``TRACE_SWITCHES`` env reads, byte-identical program-cache keys
+byte-identical program-cache keys
 (pinned by tests/test_fleet_obs.py). On jit-reachable paths, call
 sites must sit behind ``obs.enabled()`` guards — causelint rule
 OBS004 gates that (these functions assemble real field dicts, unlike

@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..switches import raw_switch_key
 from ..weaver.jaxw import merge_weave_kernel, merge_weave_kernel_v2
 
 from jax import shard_map as _shard_map
@@ -150,23 +149,13 @@ def _fleet_stats(axis, hi, lo, order, rank, visible, conflict, overflow):
 
 
 @lru_cache(maxsize=8)
-def _sharded_step(mesh: Mesh, k_max: int, kernel: str,
-                  switches: tuple):
+def _sharded_step(mesh: Mesh, k_max: int, kernel: str):
     """The jitted sharded merge step for one mesh (cached so repeat
     merge waves hit the jit cache instead of re-tracing). ``k_max`` > 0
     runs a compressed kernel — ``kernel`` picks the sparse-irregular
     "v3" (default) or chain-compressed "v2" — with that run budget
     (overflowed rows are psum-counted fleet-wide); 0 runs the
-    uncompressed kernel.
-
-    ``switches`` is the ``raw_switch_key()`` snapshot and exists ONLY
-    to key the cache: the kernels read the CAUSE_TPU_* strategy
-    switches via ``resolve()`` at trace time, so a cache keyed on
-    (mesh, k_max, kernel) alone kept serving the step traced under the
-    PREVIOUS switch config after a flip — the same stale-program class
-    benchgen.merge_wave_scalar's key fixed in round 4. A distinct
-    snapshot mints a fresh ``jax.jit`` wrapper, whose own aval cache
-    then re-traces under the new config."""
+    uncompressed kernel."""
     axis = mesh.axis_names[0]
     sharded = P(axis)
     replicated = P()
@@ -213,17 +202,14 @@ def sharded_merge_weave(mesh: Mesh, hi, lo, cause_hi, cause_lo, vclass, valid,
     """
     # normalize the cache key: kernel is only consulted when k_max > 0,
     # so k_max=0 calls must not mint per-kernel duplicate programs
-    step = _sharded_step(mesh, k_max, kernel if k_max > 0 else "v1",
-                         raw_switch_key())
+    step = _sharded_step(mesh, k_max, kernel if k_max > 0 else "v1")
     return step(hi, lo, cause_hi, cause_lo, vclass, valid)
 
 
 @lru_cache(maxsize=8)
-def _sharded_step_v4(mesh: Mesh, k_max: int, switches: tuple):
+def _sharded_step_v4(mesh: Mesh, k_max: int):
     """The v4 twin of ``_sharded_step``: 5 lanes (cause ids replaced by
-    the marshal-time concat cause-index lane), same outputs.
-    ``switches`` keys the cache on the trace-time strategy snapshot
-    (see ``_sharded_step``)."""
+    the marshal-time concat cause-index lane), same outputs."""
     from ..weaver.jaxw4 import merge_weave_kernel_v4
 
     axis = mesh.axis_names[0]
@@ -253,38 +239,22 @@ def sharded_merge_weave_v4(mesh: Mesh, hi, lo, cci, vclass, valid,
     (the cause's index in the concatenated pre-sort array, resolved at
     marshal time) instead of cause id lanes. Same outputs; the batch
     dimension must be divisible by the mesh size."""
-    return _sharded_step_v4(mesh, k_max, raw_switch_key())(
-        hi, lo, cci, vclass, valid)
+    return _sharded_step_v4(mesh, k_max)(hi, lo, cci, vclass, valid)
 
 
 @lru_cache(maxsize=8)
-def _sharded_step_v5(mesh: Mesh, u_max: int, k_max: int,
-                     pipeline: str, switches: tuple):
+def _sharded_step_v5(mesh: Mesh, u_max: int, k_max: int):
     """The v5 (segment-union) sharded step: node lanes + segment
-    tables in, per-replica (rank, visible, digest) + fleet stats out;
-    ``switches`` keys the cache on the trace-time strategy snapshot
-    (see ``_sharded_step``).
+    tables in, per-replica (rank, visible, digest) + fleet stats out.
     v5 reports in concat-lane coordinates and produces no ``order``;
     the digest's mix-sum is permutation-invariant, so feeding the raw
     lanes with concat-coordinate ranks yields the same digest value as
-    the sorted-lane kernels. ``pipeline`` picks the row kernel: "v5"
-    (jaxw5) or "v5f" (the fused token pipeline, jaxw5f)."""
-    if pipeline == "v5f":
-        from ..weaver.jaxw5f import (
-            merge_weave_kernel_v5f as _row_kernel)
-
-        def merge_weave_kernel_v5(*r, u_max, k_max):
-            return _row_kernel(*r, u_max=u_max, k_max=k_max)
-    else:
-        from ..weaver.jaxw5 import merge_weave_kernel_v5
+    the sorted-lane kernels."""
+    from ..weaver.jaxw5 import merge_weave_kernel_v5
 
     axis = mesh.axis_names[0]
     sharded = P(axis)
     replicated = P()
-    # pallas_call inside shard_map cannot express varying-mesh-axes
-    # metadata on its outputs; the fused pipeline disables the vma
-    # check (outputs are per-row, trivially sharded like the inputs)
-    extra = {"check_vma": False} if pipeline == "v5f" else {}
 
     @partial(
         _shard_map,
@@ -292,7 +262,6 @@ def _sharded_step_v5(mesh: Mesh, u_max: int, k_max: int,
         in_specs=(sharded,) * 16,
         out_specs=(sharded, sharded, sharded, sharded, replicated,
                    replicated, replicated),
-        **extra,
     )
     def step(hi, lo, cci, vc, va, seg, *sg):
         rank, visible, conflict, overflow = jax.vmap(
@@ -311,7 +280,7 @@ def _sharded_step_v5(mesh: Mesh, u_max: int, k_max: int,
 
 
 def sharded_merge_weave_v5(mesh: Mesh, lanes: dict, u_max: int,
-                           k_max: int, pipeline: str = "v5"):
+                           k_max: int):
     """Shard the v5 segment-union merge over the mesh. ``lanes`` is the
     ``benchgen.LANE_KEYS5`` dict of [B, ...] arrays. Returns
     ``(rank, visible, overflow, digest, total_visible, n_conflicts,
@@ -328,6 +297,5 @@ def sharded_merge_weave_v5(mesh: Mesh, lanes: dict, u_max: int,
     (shared.union_nodes does)."""
     from ..benchgen import LANE_KEYS5
 
-    step = _sharded_step_v5(mesh, u_max, k_max, pipeline,
-                            raw_switch_key())
+    step = _sharded_step_v5(mesh, u_max, k_max)
     return step(*(lanes[k] for k in LANE_KEYS5))

@@ -750,7 +750,7 @@ def _merge_wave(pairs, mesh, ctx) -> WaveResult:
 
 def _dispatch_wave(lanes, mesh, uuid: str):
     """The device half of a wave over an assembled [B, 2*cap] batch:
-    upload, the v5-family kernel plus digest (sharded over ``mesh``
+    upload, the v5 kernel plus digest (sharded over ``mesh``
     when given), fetch, and one doubled-budget retry of the rows whose
     sampled token budget overflowed. Returns ``(rank, visible, digest,
     overflow, info)`` as host arrays in batch-row order, ``info``
@@ -758,49 +758,26 @@ def _dispatch_wave(lanes, mesh, uuid: str):
     (the run width the kernel ran at, on the ``wave.kernel`` span as
     well)."""
     from ..benchgen import LANE_KEYS5, v5_token_budget
+    from ..weaver.jaxw5 import batched_merge_weave_v5 as fn
 
-    # BENCH_KERNEL routes the wave's kernel variant (the api-level
-    # twin of bench.py's forced-kernel knob), for the v5 family only
-    # — the wave path is segment-union by design. Unknown values fail
-    # loudly (bench.py contract): a typo must not silently time v5.
-    import os as _os
-
-    forced = _os.environ.get("BENCH_KERNEL", "").strip()
-    if forced not in ("", "v5", "v5w", "v5f"):
-        raise ValueError(
-            f"merge_wave supports BENCH_KERNEL of v5/v5w/v5f only "
-            f"(the wave path is segment-union); got {forced!r}")
-    pipeline = forced or "v5"
     n_rows = int(lanes["hi"].shape[0])
 
     def dispatch_v5(sub_lanes, u):
-        """Batched v5-family dispatch + device digest, one scalar-free
-        host fetch: upload, kernel and fetch each under a span that
-        ends when its work on the device does."""
-        if pipeline == "v5f":
-            from ..weaver.jaxw5f import batched_merge_weave_v5f as fn
-
-            kw = {"u_max": u, "k_max": u}
-        else:
-            from ..weaver.jaxw5 import batched_merge_weave_v5 as fn
-
-            kw = {"u_max": u, "k_max": u,
-                  "euler": "walk" if pipeline == "v5w" else "doubling",
-                  "with_k": True}
-
+        """Batched v5 dispatch + device digest, one scalar-free host
+        fetch: upload, kernel and fetch each under a span that ends
+        when its work on the device does."""
+        kw = {"u_max": u, "k_max": u, "with_k": True}
         dev = _upload(sub_lanes, LANE_KEYS5)
         args = [dev[k] for k in LANE_KEYS5]
         with obs.span("wave.kernel", u_max=int(u),
                       rows=int(sub_lanes["hi"].shape[0])) as ksp:
-            r, v, _c, ov, *ran_at = _recovery.run_dispatch(
+            r, v, _c, ov, ran_at = _recovery.run_dispatch(
                 "wave", lambda: fn(*args, **kw))
             d = _digest_fn()(dev["hi"], dev["lo"], r, v)
             jax.block_until_ready((r, v, d, ov))
-            # v5f returns no width: it ranks its runs at the cap. The
-            # width is a scalar of the finished program, read with no
-            # wait
-            width = count_run_width(
-                int(ran_at[0]) if ran_at else int(u), int(u))
+            # the width is a scalar of the finished program, read with
+            # no wait
+            width = count_run_width(int(ran_at), int(u))
             ksp.set(k=width)
         _LAST_KERNEL.update(fn=fn, kw=kw, args=[
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in args])
@@ -810,8 +787,7 @@ def _dispatch_wave(lanes, mesh, uuid: str):
             # the open wave window
             from ..obs import costmodel as _cm
 
-            _cm.record_dispatch(f"wave:{pipeline}:u{int(u)}",
-                                site="wave")
+            _cm.record_dispatch(f"wave:v5:u{int(u)}", site="wave")
             _cm.record_dispatch("wave:digest", site="wave")
         return (*_fetch(r, v, d, ov), width)
 
@@ -827,23 +803,18 @@ def _dispatch_wave(lanes, mesh, uuid: str):
         from ..obs import semantic as _sem
 
         _sem.token_headroom(int(u_max) - u_need, "wave")
-    with obs.span("wave.dispatch", kernel=pipeline,
+    with obs.span("wave.dispatch", kernel="v5",
                   rows=n_rows, u_max=int(u_max),
                   sharded=mesh is not None):
         if mesh is not None:
             from .mesh import sharded_merge_weave_v5
 
-            if pipeline == "v5w":
-                raise ValueError(
-                    "BENCH_KERNEL=v5w has no sharded wave step; use "
-                    "v5 or v5f under a mesh")
             jl = _upload(lanes, tuple(lanes))
             with obs.span("wave.kernel", u_max=int(u_max), rows=n_rows,
                           k=int(u_max)):
                 rank, visible, overflow, digest, _tv, _nc, _n_ov = (
                     sharded_merge_weave_v5(mesh, jl, u_max=u_max,
-                                           k_max=u_max,
-                                           pipeline=pipeline))
+                                           k_max=u_max))
                 jax.block_until_ready((rank, visible, overflow, digest))
             # the sharded step ranks its runs at the cap
             width = count_run_width(int(u_max), int(u_max))
@@ -854,9 +825,8 @@ def _dispatch_wave(lanes, mesh, uuid: str):
                 # compiled program
                 from ..obs import costmodel as _cm
 
-                _cm.record_dispatch(
-                    f"wave:sharded:{pipeline}:u{int(u_max)}",
-                    site="wave")
+                _cm.record_dispatch(f"wave:sharded:v5:u{int(u_max)}",
+                                    site="wave")
         else:
             rank, visible, digest, overflow, width = dispatch_v5(lanes,
                                                                  u_max)
@@ -890,7 +860,7 @@ def _dispatch_wave(lanes, mesh, uuid: str):
         overflow[rows] = ov2
 
     return rank, visible, digest, overflow, {
-        "kernel": pipeline, "u_need": u_need, "u_max": int(u_max),
+        "kernel": "v5", "u_need": u_need, "u_max": int(u_max),
         "retried": n_retried, "k": width,
     }
 

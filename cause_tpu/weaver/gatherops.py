@@ -1,102 +1,33 @@
-"""Switchable 1-D gather strategies for the weave kernels.
+"""The weave kernels' 1-D gather and sorted-search primitives.
 
 TPU has no hardware gather: XLA lowers ``table[idx]`` to per-element
-HBM transactions (~14 ns/element on the round-2 microbenches — the
-single most expensive primitive in the kernel ladder). The
-``rowgather`` strategy instead fetches whole 128-lane rows with
-``take_along_axis`` (a supported fast path) and contracts with a
-one-hot lane mask — 128x data amplification, but every byte streams.
-Which wins depends on the query:table ratio and the backend;
-``CAUSE_TPU_GATHER=rowgather`` flips the kernels at trace time so the
-hardware A/B needs no code change (same discipline as
-``bitonic.sort_pairs``).
+HBM transactions, the most expensive primitive in the kernel ladder.
+``searchsorted_iota_right`` therefore avoids a per-query binary search
+altogether: one scatter-add histogram and a prefix sum.
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
-from jax import lax
 
-__all__ = ["take1d", "rowgather1d", "searchsorted_iota_right",
-           "searchsorted_targets_left"]
-
-_LANE = 128
-_LANE_SHIFT = _LANE.bit_length() - 1
-
-
-def rowgather1d(table, idx):
-    """``table[idx]`` along the last axis via 128-wide row fetch +
-    one-hot contraction. Tables whose last axis is not a multiple of
-    128 (the token/segment tables) are zero-padded up — ``idx`` must be
-    in-range (callers clip, as they already must for XLA gathers), so
-    the padding is never read."""
-    lead = table.shape[:-1]
-    n = table.shape[-1]
-    if n % _LANE:
-        table = jnp.concatenate([
-            table,
-            jnp.zeros(lead + (_LANE - n % _LANE,), table.dtype),
-        ], axis=-1)
-        n = table.shape[-1]
-    q = idx.shape[-1]
-    rows = table.reshape(lead + (n // _LANE, _LANE))
-    fetched = jnp.take_along_axis(
-        rows, (idx >> _LANE_SHIFT)[..., None], axis=-2
-    )  # [..., q, 128]
-    onehot = (
-        lax.broadcasted_iota(jnp.int32, idx.shape + (_LANE,),
-                             len(idx.shape))
-        == (idx & (_LANE - 1))[..., None]
-    )
-    return jnp.sum(
-        jnp.where(onehot, fetched, 0), axis=-1
-    ).astype(table.dtype)
+__all__ = ["take1d", "searchsorted_iota_right", "searchsorted_targets_left"]
 
 
 def take1d(table, idx):
-    """The kernels' gather from a full-width lane table: plain XLA
-    gather by default, ``rowgather1d`` when
-    ``CAUSE_TPU_GATHER=rowgather`` (trace-time switch)."""
+    """The kernels' gather from a full-width lane table."""
     from ..obs import span
-    from ..switches import resolve
 
-    mode = "rowgather" if resolve("CAUSE_TPU_GATHER") == "rowgather" \
-        else "xla"
-    with span("weave.gather", strategy=mode,
-              width=int(table.shape[-1])):
-        if mode == "rowgather":
-            return rowgather1d(table, idx)
+    with span("weave.gather", width=int(table.shape[-1])):
         return table[idx]
 
 
 def searchsorted_iota_right(keys_cum, q: int):
     """``searchsorted(keys_cum, arange(q), side="right")`` for a
-    NON-DECREASING ``keys_cum``.
-
-    Default: histogram the keys (one scatter-add) and prefix-sum — no
-    per-query binary search. But an XLA TPU scatter is random access
-    just like a gather, so ``CAUSE_TPU_SEARCH=matrix`` (trace-time)
-    switches to the O(n*q) comparison-matrix count — side="right"
-    index = #{keys <= target} — which is pure elementwise work the VPU
-    streams with zero random access (same trade as
-    jaxw5._pair_search_le). NOTE: at token width the matrix is
-    [q, n] ~ 5M/row; if XLA materializes it instead of fusing the
-    reduction this form loses badly (47 s/op on CPU!), so the
-    narrower ``matrix-table`` value applies matrix search only to the
-    S-width table search in jaxw5 and leaves this histogram alone —
-    that is what the combined beststream config uses until the
-    microbench decides."""
+    NON-DECREASING ``keys_cum``: histogram the keys (one scatter-add)
+    and prefix-sum."""
     from ..obs import span
-    from ..switches import resolve
 
-    mode = "matrix" if resolve("CAUSE_TPU_SEARCH") == "matrix" \
-        else "histogram"
-    with span("weave.search", strategy=mode, site="iota_right",
-              q=int(q)):
-        if mode == "matrix":
-            tgt = jnp.arange(q, dtype=keys_cum.dtype)
-            le = keys_cum[None, :] <= tgt[:, None]
-            return jnp.sum(le, axis=1).astype(jnp.int32)
+    with span("weave.search", site="iota_right", q=int(q)):
         hist = jnp.zeros(q + 1, jnp.int32).at[
             jnp.clip(keys_cum, 0, q)
         ].add(1, mode="drop")

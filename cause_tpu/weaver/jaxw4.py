@@ -58,8 +58,7 @@ __all__ = [
 ]
 
 
-def merge_weave_kernel_v4(hi, lo, cci, vclass, valid, k_max: int,
-                          euler: str = "doubling"):
+def merge_weave_kernel_v4(hi, lo, cci, vclass, valid, k_max: int):
     """Union + reweave for one replica set, marshal-resolved causes.
 
     Inputs are the concatenated lanes of any number of trees, each
@@ -68,9 +67,7 @@ def merge_weave_kernel_v4(hi, lo, cci, vclass, valid, k_max: int,
     and ``benchgen`` guarantee it), ``cci`` the concat index of each
     lane's cause (-1 for root/none/padding), ``vclass``, ``valid``.
     Returns ``(order, rank, visible, conflict, overflow)`` exactly like
-    ``jaxw3.merge_weave_kernel_v3``. ``euler`` picks the contracted
-    ranking backend: "doubling" (XLA pointer doubling) or "walk" (the
-    sequential Pallas traversal, ``pallas_ops.euler_walk``).
+    ``jaxw3.merge_weave_kernel_v3``.
     """
     N = hi.shape[0]
     idx = jnp.arange(N, dtype=jnp.int32)
@@ -204,12 +201,7 @@ def merge_weave_kernel_v4(hi, lo, cci, vclass, valid, k_max: int,
     sord = jnp.lexsort((-head_c, packed))
     fc, ns = _link_children(sord, parent_sort)
     parent_up = jnp.where(r_valid & (parent_run >= 0), parent_run, -1)
-    if euler == "walk":
-        from .pallas_ops import euler_walk
-
-        base = euler_walk(fc, ns, parent_up, run_len, k_max)
-    else:
-        base, _ = _euler_rank(fc, ns, parent_up, run_len)
+    base, _ = _euler_rank(fc, ns, parent_up, run_len)
 
     # ---- expansion: per-run bases -> deltas -> one cumsum
     delta = jnp.where(
@@ -274,20 +266,17 @@ def merge_weave_kernel_v4(hi, lo, cci, vclass, valid, k_max: int,
 
 
 merge_weave_kernel_v4_jit = jax.jit(
-    merge_weave_kernel_v4, static_argnames=("k_max", "euler")
+    merge_weave_kernel_v4, static_argnames=("k_max",)
 )
 
 
-@partial(jax.jit, static_argnames=("k_max", "euler"))
-def batched_merge_weave_v4(hi, lo, cci, vclass, valid, k_max: int,
-                           euler: str = "doubling"):
+@partial(jax.jit, static_argnames=("k_max",))
+def batched_merge_weave_v4(hi, lo, cci, vclass, valid, k_max: int):
     """Marshal-resolved batch: [B, M] lanes -> per-replica weave ranks.
     Same output contract as ``jaxw3.batched_merge_weave_v3``; inputs
-    swap the cause id lanes (chi, clo) for the single ``cci`` lane.
-    ``euler="walk"`` ranks the contracted trees with the sequential
-    Pallas traversal (its grid absorbs the vmap batch dimension)."""
+    swap the cause id lanes (chi, clo) for the single ``cci`` lane."""
 
     def row(h, l, cc, vc, va):
-        return merge_weave_kernel_v4(h, l, cc, vc, va, k_max, euler=euler)
+        return merge_weave_kernel_v4(h, l, cc, vc, va, k_max)
 
     return jax.vmap(row)(hi, lo, cci, vclass, valid)

@@ -62,11 +62,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..switches import raw_switch_key, resolve
 from .arrays import I32_MAX, VCLASS_H_HIDE, VCLASS_HIDE
 from .jaxw import _euler_rank, _link_children
 from .jaxw3 import _shift1
-from .bitonic import sort_pairs
 from .gatherops import (searchsorted_iota_right,
                         searchsorted_targets_left, take1d)
 
@@ -78,23 +76,15 @@ __all__ = [
 ]
 
 
-def _hint_kw(sorted_: bool = False, unique: bool = False) -> dict:
-    """Scatter-annotation kwargs under ``CAUSE_TPU_SCATTER=hint``
-    (trace-time A/B switch): an XLA TPU scatter serializes to handle
-    potential duplicate indices; the kernel's scatter sites below are
-    rewritten so their index streams are unique (and mostly sorted) BY
-    CONSTRUCTION — invalid entries dump into per-position spread slots
-    past the live range instead of sharing one dump index — so the
-    annotations are provable, not merely test-passing. Off by default
-    so the hardware A/B isolates their effect."""
-    if resolve("CAUSE_TPU_SCATTER") != "hint":
-        return {}
-    kw = {}
-    if sorted_:
-        kw["indices_are_sorted"] = True
-    if unique:
-        kw["unique_indices"] = True
-    return kw
+def _sort(operands, num_keys: int = 1):
+    """The kernels' sort: ``lax.sort`` along the last axis, ascending
+    and lexicographic over the first ``num_keys`` operands (stable;
+    the rest ride as payloads)."""
+    from ..obs import span
+
+    with span("weave.sort", width=int(operands[0].shape[-1]),
+              n_ops=len(operands)):
+        return lax.sort(tuple(operands), num_keys=num_keys)
 
 
 def _lt(a1, a2, b1, b2):
@@ -123,28 +113,11 @@ def _pair_cummax(hi, lo):
 
 def _pair_search_le(kh, kl, qh, ql, size):
     """For each query id, the rightmost index i in the sorted (kh, kl)
-    arrays with key[i] <= query (-1 if none).
-
-    Default: a fori binary search (log2(size) rounds of table
-    gathers). ``CAUSE_TPU_SEARCH=matrix`` or ``matrix-table``
-    (trace-time) counts key<=query over the full [q, size] comparison
-    matrix instead — O(size^2) elementwise work that streams on the
-    VPU with zero random access; at the segment-table widths
-    (size ~512) that is cheaper on TPU than 10 gather rounds.
-    (``matrix-table`` applies matrix search HERE only, leaving the
-    U-width searchsorted histogram in gatherops untouched — see its
-    docstring for why.)"""
+    arrays with key[i] <= query (-1 if none): a fori binary search,
+    log2(size) rounds of table gathers."""
     from ..obs import span as _span
 
-    mode = resolve("CAUSE_TPU_SEARCH")
-    if mode in ("matrix", "matrix-table"):
-        with _span("weave.search", strategy=mode, site="table",
-                   size=int(size)):
-            le = _le(kh[None, :], kl[None, :], qh[:, None], ql[:, None])
-            return jnp.sum(le, axis=1).astype(jnp.int32) - 1
-
-    with _span("weave.search", strategy="binary", site="table",
-               size=int(size)):
+    with _span("weave.search", site="table", size=int(size)):
         steps = 1
         while (1 << steps) < size + 1:
             steps += 1
@@ -164,19 +137,11 @@ def _pair_search_le(kh, kl, qh, ql, size):
         return lo_b
 
 
-def _rides() -> bool:
-    """Whether payloads ride the token and lane sorts (network and
-    matrix sorts) instead of being gathered by permutation."""
-    return resolve("CAUSE_TPU_SORT") in ("bitonic", "pallas", "matrix")
-
-
 def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
                                 sg_min_hi, sg_min_lo, sg_max_hi,
                                 sg_max_lo, sg_len, sg_lane0, sg_dense,
                                 sg_tail_special, sg_valid, sg_vsum,
-                                u_max: int, k_max: int,
-                                stage: str | None = None,
-                                euler: str = "doubling"):
+                                u_max: int, k_max: int):
     """Union + reweave at segment granularity for one replica set.
 
     Node lanes as in v4 (``hi/lo/cci/vclass/valid`` — trees
@@ -191,9 +156,7 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
     token sort + dedupe, ``D`` cause resolution, ``E`` token-width
     ranking + kills, ``F`` expansion to concat lanes. The scopes reach
     the optimized HLO's ``op_name`` metadata (and nothing else), so a
-    device trace's ops can be named by phase. ``stage="_AB"`` is the
-    fused v5f pipeline's handoff after phase B (jaxw5f); ``None``, the
-    default, runs the full kernel.
+    device trace's ops can be named by phase.
 
     The kernel is three pieces: ``_v5_front`` (phases A-D and phase E
     up to the run count), ``_v5_run_tail`` (the rest of phase E, at a
@@ -203,22 +166,18 @@ def _merge_weave_kernel_v5_impl(hi, lo, cci, vclass, valid, seg,
     fr = _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
                    sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
                    sg_tail_special, sg_valid, sg_vsum,
-                   u_max=u_max, k_max=k_max, stage=stage)
-    if stage == "_AB":
-        return fr
-    delta_u, vict_tail = _v5_run_tail(fr, k_max, euler)
+                   u_max=u_max, k_max=k_max)
+    delta_u, vict_tail = _v5_run_tail(fr, k_max)
     return _v5_expand(fr, delta_u, vict_tail)
 
 
 def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
               sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
-              sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int,
-              stage: str | None = None):
+              sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int):
     """Phases A-D and the token-width head of phase E for one row, up
     to the run table's inputs (``rs_cum``, ``n_runs``, ``head_tok``).
-    Returns a dict of the arrays the run tail and phase F read, or the
-    ``stage="_AB"`` handoff. ``k_max`` sets ``overflow_k`` and the width
-    of ``head_tok``."""
+    Returns a dict of the arrays the run tail and phase F read.
+    ``k_max`` sets ``overflow_k`` and the width of ``head_tok``."""
     # ================= A. segment ordering + explode/dedupe =========
     with jax.named_scope("v5.A"):
         N = hi.shape[0]
@@ -228,7 +187,7 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
 
         kh = jnp.where(sg_valid, sg_min_hi, BIG)
         kl = jnp.where(sg_valid, sg_min_lo, BIG)
-        s_mh, s_ml, s_src = sort_pairs((kh, kl, sidx), num_keys=2)
+        s_mh, s_ml, s_src = _sort((kh, kl, sidx), num_keys=2)
         s_Mh = take1d(sg_max_hi, s_src)
         s_Ml = take1d(sg_max_lo, s_src)
         s_va = take1d(sg_valid, s_src)
@@ -273,15 +232,13 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
         # per-group interval tables (twins share min/max by construction).
         # Scatter indices: group ordinals at group starts (strictly
         # increasing), everything else dumped into its own spread slot past
-        # S — unique by construction, so the scatter needs no duplicate
-        # handling (annotated under CAUSE_TPU_SCATTER=hint).
+        # S — unique by construction.
         is_start = grp_start & s_va
         gsl = jnp.where(is_start, grp, S + sidx)
-        uniq = _hint_kw(unique=True)
 
         def _gtable(vals, fill):
             return jnp.full(2 * S, fill, jnp.int32).at[gsl].set(
-                jnp.where(is_start, vals, fill), **uniq)[:S]
+                jnp.where(is_start, vals, fill))[:S]
 
         g_mh = _gtable(s_mh, BIG)
         g_ml = _gtable(s_ml, BIG)
@@ -305,7 +262,7 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
         # group tables for the stabbed group: len/tail-specialness of its
         # representative member (first of group; twins agree)
         rep = jnp.full(2 * S, 0, jnp.int32).at[gsl].set(
-            jnp.where(is_start, sidx, 0), **uniq)[:S]
+            jnp.where(is_start, sidx, 0))[:S]
         rep_pg = take1d(rep, pgc)
         r_len = take1d(s_len, rep_pg)
         r_tsp = take1d(s_tsp, rep_pg)
@@ -357,7 +314,7 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
         # a twin-DROPPED segment copy (tree B's own copy of the shared
         # base) must resolve to the KEPT twin's token: group-start fill
         # gsp redirects any twin member to its group's first (kept) member.
-        inv_s = jnp.zeros(S, jnp.int32).at[s_src].set(sidx, **uniq)
+        inv_s = jnp.zeros(S, jnp.int32).at[s_src].set(sidx)
         seg_expl_sorted = s_va & explode
         gsp = lax.cummax(jnp.where(grp_start, sidx, -1))
 
@@ -371,47 +328,15 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
                     + jnp.where(ex, pc - take1d(sg_lane0, m), 0)
                     ).astype(jnp.int32)
 
-        if stage == "_AB":
-            # internal handoff for the fused v5f pipeline (jaxw5f): every
-            # phase-A/B product the token kernels consume, plus the
-            # coverage inputs the F glue needs. Not a profiling stage —
-            # returns a namespace of traced arrays, so only jaxw5f calls
-            # it (inside its own jit), never the jitted entry points.
-            from types import SimpleNamespace
-
-            return SimpleNamespace(
-                t_hi=t_hi, t_lo=t_lo, t_len=t_len, t_vc=t_vc,
-                t_tsp=t_tsp, t_lane=t_lane, token_of_lane=token_of_lane,
-                overflow_u=overflow_u, survive=survive, inv_s=inv_s,
-                uidx=uidx)
-
     # ================= C. sort tokens, dedupe =======================
     with jax.named_scope("v5.C"):
-        # With a network sort (bitonic/pallas) the payload fields RIDE the
-        # sort — one roll+select per stage each, all streaming — instead of
-        # five post-sort permutation gathers (the expensive primitive the
-        # strategy exists to avoid). The matrix rank-sort rides too: its
-        # payload apply is a streaming rowgather per operand, so carrying
-        # payloads keeps a sort=matrix-only A/B free of per-element
-        # gathers. With the default comparator sort, extra variadic
-        # operands slow the comparator, so the gather form stays.
-        # Identical results either way: same keys, same implicit-iota
-        # stability, and payload-carry == gather-by-permutation.
-        su_src_in = uidx
-        if _rides():
-            (st_hi, st_lo, t_src, sv_len, sv_vc, sv_tsp_i,
-             sv_lane) = sort_pairs(
-                (t_hi, t_lo, su_src_in, t_len, t_vc,
-                 t_tsp.astype(jnp.int32), t_lane), num_keys=2)
-            sv_tsp = sv_tsp_i.astype(bool)
-            sv_tail_lane = sv_lane + sv_len - 1  # == permuted t_tail_lane
-        else:
-            st_hi, st_lo, t_src = sort_pairs((t_hi, t_lo, su_src_in),
-                                             num_keys=2)
-            g = lambda arr: take1d(arr, t_src)  # presort -> sorted order
-            sv_len, sv_vc, sv_tsp = g(t_len), g(t_vc), g(t_tsp)
-            sv_lane, sv_tail_lane = g(t_lane), g(t_tail_lane)
-        inv_t = jnp.zeros(U, jnp.int32).at[t_src].set(uidx, **uniq)
+        # extra variadic operands slow the comparator sort, so the
+        # payloads are gathered by the sort's permutation
+        st_hi, st_lo, t_src = _sort((t_hi, t_lo, uidx), num_keys=2)
+        g = lambda arr: take1d(arr, t_src)  # presort -> sorted order
+        sv_len, sv_vc, sv_tsp = g(t_len), g(t_vc), g(t_tsp)
+        sv_lane, sv_tail_lane = g(t_lane), g(t_tail_lane)
+        inv_t = jnp.zeros(U, jnp.int32).at[t_src].set(uidx)
 
         tva = ~((st_hi == BIG) & (st_lo == BIG))
         sdup = (
@@ -527,7 +452,7 @@ def _v5_front(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
     )
 
 
-def _v5_run_tail(fr, k: int, euler: str):
+def _v5_run_tail(fr, k: int):
     """The run-width half of phase E for one row at static run width
     ``k``: the run tables, the sibling sort, the Euler ranking, and the
     successor kills. Returns ``(delta_u, vict_tail)``: the run bases as
@@ -578,15 +503,10 @@ def _v5_run_tail(fr, k: int, euler: str):
         parent_sort = jnp.where(r_valid & (parent_run >= 0), parent_run, k)
         packed = parent_sort * 2 + (~h_special).astype(jnp.int32)
         kidx_r = jnp.arange(k, dtype=jnp.int32)
-        sord = sort_pairs((packed, -hc, kidx_r), num_keys=2)[2]
+        sord = _sort((packed, -hc, kidx_r), num_keys=2)[2]
         fc, ns = _link_children(sord, parent_sort)
         parent_up = jnp.where(r_valid & (parent_run >= 0), parent_run, -1)
-        if euler == "walk":
-            from .pallas_ops import euler_walk
-
-            base_run = euler_walk(fc, ns, parent_up, run_w, k)
-        else:
-            base_run, _ = _euler_rank(fc, ns, parent_up, run_w)
+        base_run, _ = _euler_rank(fc, ns, parent_up, run_w)
 
         # expand run bases to token bases (node units): delta-scatter at
         # run-head tokens + one cumsum over U, then add within-run offset
@@ -602,14 +522,13 @@ def _v5_run_tail(fr, k: int, euler: str):
         # no collision fix-up needed)
         scat_du = jnp.where(r_valid, hc, U + kidx_r)
         delta_u = jnp.zeros(U + k, jnp.int32).at[scat_du].set(
-            delta.astype(jnp.int32),
-            **_hint_kw(sorted_=True, unique=True))[:U]
+            delta.astype(jnp.int32))[:U]
 
         # preorder-successor run: the run with the next-larger base. base
         # values are node-unit positions (up to N), so find successors by
         # sorting runs on base instead of scattering over node positions.
         bkey = jnp.where(r_valid, base_run, BIG)
-        b_sorted, b_src = sort_pairs((bkey, kidx_r), num_keys=1)
+        b_sorted, b_src = _sort((bkey, kidx_r), num_keys=1)
         succ_in_sorted = jnp.concatenate([
             b_src[1:], jnp.full((1,), -1, jnp.int32)
         ])
@@ -617,8 +536,7 @@ def _v5_run_tail(fr, k: int, euler: str):
             b_sorted[1:] != BIG, jnp.zeros((1,), bool)
         ])
         succ_of = jnp.full(k, -1, jnp.int32).at[b_src].set(
-            jnp.where(succ_valid, succ_in_sorted, -1),
-            **_hint_kw(unique=True)
+            jnp.where(succ_valid, succ_in_sorted, -1)
         )
         succ_run = jnp.where(r_valid, succ_of, -1)
         s_c = jnp.clip(
@@ -654,7 +572,6 @@ def _v5_expand(fr, delta_u, vict_tail):
     N = vclass.shape[0]
     S = sg_len.shape[0]
     U = keep_t.shape[0]
-    uniq = _hint_kw(unique=True)
     conflict = fr["conflict"]
     with jax.named_scope("v5.E"):
         rank_tok = jnp.where(
@@ -669,16 +586,11 @@ def _v5_expand(fr, delta_u, vict_tail):
         # deltas scatter + cumsum reconstructs per-lane values without any
         # full-width gather
         lane_key = jnp.where(keep_t & (rank_tok < N), sv_lane, N)
-        if _rides():  # rank rides the lane sort (see phase C note)
-            lk, tok_at, tb_l = sort_pairs((lane_key, uidx, rank_tok),
-                                          num_keys=1)
-        else:
-            lk, tok_at = sort_pairs((lane_key, uidx), num_keys=1)
-            tb_l = take1d(rank_tok, tok_at)
+        lk, tok_at = _sort((lane_key, uidx), num_keys=1)
+        tb_l = take1d(rank_tok, tok_at)
 
-        # pieces shared by both F backends: per-lane coverage flags, the
-        # token-level kill scatters (victims can duplicate — genuine
-        # scatters, U-width, stay in XLA either way), and the root lane
+        # per-lane coverage flags, the token-level kill scatters (victims
+        # can duplicate — genuine scatters, U-width), and the root lane
         seg_cov = sg_valid & take1d(fr["survive"], fr["inv_s"])
         killed_sc = jnp.zeros(N + 1, bool)
         killed_sc = killed_sc.at[jnp.where(fr["kg"], fr["vict_inrun"], N)
@@ -687,24 +599,6 @@ def _v5_expand(fr, delta_u, vict_tail):
         root_lane = jnp.zeros(N, bool).at[
             jnp.clip(sv_lane[0], 0, N - 1)
         ].set(keep_t[0])
-
-        if resolve("CAUSE_TPU_FPHASE") == "pallas" and N % 128 == 0:
-            # fused tile-window expansion (pallas_fphase): no scatters, no
-            # cumsums — per-tile compare-select windows in VMEM compute
-            # the fills and coverage; visibility is a vectorized second
-            # pass in the same kernel
-            from .pallas_fphase import fphase_expand
-
-            cov_start = jnp.where(seg_cov, sg_lane0, N).astype(jnp.int32)
-            cov_end = jnp.where(
-                seg_cov, sg_lane0 + sg_len, 0).astype(jnp.int32)
-            cs, ce = sort_pairs((cov_start, cov_end), num_keys=1)
-            killed_ext = killed_sc[:N] | root_lane
-            flags = (valid.astype(jnp.int32)
-                     | (killed_ext.astype(jnp.int32) << 1))
-            rank_lane, visible = fphase_expand(
-                lk, tb_l, cs, ce, vclass, seg, flags)
-            return rank_lane, visible, conflict, overflow
 
         tl_l = jnp.where(lk < N, lk, 0)
         ok_l = lk < N
@@ -720,9 +614,8 @@ def _v5_expand(fr, delta_u, vict_tail):
         )
         # kept tokens sit in the sorted prefix with strictly increasing
         # lanes; the rest dump into spread slots past N — sorted + unique
-        # by construction (annotated under CAUSE_TPU_SCATTER=hint)
+        # by construction
         scat = jnp.where(ok_l, tl_l, N + uidx)
-        su_kw = _hint_kw(sorted_=True, unique=True)
         bits = (N - 1).bit_length()
         if 2 * bits <= 30:
             # base and lane are both < N, so their delta streams pack into
@@ -730,20 +623,17 @@ def _v5_expand(fr, delta_u, vict_tail):
             # of two of each (deltas may be negative, but the cumsum is
             # exact and every prefix total is a valid packed (base, lane))
             d_pack = d_base * (1 << bits) + d_lane
-            pack_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-                d_pack, **su_kw)[:N]
+            pack_n = jnp.zeros(N + U, jnp.int32).at[scat].add(d_pack)[:N]
             pack_fill = jnp.cumsum(pack_n)
             base_fill = pack_fill >> bits
             lane_fill = pack_fill & ((1 << bits) - 1)
         else:  # concat width N > 32k (per-tree capacity > 16k): packed
                # pairs would overflow int32
-            base_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-                d_base, **su_kw)[:N]
-            lane_n = jnp.zeros(N + U, jnp.int32).at[scat].add(
-                d_lane, **su_kw)[:N]
+            base_n = jnp.zeros(N + U, jnp.int32).at[scat].add(d_base)[:N]
+            lane_n = jnp.zeros(N + U, jnp.int32).at[scat].add(d_lane)[:N]
             base_fill = jnp.cumsum(base_n)
             lane_fill = jnp.cumsum(lane_n)
-        has_tok = jnp.zeros(N + U, bool).at[scat].set(True, **su_kw)[:N]
+        has_tok = jnp.zeros(N + U, bool).at[scat].set(True)[:N]
         lane_idx = jnp.arange(N, dtype=jnp.int32)
 
         # per-lane coverage flags from the segment tables (marshal order =
@@ -754,10 +644,10 @@ def _v5_expand(fr, delta_u, vict_tail):
         cov_cnt = jnp.zeros(N + 1 + S, jnp.int32)
         cov_cnt = cov_cnt.at[
             jnp.where(seg_cov, sg_lane0, N + 1 + sidx)
-        ].add(1, **uniq)
+        ].add(1)
         cov_cnt = cov_cnt.at[
             jnp.where(seg_cov, sg_lane0 + sg_len, N + 1 + sidx)
-        ].add(-1, **uniq)
+        ].add(-1)
         in_surviving = jnp.cumsum(cov_cnt[:N]) > 0
 
         # surviving-segment lanes take the seg token's base + offset (their
@@ -789,31 +679,27 @@ def _v5_expand(fr, delta_u, vict_tail):
 def merge_weave_kernel_v5(hi, lo, cci, vclass, valid, seg,
                           sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
                           sg_len, sg_lane0, sg_dense, sg_tail_special,
-                          sg_valid, sg_vsum, u_max: int, k_max: int,
-                          stage: str | None = None,
-                          euler: str = "doubling"):
+                          sg_valid, sg_vsum, u_max: int, k_max: int):
     """The v5 segment-union kernel (see ``_merge_weave_kernel_v5_impl``
     for the phase-by-phase contract), traced under an obs span so a
-    bench/harvest trace attributes host TRACE time — where the sort/
-    gather/search strategy spans nest — to the kernel build it
-    belongs to. Runs only at trace time (the body is jit-staged), so
-    the span cost never touches the dispatch path."""
+    trace attributes host TRACE time — where the sort/gather/search
+    spans nest — to the kernel build it belongs to. Runs only at trace
+    time (the body is jit-staged), so the span cost never touches the
+    dispatch path."""
     from ..obs import span as _span
 
     with _span("weave.trace.v5", n=int(hi.shape[-1]),
-               u_max=int(u_max), k_max=int(k_max),
-               stage=stage or "FULL", euler=euler):
+               u_max=int(u_max), k_max=int(k_max), stage="FULL"):
         return _merge_weave_kernel_v5_impl(
             hi, lo, cci, vclass, valid, seg,
             sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
             sg_len, sg_lane0, sg_dense, sg_tail_special,
-            sg_valid, sg_vsum, u_max=u_max, k_max=k_max,
-            stage=stage, euler=euler)
+            sg_valid, sg_vsum, u_max=u_max, k_max=k_max)
 
 
 merge_weave_kernel_v5_jit = jax.jit(
     merge_weave_kernel_v5,
-    static_argnames=("u_max", "k_max", "euler"),
+    static_argnames=("u_max", "k_max"),
 )
 
 
@@ -830,8 +716,7 @@ def run_widths(k_max: int) -> tuple:
 
 def batched_v5(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
                sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
-               sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int,
-               euler: str = "doubling"):
+               sg_tail_special, sg_valid, sg_vsum, u_max: int, k_max: int):
     """The batched v5 body (traced inside a caller's jit): every row's
     front, then the run tail of all rows at the smallest width of
     ``run_widths(k_max)`` that holds the batch's largest run count,
@@ -845,14 +730,11 @@ def batched_v5(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
     from ..obs import span as _span
 
     with _span("weave.trace.v5", n=int(hi.shape[-1]),
-               u_max=int(u_max), k_max=int(k_max), stage="BATCHED",
-               euler=euler):
-        sw = raw_switch_key()
+               u_max=int(u_max), k_max=int(k_max), stage="BATCHED"):
         # the scopes around the row pieces name what XLA keeps of the
         # call boundaries (the pieces name their own ops)
         with jax.named_scope("v5.E"):
-            fr = jax.vmap(partial(_front_row, u_max=u_max, k_max=k_max,
-                                  switches=sw))(
+            fr = jax.vmap(partial(_front_row, u_max=u_max, k_max=k_max))(
                 hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
                 sg_max_hi, sg_max_lo, sg_len, sg_lane0, sg_dense,
                 sg_tail_special, sg_valid, sg_vsum)
@@ -861,8 +743,7 @@ def batched_v5(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
 
         def tail(k):
             def run(fr):
-                delta_u, vict = jax.vmap(
-                    partial(_tail_row, k=k, euler=euler, switches=sw))(fr)
+                delta_u, vict = jax.vmap(partial(_tail_row, k=k))(fr)
                 # pad the per-run kills to k_max with the no-kill lane
                 return delta_u, jnp.pad(vict, ((0, 0), (0, k_max - k)),
                                         constant_values=N)
@@ -874,49 +755,44 @@ def batched_v5(hi, lo, cci, vclass, valid, seg, sg_min_hi, sg_min_lo,
             width = jnp.asarray(ks, jnp.int32)[i]
             delta_u, vict_tail = lax.switch(i, [tail(k) for k in ks], fr)
         with jax.named_scope("v5.F"):
-            rank, visible, conflict, overflow = jax.vmap(
-                partial(_expand_row, switches=sw))(fr, delta_u, vict_tail)
+            rank, visible, conflict, overflow = jax.vmap(_expand_row)(
+                fr, delta_u, vict_tail)
         return rank, visible, conflict, overflow, width
 
 
 # The row pieces of ``batched_v5``, each under its own jit: programs of
 # one row shape (the merge tree's levels, one per batch size) then
 # trace each piece once, where tracing the nine tails again for every
-# batch size doubled a program's trace time. ``switches`` keys each
-# trace on the trace-time strategy snapshot (``raw_switch_key``).
-@partial(jax.jit, static_argnames=("u_max", "k_max", "switches"))
-def _front_row(*lanes, u_max: int, k_max: int, switches: tuple):
+# batch size doubled a program's trace time.
+@partial(jax.jit, static_argnames=("u_max", "k_max"))
+def _front_row(*lanes, u_max: int, k_max: int):
     return _v5_front(*lanes, u_max=u_max, k_max=k_max)
 
 
-@partial(jax.jit, static_argnames=("k", "euler", "switches"))
-def _tail_row(fr, k: int, euler: str, switches: tuple):
-    return _v5_run_tail(fr, k, euler)
+@partial(jax.jit, static_argnames=("k",))
+def _tail_row(fr, k: int):
+    return _v5_run_tail(fr, k)
 
 
-@partial(jax.jit, static_argnames=("switches",))
-def _expand_row(fr, delta_u, vict_tail, switches: tuple):
+@jax.jit
+def _expand_row(fr, delta_u, vict_tail):
     return _v5_expand(fr, delta_u, vict_tail)
 
 
-@partial(jax.jit, static_argnames=("u_max", "k_max", "euler", "with_k"))
+@partial(jax.jit, static_argnames=("u_max", "k_max", "with_k"))
 def batched_merge_weave_v5(hi, lo, cci, vclass, valid, seg,
                            sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
                            sg_len, sg_lane0, sg_dense, sg_tail_special,
                            sg_valid, sg_vsum, u_max: int, k_max: int,
-                           euler: str = "doubling", with_k: bool = False):
+                           with_k: bool = False):
     """Segment-union batch: [B, N] node lanes + [B, S] segment tables
     -> per-replica (rank, visible, conflict, overflow), rank/visible
     indexed by concat lane, plus the run width the batch ran at when
     ``with_k`` (``batched_v5``). ``k_max`` is the run cap, the line
     past which a row overflows; the run tail runs at the smallest
-    width of ``run_widths(k_max)`` that holds the batch's runs.
-    ``euler="walk"`` ranks the contracted forest with the sequential
-    Pallas traversal (pallas_ops.euler_walk) instead of log-depth
-    pointer doubling."""
+    width of ``run_widths(k_max)`` that holds the batch's runs."""
     out = batched_v5(hi, lo, cci, vclass, valid, seg,
                      sg_min_hi, sg_min_lo, sg_max_hi, sg_max_lo,
                      sg_len, sg_lane0, sg_dense, sg_tail_special,
-                     sg_valid, sg_vsum, u_max=u_max, k_max=k_max,
-                     euler=euler)
+                     sg_valid, sg_vsum, u_max=u_max, k_max=k_max)
     return out if with_k else out[:4]

@@ -54,8 +54,8 @@ TOTAL document digest, so per-level convergence evidence costs no
 extra dispatch. ``batched_weave_digest`` is the tree's full-width
 level (first contact / window-budget bounce), through
 ``parallel.wave.dispatch_full_rows``; ``bench.py``'s divergence sweep
-and ``scripts/harvest.py``'s delta gate call it directly, as the
-full-width arm they check the window program against.
+calls it directly, as the full-width arm it checks the window program
+against.
 """
 
 from __future__ import annotations
@@ -85,9 +85,9 @@ def batched_weave_digest(hi, lo, cci, vclass, valid, seg,
     the run cap, the runs rank at the batch's own width) AND the
     per-row convergence digest. Returns ``(rank, visible, digest,
     overflow)``, plus the run width the batch ran at when ``with_k``.
-    This is what the divergence sweep and the harvest digest gate time
-    as the full-weave A/B arm — kernel + digest in one program, the
-    same shape of work a session's full wave performs in two."""
+    This is what the divergence sweep times as the full-weave A/B arm
+    — kernel + digest in one program, the same shape of work a
+    session's full wave performs in two."""
     from ..parallel.mesh import replica_digest
 
     rank, visible, _conflict, overflow, k = batched_v5(

@@ -156,7 +156,7 @@ def forest_lanes(nodes_map: dict, key_rank: Dict[object, int],
         vc[sl] = np.fromiter((vclass_of(v) for _, v in bodies), np.int32,
                              n_real)
 
-        def resolve(cause):
+        def cause_lane(cause):
             if is_id(cause):
                 t = idx_of.get(tuple(cause))
                 if t is None:
@@ -166,7 +166,7 @@ def forest_lanes(nodes_map: dict, key_rank: Dict[object, int],
                 return t
             return key_lane[cause]
 
-        cci[sl] = np.fromiter((resolve(c) for c, _ in bodies), np.int64,
+        cci[sl] = np.fromiter((cause_lane(c) for c, _ in bodies), np.int64,
                               n_real)
         for i, nid in enumerate(ids):
             lane_nodes[base + i] = (nid, bodies[i][0], bodies[i][1])

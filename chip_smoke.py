@@ -29,7 +29,6 @@ from __future__ import annotations
 import argparse
 import functools
 import json
-import os
 import sys
 import tempfile
 import time
@@ -149,21 +148,14 @@ def phase_device(want_chips: int) -> dict:
 
 
 def phase_setup() -> None:
-    """Compile cache, shipped defaults, and the dispatch round trip."""
+    """Compile cache and the dispatch round trip."""
     import jax
     import jax.numpy as jnp
 
-    from cause_tpu import benchgen, switches
+    from cause_tpu import benchgen
 
     cache = benchgen.enable_compile_cache()
-    defaults = os.path.join(os.path.dirname(os.path.abspath(
-        switches.__file__)), "_tpu_defaults.json")
-    # no defaults record is committed, so any present copy is one git
-    # does not track: it would silently re-route the kernels
-    check(not os.path.exists(defaults), "setup",
-          f"{defaults} is present but not committed")
-    print(f"[setup] compile_cache={cache} "
-          f"TPU_DEFAULTS={json.dumps(switches.TPU_DEFAULTS)}", flush=True)
+    print(f"[setup] compile_cache={cache}", flush=True)
 
     step = jax.jit(lambda x: x + 1)
     x = jnp.int32(0)

@@ -24,7 +24,6 @@ import _bootstrap  # noqa: F401  (repo-root sys.path for checkout runs)
 
 import argparse
 import json
-import os
 import time
 
 import numpy as np
@@ -77,29 +76,7 @@ def wave_bench(args):
     from cause_tpu.benchgen import LANE_KEYS5, v5_token_budget
     import jax.numpy as jnp
 
-    # BENCH_KERNEL routes the wave-family kernel (v5 default, v5w
-    # euler walk, v5f fused pipeline) — the SAME knob merge_wave
-    # reads, so the device-kernel split and the whole-wave number in
-    # one log line always measure the same program; every JSON line
-    # below records it
-    wave_kernel = os.environ.get("BENCH_KERNEL", "").strip() or "v5"
-    if wave_kernel not in ("v5", "v5w", "v5f"):
-        raise SystemExit(f"api_bench: BENCH_KERNEL must be "
-                         f"v5/v5w/v5f, got {wave_kernel!r}")
-    if wave_kernel == "v5f":
-        from cause_tpu.weaver.jaxw5f import (
-            batched_merge_weave_v5f)
-
-        def batched_merge_weave_v5(*a, u_max, k_max):
-            return batched_merge_weave_v5f(*a, u_max=u_max,
-                                           k_max=k_max)
-    else:
-        from cause_tpu.weaver.jaxw5 import (
-            batched_merge_weave_v5 as _bm5)
-        _euler = "walk" if wave_kernel == "v5w" else "doubling"
-
-        def batched_merge_weave_v5(*a, u_max, k_max):
-            return _bm5(*a, u_max=u_max, k_max=k_max, euler=_euler)
+    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
 
     B, n_base, n_div = args.wave, args.n_base, args.n_div
 
@@ -234,7 +211,7 @@ def wave_bench(args):
         "host_lt_kernel": bool(t_host < t_kernel),
         "u_max": int(u_max), "overflow_rows": n_over,
         "fallback_pairs": len(res.fallback),
-        "kernel": wave_kernel,
+        "kernel": "v5",
         "platform": platform, "unit": "ms",
     }), flush=True)
 

@@ -281,7 +281,6 @@ def main():
         return run
 
     timed("WHOLE v4", whole("v4", k_max))
-    timed("WHOLE v4 + pallas euler walk", whole("v4w", k_max))
     timed("WHOLE v3", whole("v3", k_max))
 
 

@@ -324,49 +324,7 @@ def gc_round(rng):
     assert c.causal_to_edn(out2) == before, "frontier gc changed doc"
 
 
-def v5f_round(rng):
-    """Round 5: the fused token pipeline vs jaxw5, bit-for-bit, on a
-    random replica pair at a FIXED shape bucket (one compile)."""
-    import jax.numpy as jnp
-
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.arrays import SiteInterner
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-    from cause_tpu.weaver.jaxw5f import merge_weave_kernel_v5f_jit
-
-    cap, u = 64, 128
-    sites = [new_site_id() for _ in range(3)]
-    ra = c.clist(*[str(i) for i in range(rng.randrange(1, 15))])
-    rb = CausalList(ra.ct.evolve(site_id=sites[2]))
-    for _ in range(rng.randrange(0, 12)):
-        ra = ra.insert(_rand_node(rng, ra, sites[0]))
-    for _ in range(rng.randrange(0, 12)):
-        rb = rb.insert(_rand_node(rng, rb, sites[1]))
-    if max(len(ra.ct.nodes), len(rb.ct.nodes)) > cap:
-        return  # stay in the one compiled shape bucket
-    interner = SiteInterner(
-        nid[1] for h in (ra, rb) for nid in h.ct.nodes)
-    rows = []
-    for t, h in enumerate((ra, rb)):
-        na = NodeArrays.from_nodes_map(h.ct.nodes, cap, interner)
-        hi, lo = na.id_lanes()
-        cci = np.where(na.cause_idx >= 0,
-                       na.cause_idx + t * cap, -1).astype(np.int32)
-        rows.append({"hi": hi, "lo": lo, "cci": cci,
-                     "vc": na.vclass, "valid": na.valid})
-    row = {k: np.concatenate([r[k] for r in rows]) for k in rows[0]}
-    v5row = benchgen.v5_inputs(row, cap, s_max=cap)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    got = merge_weave_kernel_v5f_jit(*args, u_max=u, k_max=u)
-    for x, y, name in zip(base, got,
-                          ("rank", "visible", "conflict", "overflow")):
-        assert np.array_equal(np.asarray(x), np.asarray(y)), name
-
-
-ROUNDS = (list_round, wave_round, map_round, base_round, gc_round,
-          v5f_round)
+ROUNDS = (list_round, wave_round, map_round, base_round, gc_round)
 
 
 def _append_soak_ledger_row(args, done: int, seed: int) -> None:

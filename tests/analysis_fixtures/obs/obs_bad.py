@@ -1,6 +1,6 @@
 """Known-bad OBS fixture (lives under an ``obs/`` directory, so the
-obs-package rules apply): reading trace switches breaks the obs-off
-zero-reads contract."""
+obs-package rules apply): obs reading config it does not own breaks
+the obs-off invariance contract."""
 
 import os
 

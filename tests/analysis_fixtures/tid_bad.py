@@ -1,44 +1,24 @@
-"""Known-bad TID fixture: every trace-identity rule must fire here.
+"""Known-bad TID fixture: reads of unregistered CAUSE_TPU_* knobs.
 
 Never imported at runtime — causelint parses it (tests/test_analysis.py
-pins one finding per rule id and a non-zero CLI exit).
+pins the findings and a non-zero CLI exit).
 """
 
 import os
-from functools import lru_cache, partial
 
 import jax
-
-from cause_tpu.switches import resolve
 
 
 @jax.jit
 def traced_reads_unregistered(x):
-    # TID001: CAUSE_TPU_TYPO is in neither TRACE_SWITCHES nor
-    # KNOWN_ENV_KNOBS, read from jit-reachable code
+    # TID001: CAUSE_TPU_TYPO is not in KNOWN_ENV_KNOBS, read from
+    # jit-reachable code
     if os.environ.get("CAUSE_TPU_TYPO"):
         return x + 1
     return x
 
 
-def helper_misuse():
-    # TID001: helper called with an unregistered name (host code —
-    # the misuse is a hazard anywhere)
-    return resolve("CAUSE_TPU_NOT_A_SWITCH")
-
-
-# TID002: a restated switch-name literal outside switches.py
-FLIPS = {"CAUSE_TPU_SORT": "matrix"}
-
-
-@lru_cache(maxsize=4)
-def make_cached_program(k_max):
-    # TID003: lru_cache'd factory of a traced program that reads a
-    # switch at trace time, with no `switches` key parameter
-    @partial(jax.jit, static_argnames=())
-    def step(x):
-        if resolve("CAUSE_TPU_SORT") == "matrix":
-            return x * 2
-        return x
-
-    return step
+def host_reads_retired():
+    # TID001: a retired strategy switch read from host code — no knob
+    # may steer the program unregistered, traced or not
+    return os.environ.get("CAUSE_TPU_SORT", "")

@@ -1,36 +1,14 @@
-"""Known-good TID fixture: the sanctioned patterns stay silent."""
+"""Known-good TID fixture: reads of registered knobs stay silent."""
 
-from functools import lru_cache, partial
+import os
 
-import jax
-
-from cause_tpu.switches import TRACE_SWITCHES, raw_switch_key, resolve
+from cause_tpu.switches import KNOWN_ENV_KNOBS
 
 
-@jax.jit
-def traced_reads_registered(x):
-    # registered switch through the sanctioned helper: clean
-    if resolve("CAUSE_TPU_SORT") == "matrix":
-        return x * 2
-    return x
+def obs_on():
+    return os.environ.get("CAUSE_TPU_OBS", "") == "1"
 
 
-def imported_not_restated():
+def knob_snapshot():
     # iterating the imported registry is the blessed pattern
-    return [k for k in TRACE_SWITCHES]
-
-
-@lru_cache(maxsize=4)
-def make_cached_program(k_max, switches):
-    # the switch snapshot is part of the cache key: clean
-    @partial(jax.jit, static_argnames=())
-    def step(x):
-        if resolve("CAUSE_TPU_SORT") == "matrix":
-            return x * 2
-        return x
-
-    return step
-
-
-def build(k_max):
-    return make_cached_program(k_max, raw_switch_key())
+    return {k: os.environ.get(k, "") for k in KNOWN_ENV_KNOBS}

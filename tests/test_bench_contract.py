@@ -77,27 +77,3 @@ def test_explicit_cpu_rehearsal_contract():
     assert rec["burst_reps"] >= 1
 
 
-@pytest.mark.slow
-def test_certified_defaults_file_on_cpu(tmp_path):
-    """A measured-defaults file (the state after a certifying TPU run)
-    leaves a CPU rehearsal on the XLA-default program: switches ignore
-    TPU defaults off the TPU."""
-    p = tmp_path / "_tpu_defaults.json"
-    p.write_text(json.dumps({
-        "switches": {"CAUSE_TPU_GATHER": "rowgather",
-                     "CAUSE_TPU_SEARCH": "matrix-table",
-                     "CAUSE_TPU_SCATTER": "hint"},
-        "kernel": "v5",
-        "evidence": {"p50_amortized_ms": 1.0, "xla_base_ms": 2.0},
-    }))
-    rec = _bench_cpu({"CAUSE_TPU_DEFAULTS_FILE": str(p)})
-    assert rec["kernel"] == "v5"
-    assert rec["config"] == "default"
-
-
-@pytest.mark.slow
-def test_corrupt_defaults_file_is_ignored(tmp_path):
-    p = tmp_path / "_tpu_defaults.json"
-    p.write_text("{definitely not json")
-    rec = _bench_cpu({"CAUSE_TPU_DEFAULTS_FILE": str(p)})
-    assert rec["kernel"] == "v5"

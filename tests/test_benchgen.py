@@ -81,29 +81,20 @@ def test_batched_shape():
 
 
 def test_scalar_program_cache_hit_is_backend_init_free(monkeypatch):
-    """ADVICE r4 #2: the merge_wave_scalar program-cache lookup runs on
-    host paths (bench.py's parent process, wave assembly) that must
-    never trigger jax backend init — but switches.resolve() consults
-    jax.default_backend() the moment TPU_DEFAULTS is populated. The
-    cache key therefore uses RAW env values (sound: the backend is
-    process-constant after init, so env -> resolved is one mapping per
-    process). This test pins the contract: with TPU_DEFAULTS non-empty
-    and resolve() booby-trapped, a cache hit must still be served."""
+    """The merge_wave_scalar program-cache lookup runs on host paths
+    (bench.py's parent process, wave assembly) that must never trigger
+    jax backend init: with the backend queries booby-trapped, a cache
+    hit must still be served. The key is the program's own static
+    arguments, ``(k_max, kernel, u_max)``."""
+    import jax
+
     from cause_tpu import benchgen as bg_mod
-    from cause_tpu import switches
 
-    for k in switches.TRACE_SWITCHES:
-        monkeypatch.delenv(k, raising=False)
-    monkeypatch.setattr(
-        switches, "TPU_DEFAULTS", {"CAUSE_TPU_SORT": "pallas"})
+    def boom(*a, **k):  # pragma: no cover - the assertion IS the test
+        raise AssertionError("backend queried on the program-cache path")
 
-    def boom(name):  # pragma: no cover - the assertion IS the test
-        raise AssertionError(
-            "switches.resolve() called on the program-cache key path")
-
-    monkeypatch.setattr(switches, "resolve", boom)
-
-    key = (7, "v5", 7, ("",) * len(switches.TRACE_SWITCHES))
+    monkeypatch.setattr(jax, "default_backend", boom)
+    monkeypatch.setattr(jax, "devices", boom)
     seen = []
     sentinel = object()
 
@@ -111,25 +102,21 @@ def test_scalar_program_cache_hit_is_backend_init_free(monkeypatch):
         seen.append(a)
         return sentinel
 
-    monkeypatch.setitem(bg_mod._scalar_programs, key, fake_program)
+    monkeypatch.setitem(bg_mod._scalar_programs, (7, "v5", 7), fake_program)
     out = bg_mod.merge_wave_scalar(1, 2, k_max=7, kernel="v5", u_max=7)
     assert out is sentinel
     assert seen == [(1, 2)]
 
 
-def test_scalar_program_cache_key_xla_collapse(monkeypatch):
-    """The explicit "xla" value and unset share a cache key ONLY for
-    switches without a TPU_DEFAULTS entry (where they resolve
-    identically on every backend). A defaulted switch keeps them
-    distinct: unset applies the default on TPU, "xla" forces the XLA
-    lowering — collapsing those would serve the wrong program."""
-    from cause_tpu import benchgen as bg_mod
-    from cause_tpu import switches
+RETIRED = ("CAUSE_TPU_SORT", "CAUSE_TPU_GATHER", "CAUSE_TPU_SEARCH",
+           "CAUSE_TPU_SCATTER", "CAUSE_TPU_FPHASE")
 
-    for k in switches.TRACE_SWITCHES:
-        monkeypatch.delenv(k, raising=False)
-    monkeypatch.setattr(
-        switches, "TPU_DEFAULTS", {"CAUSE_TPU_SORT": "pallas"})
+
+def test_scalar_program_cache_key_xla_collapse(monkeypatch):
+    """Every value of the retired strategy variables, "xla" included,
+    collapses onto the one key: the environment is no part of a
+    program's identity."""
+    from cause_tpu import benchgen as bg_mod
 
     hits = []
 
@@ -137,45 +124,15 @@ def test_scalar_program_cache_key_xla_collapse(monkeypatch):
         hits.append(a)
         return "sentinel"
 
-    base = ["" for _ in switches.TRACE_SWITCHES]
-    # non-defaulted switch: "xla" collapses onto the unset key
-    monkeypatch.setitem(
-        bg_mod._scalar_programs, (7, "v5", 7, tuple(base)), fake_program)
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "xla")
-    assert bg_mod.merge_wave_scalar(
-        1, k_max=7, kernel="v5", u_max=7) == "sentinel"
-    monkeypatch.delenv("CAUSE_TPU_GATHER")
-
-    # defaulted switch: "xla" must NOT hit the unset entry
-    monkeypatch.setenv("CAUSE_TPU_SORT", "xla")
-    si = switches.TRACE_SWITCHES.index("CAUSE_TPU_SORT")
-    distinct = list(base)
-    distinct[si] = "xla"
-    probe = []
-    monkeypatch.setitem(
-        bg_mod._scalar_programs, (7, "v5", 7, tuple(distinct)),
-        lambda *a: probe.append(a) or "forced-xla")
-    assert bg_mod.merge_wave_scalar(
-        1, k_max=7, kernel="v5", u_max=7) == "forced-xla"
-    assert len(hits) == 1 and len(probe) == 1
-
-
-def test_raw_switch_key_matches_program_cache_shape(monkeypatch):
-    """merge_wave_scalar (and the mesh sharded-step caches) key on
-    switches.raw_switch_key(): one raw_key value per TRACE_SWITCHES
-    member, in registry order — the exact tuple the cache-hit tests
-    above construct by hand. Pins the helper so the two can't drift."""
-    from cause_tpu import switches
-
-    for k in switches.TRACE_SWITCHES:
-        monkeypatch.delenv(k, raising=False)
-    assert switches.raw_switch_key() == ("",) * len(
-        switches.TRACE_SWITCHES)
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    key = switches.raw_switch_key()
-    gi = switches.TRACE_SWITCHES.index("CAUSE_TPU_GATHER")
-    assert key[gi] == "rowgather"
-    assert all(v == "" for i, v in enumerate(key) if i != gi)
+    monkeypatch.setitem(bg_mod._scalar_programs, (7, "v5", 7), fake_program)
+    for value in ("", "xla", "matrix", "pallas"):
+        for name in RETIRED:
+            monkeypatch.setenv(name, value)
+        assert bg_mod.merge_wave_scalar(
+            1, k_max=7, kernel="v5", u_max=7) == "sentinel"
+    assert len(hits) == 4
+    assert set(bg_mod._scalar_programs) >= {(7, "v5", 7)}
+    assert all(len(k) == 3 for k in bg_mod._scalar_programs)
 
 
 def test_compile_cache_placement(monkeypatch, tmp_path):

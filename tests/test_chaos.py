@@ -44,7 +44,7 @@ from cause_tpu.ids import new_site_id
 from cause_tpu.obs import semantic
 from cause_tpu.parallel import merge_wave, recovery
 from cause_tpu.parallel.session import FleetSession
-from cause_tpu.switches import TRACE_SWITCHES, raw_key
+from cause_tpu.benchgen import _scalar_programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -102,7 +102,7 @@ def test_chaos_off_is_invariant(tmp_path):
     keys after a full sync + wave + session pass."""
     out = str(tmp_path / "never.jsonl")
     obs.configure(enabled=False, out=out)
-    key_before = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_before = sorted(_scalar_programs)
 
     assert chaos.enabled() is False
     base = _base()
@@ -129,7 +129,7 @@ def test_chaos_off_is_invariant(tmp_path):
     assert not os.path.exists(out)
     assert sync.quarantined() == frozenset()
     assert not sync.any_quarantined()
-    key_after = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_after = sorted(_scalar_programs)
     assert key_after == key_before
 
 

@@ -28,7 +28,7 @@ from cause_tpu.obs import costmodel, ledger
 from cause_tpu.obs import semantic
 from cause_tpu.parallel import merge_wave
 from cause_tpu.parallel.session import FleetSession
-from cause_tpu.switches import TRACE_SWITCHES, raw_key
+from cause_tpu.benchgen import _scalar_programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -83,7 +83,7 @@ def test_obs_off_is_invariant(tmp_path):
     byte-identical."""
     out = str(tmp_path / "never.jsonl")
     obs.configure(enabled=False, out=out)
-    key_before = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_before = sorted(_scalar_programs)
 
     base = _fleet_base()
     a, b = _replica_pair(base)
@@ -107,7 +107,7 @@ def test_obs_off_is_invariant(tmp_path):
     assert costmodel._PROGRAMS == {}
     assert costmodel._PENDING_OPS == {}
     assert costmodel._PENDING_BAGS == {}
-    key_after = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_after = sorted(_scalar_programs)
     assert key_after == key_before
 
 

@@ -1,12 +1,11 @@
 """cause_tpu.obs.devprof — device-program telemetry.
 
 Pins the PR-4 tentpole contract: cost_analysis capture once per
-compiled program (CPU-lowered here), the switch-aware program-identity
-keying of the emitted events, gauge streaming for the memory samples,
-and — load-bearing, like
-test_obs.py's disabled-mode pins — that with obs OFF devprof records
-nothing, reads no TRACE_SWITCHES env vars, and leaves the
-program-cache values exactly what they were pre-devprof (plain jit
+compiled program (CPU-lowered here), the program-identity keying of
+the emitted events, gauge streaming for the memory samples, and —
+load-bearing, like test_obs.py's disabled-mode pins — that with obs
+OFF devprof records nothing, reads no CAUSE_TPU_* env vars, and leaves
+the program-cache values exactly what they were pre-devprof (plain jit
 programs, not wrappers).
 """
 
@@ -18,7 +17,6 @@ import pytest
 from cause_tpu import obs
 from cause_tpu.obs import core as obs_core
 from cause_tpu.obs import devprof
-from cause_tpu.switches import TRACE_SWITCHES
 
 
 @pytest.fixture(autouse=True)
@@ -67,7 +65,7 @@ def test_disabled_devprof_records_nothing(monkeypatch):
     assert devprof.sample_device_memory("nowhere") == {}
     assert devprof.arena_footprint(object()) == {}
     assert obs.events() == []
-    assert not (set(read) & set(TRACE_SWITCHES)), read
+    assert not [k for k in read if str(k).startswith("CAUSE_TPU_")], read
 
 
 def test_disabled_program_cache_stores_plain_jit_programs(monkeypatch):
@@ -88,7 +86,7 @@ def test_disabled_program_cache_stores_plain_jit_programs(monkeypatch):
     u = int(benchgen.v5_token_budget(v5batch))
     benchgen.merge_wave_scalar(*args, k_max=u, kernel="v5", u_max=u)
     (key,) = benchgen._scalar_programs
-    assert key == (u, "v5", u, ("",) * len(TRACE_SWITCHES))
+    assert key == (u, "v5", u)
     program = benchgen._scalar_programs[key]
     assert not isinstance(program, devprof._ProfiledProgram)
     assert obs.events() == []
@@ -123,14 +121,19 @@ def test_cost_capture_on_cpu_lowered_program():
 
 
 def test_program_event_keyed_by_switch_identity(monkeypatch):
+    """The program event is keyed by the program's identity alone: the
+    ``meta`` its cache key carries, and nothing from the environment
+    (a retired strategy variable leaves no mark)."""
     obs.configure(enabled=True)
     monkeypatch.setenv("CAUSE_TPU_SORT", "matrix")
     f = _toy_program()
-    prof = devprof.profile_program(f, (np.ones(4, np.float32),))
+    prof = devprof.profile_program(f, (np.ones(4, np.float32),),
+                                   kernel="toy", u_max=8)
     assert prof is not None
     (ev,) = [e for e in obs.events()
              if e.get("name") == "devprof.program"]
-    assert ev["fields"]["switches"] == {"CAUSE_TPU_SORT": "matrix"}
+    assert set(ev["fields"]) == {"compile_ms", "cost", "kernel", "u_max"}
+    assert (ev["fields"]["kernel"], ev["fields"]["u_max"]) == ("toy", 8)
 
 
 def test_program_cache_capture_once_per_program(monkeypatch):
@@ -165,7 +168,7 @@ def test_program_cache_capture_once_per_program(monkeypatch):
     assert snap.get("program_cache.hit") == 1
     # the cached value is the profiled wrapper (identity keys unchanged)
     (key,) = benchgen._scalar_programs
-    assert key == (u, "v5", u, ("",) * len(TRACE_SWITCHES))
+    assert key == (u, "v5", u)
     assert isinstance(benchgen._scalar_programs[key],
                       devprof._ProfiledProgram)
 

@@ -27,7 +27,7 @@ from cause_tpu.gc import compact
 from cause_tpu.ids import new_site_id
 from cause_tpu.obs import fleet, semantic
 from cause_tpu.parallel import merge_wave
-from cause_tpu.switches import TRACE_SWITCHES, raw_key
+from cause_tpu.benchgen import _scalar_programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -85,7 +85,7 @@ def test_obs_off_is_invariant(tmp_path):
     byte-identical."""
     out = str(tmp_path / "never.jsonl")
     obs.configure(enabled=False, out=out)
-    key_before = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_before = sorted(_scalar_programs)
 
     base = _fleet_base()
     a, b = _replica_pair(base)
@@ -102,7 +102,7 @@ def test_obs_off_is_invariant(tmp_path):
     assert not os.path.exists(out)
     assert semantic.observe_wave("u", [1], [True]) is None
     assert semantic._MON == {}  # no monitor state accumulates
-    key_after = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_after = sorted(_scalar_programs)
     assert key_after == key_before
 
 

@@ -90,183 +90,6 @@ def test_v5_tiny_pair_parity():
     assert np.array_equal(np.asarray(v), vis1)
 
 
-def test_v5w_walk_parity_tiny():
-    """euler="walk" (sequential Pallas traversal, interpret mode on
-    CPU) must rank the v5 token forest identically to the
-    pointer-doubling default."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    got_d = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    got_w = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u,
-                                      euler="walk")
-    for d, w, name in zip(got_d, got_w,
-                          ("rank", "visible", "conflict", "overflow")):
-        assert np.array_equal(np.asarray(d), np.asarray(w)), name
-
-
-def test_v5_bitonic_sort_parity_tiny(monkeypatch):
-    """CAUSE_TPU_SORT=bitonic must leave the v5 kernel's outputs
-    bit-identical (the network reproduces stable lax.sort order)."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_SORT", "bitonic")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        monkeypatch.delenv("CAUSE_TPU_SORT")
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
-def test_v5_rowgather_parity_tiny(monkeypatch):
-    """CAUSE_TPU_GATHER=rowgather must leave the v5 kernel's outputs
-    bit-identical (streaming row-fetch gather vs XLA per-element)."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        monkeypatch.delenv("CAUSE_TPU_GATHER")
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
-def test_v5_all_switches_parity_tiny(monkeypatch):
-    """rowgather + bitonic + matrix-search + walk combined must stay
-    bit-identical — the 'allstream' configuration the watcher benches
-    on TPU."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    monkeypatch.setenv("CAUSE_TPU_SORT", "bitonic")
-    monkeypatch.setenv("CAUSE_TPU_SEARCH", "matrix")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u,
-                                        euler="walk")
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        for k in ("CAUSE_TPU_GATHER", "CAUSE_TPU_SORT",
-                  "CAUSE_TPU_SEARCH"):
-            monkeypatch.delenv(k)
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
-def test_v5_pallas_sort_parity_tiny(monkeypatch):
-    """CAUSE_TPU_SORT=pallas (the VMEM-resident in-kernel network)
-    must leave the v5 kernel's outputs bit-identical."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_SORT", "pallas")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        monkeypatch.delenv("CAUSE_TPU_SORT")
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
-def test_v5_pallas_allstream_parity_tiny(monkeypatch):
-    """rowgather + pallas-sort + matrix-search + walk combined must
-    stay bit-identical — the round-4 headline candidate config."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    monkeypatch.setenv("CAUSE_TPU_SORT", "pallas")
-    monkeypatch.setenv("CAUSE_TPU_SEARCH", "matrix")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u,
-                                        euler="walk")
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        for k in ("CAUSE_TPU_GATHER", "CAUSE_TPU_SORT",
-                  "CAUSE_TPU_SEARCH"):
-            monkeypatch.delenv(k)
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
-def test_v5_beststream_combined_parity_tiny(monkeypatch):
-    """The EXACT shipped beststream combination (pallas sort +
-    rowgather + matrix-table search + scatter hints + euler walk) —
-    the program bench.py's alt attempt and harvest's BESTSTREAM trace
-    — must stay bit-identical to the default. The individual switches
-    are covered above; this pins the combined trace (payload-riding +
-    annotations interact only here)."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_SORT", "pallas")
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    monkeypatch.setenv("CAUSE_TPU_SEARCH", "matrix-table")
-    monkeypatch.setenv("CAUSE_TPU_SCATTER", "hint")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u,
-                                        euler="walk")
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        for k in ("CAUSE_TPU_GATHER", "CAUSE_TPU_SORT",
-                  "CAUSE_TPU_SEARCH", "CAUSE_TPU_SCATTER"):
-            monkeypatch.delenv(k)
-        merge_weave_kernel_v5_jit.clear_cache()
-
-
 def test_api_merge_parity_all_backends_extend_shape():
     """API-level pair merge on an extend-built (tx-run) tree: jax and
     native must match pure — tiny twin of the suites' big fuzz."""
@@ -287,30 +110,6 @@ def test_api_merge_parity_all_backends_extend_shape():
         )
     )
     assert nat == pure
-
-
-def test_v5_scatter_hint_parity_tiny(monkeypatch):
-    """CAUSE_TPU_SCATTER=hint (unique/sorted scatter annotations over
-    the spread-dump-slot index streams) must leave the v5 kernel's
-    outputs bit-identical."""
-    from cause_tpu.weaver.jaxw5 import merge_weave_kernel_v5_jit
-
-    row = tiny_pair()
-    v5row = benchgen.v5_inputs(row, CAP)
-    u = benchgen.v5_token_budget(v5row)
-    args = [jnp.asarray(v5row[k]) for k in LANE_KEYS5]
-    base = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-    monkeypatch.setenv("CAUSE_TPU_SCATTER", "hint")
-    merge_weave_kernel_v5_jit.clear_cache()
-    try:
-        got = merge_weave_kernel_v5_jit(*args, u_max=u, k_max=u)
-        for b, g, name in zip(base, got,
-                              ("rank", "visible", "conflict",
-                               "overflow")):
-            assert np.array_equal(np.asarray(b), np.asarray(g)), name
-    finally:
-        monkeypatch.delenv("CAUSE_TPU_SCATTER")
-        merge_weave_kernel_v5_jit.clear_cache()
 
 
 def test_graft_entry_compiles_v5_against_pure():

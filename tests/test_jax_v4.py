@@ -175,53 +175,6 @@ def test_v4_hypothesis_random_interactions():
     prop()
 
 
-def test_v4_euler_walk_parity():
-    """The sequential Pallas traversal (euler="walk", interpret mode on
-    CPU) ranks identically to the pointer-doubling default — on pair
-    merges, fuzz trees, and the batched path."""
-    rng = random.Random(0xA11CE)
-    row = benchgen.divergent_pair_lanes(
-        n_base=40, n_div=12, capacity=64, hide_every=3
-    )
-    a4 = tuple(jnp.asarray(row[k]) for k in LANE_KEYS4)
-    k_max = benchgen.estimate_pair_runs(row) + 8
-    od, rd, vd, cd, ovd = jaxw4.merge_weave_kernel_v4(*a4, k_max=k_max)
-    ow, rw, vw, cw, ovw = jaxw4.merge_weave_kernel_v4(
-        *a4, k_max=k_max, euler="walk"
-    )
-    assert not bool(ovd) and not bool(ovw)
-    assert np.array_equal(np.asarray(rd), np.asarray(rw))
-    assert np.array_equal(np.asarray(vd), np.asarray(vw))
-    assert bool(cd) == bool(cw)
-
-    for _ in range(10):
-        cl = c.clist(*"ab")
-        sites = [new_site_id() for _ in range(3)]
-        for _ in range(rng.randrange(3, 20)):
-            cl = cl.insert(rand_node(rng, cl, site_id=rng.choice(sites)))
-        _, a4t, na = tree_args(cl)
-        k = max(8, na.capacity)
-        _, rd, vd, _, _ = jaxw4.merge_weave_kernel_v4(*a4t, k_max=k)
-        _, rw, vw, _, _ = jaxw4.merge_weave_kernel_v4(
-            *a4t, k_max=k, euler="walk"
-        )
-        assert np.array_equal(np.asarray(rd), np.asarray(rw))
-        assert np.array_equal(np.asarray(vd), np.asarray(vw))
-
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=5, n_base=30, n_div=9, capacity=64, hide_every=2
-    )
-    b4 = tuple(jnp.asarray(batch[k]) for k in LANE_KEYS4)
-    km = benchgen.pair_run_budget(batch)
-    _, rd, vd, _, ovd = jaxw4.batched_merge_weave_v4(*b4, k_max=km)
-    _, rw, vw, _, ovw = jaxw4.batched_merge_weave_v4(
-        *b4, k_max=km, euler="walk"
-    )
-    assert not np.asarray(ovd).any() and not np.asarray(ovw).any()
-    assert np.array_equal(np.asarray(rd), np.asarray(rw))
-    assert np.array_equal(np.asarray(vd), np.asarray(vw))
-
-
 def test_v4_conflict_flag():
     """Two lanes sharing an id with different bodies raise the conflict
     flag through v4 exactly as v1."""

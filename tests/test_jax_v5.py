@@ -328,10 +328,9 @@ CAP, S_MAX, U_LADDER = 256, 512, 512
 
 
 @lru_cache(maxsize=None)
-def _fixed_kernel(u_max, k_max, euler):
+def _fixed_kernel(u_max, k_max):
     return jax.jit(jax.vmap(partial(jaxw5.merge_weave_kernel_v5,
-                                    u_max=u_max, k_max=k_max,
-                                    euler=euler)))
+                                    u_max=u_max, k_max=k_max)))
 
 
 def _fork(base):
@@ -410,16 +409,15 @@ def _lane_ids(a, b):
     return ids
 
 
-def _check_ladder(pairs, want_k, u_max=U_LADDER, k_max=U_LADDER,
-                  euler="doubling"):
+def _check_ladder(pairs, want_k, u_max=U_LADDER, k_max=U_LADDER):
     """The batched body runs at ``want_k`` and matches the fixed-width
     kernel bit for bit; rows that did not overflow weave as the pure
     weaver does. Returns the overflow flags."""
     args = _batch(pairs)
     rank, vis, conf, ovf, k = jaxw5.batched_merge_weave_v5(
-        *args, u_max=u_max, k_max=k_max, euler=euler, with_k=True)
+        *args, u_max=u_max, k_max=k_max, with_k=True)
     assert int(k) == want_k
-    fixed = _fixed_kernel(u_max, k_max, euler)(*args)
+    fixed = _fixed_kernel(u_max, k_max)(*args)
     for got, ref in zip((rank, vis, conf, ovf), fixed):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     rank, vis, ovf = np.asarray(rank), np.asarray(vis), np.asarray(ovf)
@@ -479,9 +477,3 @@ def test_runs_past_k_max_still_overflow():
     assert ovf.tolist() == [True, False, False]
 
 
-def test_walk_runs_at_the_batch_width():
-    base, one = _doc(60), _doc(1)
-    rng = random.Random(5)
-    pairs = [(_star(one, 128), _fork(one)),
-             (_typed_anywhere(base, 10, rng), _tail_pastes(base, 12))]
-    assert not _check_ladder(pairs, 256, euler="walk").any()

@@ -1,7 +1,7 @@
 """cause_tpu.obs.lag — the convergence-lag tracer.
 
 Pins the PR-9 contract: obs-off no-op invariance (zero records, zero
-op-registry state, zero env/TRACE_SWITCHES reads, byte-identical
+op-registry state, zero env reads, byte-identical
 program-cache keys), op stamping at the mutation funnel and the sync
 ingest path, resolution against the substrate's own wave/tree digest
 agreement (create→woven at the wave, create→converged at the first
@@ -29,7 +29,7 @@ from cause_tpu.obs import costmodel, lag, semantic
 from cause_tpu.obs.lag import LagHistogram
 from cause_tpu.parallel import merge_wave
 from cause_tpu.parallel.session import FleetSession
-from cause_tpu.switches import TRACE_SWITCHES, raw_key
+from cause_tpu.benchgen import _scalar_programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -86,7 +86,7 @@ def test_obs_off_is_invariant(tmp_path):
     and leaves the program-cache key mapping byte-identical."""
     out = str(tmp_path / "never.jsonl")
     obs.configure(enabled=False, out=out)
-    key_before = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_before = sorted(_scalar_programs)
 
     base = _fleet_base()
     a, b = _replica_pair(base)
@@ -112,7 +112,7 @@ def test_obs_off_is_invariant(tmp_path):
     assert lag._HIST_CONVERGED.count == 0
     assert lag._WINDOW == []
     assert lag.pending_ops() == 0
-    key_after = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_after = sorted(_scalar_programs)
     assert key_after == key_before
 
 

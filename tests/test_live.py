@@ -29,7 +29,7 @@ from cause_tpu.obs.fleet import FleetReducer, fleet_report
 from cause_tpu.obs.lag import LagReducer, lag_summary
 from cause_tpu.obs.perfetto import CountersReducer, \
     merged_final_counters
-from cause_tpu.switches import TRACE_SWITCHES, raw_key
+from cause_tpu.benchgen import _scalar_programs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 R9_STREAM = os.path.join(REPO, "measurements", "obs_lag_r9.jsonl")
@@ -99,7 +99,7 @@ def test_obs_off_is_invariant(tmp_path):
     byte-identical."""
     out = str(tmp_path / "never.jsonl")
     obs.configure(enabled=False, out=out)
-    key_before = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_before = sorted(_scalar_programs)
 
     assert obs.subscribe() is None
     assert live.attach() is None
@@ -115,7 +115,7 @@ def test_obs_off_is_invariant(tmp_path):
     from cause_tpu.obs.core import _STATE
 
     assert _STATE is not None and _STATE.subscribers == ()
-    key_after = tuple(raw_key(k) for k in TRACE_SWITCHES)
+    key_after = sorted(_scalar_programs)
     assert key_after == key_before
 
 

@@ -1,118 +1,14 @@
-"""Mosaic-lowering regression guard for the Pallas kernels.
+"""TPU lowering guard for the shipped programs.
 
-Interpret mode (what the CPU suite runs) accepts programs the real
-Mosaic compiler rejects — the original euler_walk design passed every
-CPU test yet failed TPU lowering with "Cannot store scalars to VMEM".
-``jax.export`` with platforms=["tpu"] runs the Pallas->Mosaic lowering
-from any backend. Lowering is not compiling: the chip's compiler, which
-refuses kernels for VMEM or tiling, runs in tests/test_tpu_compile.py."""
+``jax.export`` with platforms=["tpu"] lowers a program for the TPU from
+any backend, so a jnp construct with no TPU lowering fails here, not on
+the chip. Lowering is not compiling: the chip's compiler runs in
+tests/test_tpu_compile.py."""
 
-import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-
-from cause_tpu.weaver import pallas_ops
-
-
-def _chain_tables(k, n_runs):
-    """A root with a chain of children plus some siblings."""
-    rng = np.random.RandomState(k)
-    parent = np.full(k, -1, np.int32)
-    for i in range(1, n_runs):
-        parent[i] = rng.randint(0, i)
-    w = np.zeros(k, np.int32)
-    w[:n_runs] = rng.randint(1, 5, n_runs)
-    # first-child / next-sibling from the parent table (children in
-    # index order, mirroring _link_children's contract closely enough
-    # for a lowering + smoke-parity test)
-    fc = np.full(k, -1, np.int32)
-    ns = np.full(k, -1, np.int32)
-    last_child = {}
-    for i in range(1, n_runs):
-        p = parent[i]
-        if p in last_child:
-            ns[last_child[p]] = i
-        else:
-            fc[p] = i
-        last_child[p] = i
-    return (jnp.asarray(fc), jnp.asarray(ns), jnp.asarray(parent),
-            jnp.asarray(w))
-
-
-def test_euler_walk_exports_for_tpu(monkeypatch):
-    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
-    fc, ns, parent, w = _chain_tables(256, 40)
-
-    def single(a, b, c, d):
-        return pallas_ops.euler_walk(a, b, c, d, 256)
-
-    jax.export.export(jax.jit(single), platforms=["tpu"])(
-        fc, ns, parent, w)
-
-
-def test_euler_walk_batch_exports_for_tpu(monkeypatch):
-    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
-    fc, ns, parent, w = _chain_tables(256, 40)
-    B = 12  # non-multiple of the 8-row block: exercises padding
-    batch = tuple(jnp.tile(x, (B, 1)) for x in (fc, ns, parent, w))
-
-    def batched(a, b, c, d):
-        return jax.vmap(
-            lambda e, f, g, h: pallas_ops.euler_walk(e, f, g, h, 256)
-        )(a, b, c, d)
-
-    jax.export.export(jax.jit(batched), platforms=["tpu"])(*batch)
-
-
-def test_v5w_kernel_exports_for_tpu(monkeypatch):
-    """The full v5 kernel with euler='walk' must lower for TPU — the
-    exact program bench.py dispatches under BENCH_KERNEL=v5w."""
-    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
-
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5(*a, u_max=u, k_max=u,
-                                      euler="walk")
-
-    jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-
-
-def test_v5_allstream_exports_for_tpu(monkeypatch):
-    """The full streaming configuration (rowgather + bitonic + matrix
-    search) must lower for TPU — the watcher's headline candidate."""
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    monkeypatch.setenv("CAUSE_TPU_SORT", "bitonic")
-    monkeypatch.setenv("CAUSE_TPU_SEARCH", "matrix")
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
-
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5(*a, u_max=u, k_max=u)
-
-    batched_merge_weave_v5.clear_cache()
-    try:
-        jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-    finally:
-        batched_merge_weave_v5.clear_cache()
 
 
 def test_v5_kernel_exports_for_tpu():
@@ -135,168 +31,82 @@ def test_v5_kernel_exports_for_tpu():
     jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
 
 
-def test_walk_parity_vs_doubling_after_redesign():
-    """The SMEM redesign still ranks exactly like _euler_rank."""
-    from cause_tpu.weaver.jaxw import _euler_rank
-
-    fc, ns, parent, w = _chain_tables(128, 31)
-    want, _ = _euler_rank(fc, ns, parent, w)
-    got = pallas_ops.euler_walk(fc, ns, parent, w, 128)
-    assert np.array_equal(np.asarray(want), np.asarray(got))
-    # batched via vmap (the kernels' calling convention)
-    B = 5
-    batch = tuple(jnp.tile(x, (B, 1)) for x in (fc, ns, parent, w))
-    got_b = jax.vmap(
-        lambda a, b, c, d: pallas_ops.euler_walk(a, b, c, d, 128)
-    )(*batch)
-    for r in range(B):
-        assert np.array_equal(np.asarray(want), np.asarray(got_b[r]))
 
 
-def test_v5_scatter_hint_exports_for_tpu(monkeypatch):
-    """The annotated-scatter configuration must lower for TPU."""
-    monkeypatch.setenv("CAUSE_TPU_SCATTER", "hint")
-    from cause_tpu import benchgen
+_BOOL = {"valid", "sg_dense", "sg_tail_special", "sg_valid"}
+_NODE = {"hi", "lo", "cci", "vc", "valid", "seg"}
+
+
+def _specs(B, N, S):
     from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
 
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5(*a, u_max=u, k_max=u)
-
-    batched_merge_weave_v5.clear_cache()
-    try:
-        jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-    finally:
-        batched_merge_weave_v5.clear_cache()
+    lead = () if B is None else (B,)
+    return [jax.ShapeDtypeStruct(lead + ((N,) if k in _NODE else (S,)),
+                                 jnp.bool_ if k in _BOOL else jnp.int32)
+            for k in LANE_KEYS5]
 
 
-def test_v5_beststream_combined_exports_for_tpu(monkeypatch):
-    """The exact shipped beststream combination (pallas sort +
-    rowgather + matrix-table + scatter hints + euler walk) must lower
-    for TPU — the program a window's alt attempt compiles."""
-    from cause_tpu.weaver import pallas_ops, pallas_sort
-
-    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)
-    monkeypatch.setattr(pallas_sort, "_interpret", lambda: False)
-    monkeypatch.setenv("CAUSE_TPU_SORT", "pallas")
-    monkeypatch.setenv("CAUSE_TPU_GATHER", "rowgather")
-    monkeypatch.setenv("CAUSE_TPU_SEARCH", "matrix-table")
-    monkeypatch.setenv("CAUSE_TPU_SCATTER", "hint")
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
-
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5(*a, u_max=u, k_max=u,
-                                      euler="walk")
-
-    batched_merge_weave_v5.clear_cache()
-    try:
-        jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-    finally:
-        batched_merge_weave_v5.clear_cache()
+def _i32(*shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def test_fphase_kernel_exports_for_tpu(monkeypatch):
-    """The fused F-phase expansion (pallas_fphase) must lower via
-    Mosaic: dynamic-start window loads from the transposed tables,
-    sublane-axis reductions, vector stores, and the vectorized
-    visibility pass with jnp.roll."""
-    from cause_tpu.weaver import pallas_fphase
+def _shipped(name):
+    """``[(jitted program, args, static kwargs)]`` of one shipped
+    program at a test shape."""
+    from cause_tpu.parallel import mesh
+    from cause_tpu.parallel.session import _apply_deltas
+    from cause_tpu.parallel.wave import _digest_fn
+    from cause_tpu.weaver import jaxw5, jaxwd
 
-    monkeypatch.setattr(pallas_fphase, "_interpret", lambda: False)
-    rng = np.random.RandomState(5)
-    B, N, U, S = 12, 512, 160, 64  # B pads to 16; U/S pad to 128
-    lk = np.sort(np.stack([
-        rng.choice(N, size=U, replace=False) for _ in range(B)
-    ]), axis=1).astype(np.int32)
-    lk[:, 100:] = N  # sentinel tail
-    tb = rng.randint(0, N, size=(B, U)).astype(np.int32)
-    cs = np.full((B, S), N, np.int32)
-    ce = np.zeros((B, S), np.int32)
-    cs[:, :10] = np.arange(10, dtype=np.int32) * 40
-    ce[:, :10] = cs[:, :10] + 30
-    vc = rng.randint(0, 4, size=(B, N)).astype(np.int32)
-    seg = np.repeat(np.arange(N // 8, dtype=np.int32), 8)[None].repeat(
-        B, 0).astype(np.int32)
-    fl = rng.randint(0, 4, size=(B, N)).astype(np.int32)
-
-    def f(*a):
-        return jax.vmap(pallas_fphase.fphase_expand)(*a)
-
-    jax.export.export(jax.jit(f), platforms=["tpu"])(
-        *(jnp.asarray(x) for x in (lk, tb, cs, ce, vc, seg, fl)))
-
-
-def test_v5_fphase_exports_for_tpu(monkeypatch):
-    """The full v5 program under CAUSE_TPU_FPHASE=pallas lowers for
-    TPU — the exact program the harvest ladder times."""
-    monkeypatch.setenv("CAUSE_TPU_FPHASE", "pallas")
-    from cause_tpu.weaver import pallas_fphase
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5 import batched_merge_weave_v5
-
-    monkeypatch.setattr(pallas_fphase, "_interpret", lambda: False)
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5(*a, u_max=u, k_max=u)
-
-    batched_merge_weave_v5.clear_cache()
-    try:
-        jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-    finally:
-        batched_merge_weave_v5.clear_cache()
+    bm5 = jaxw5.batched_merge_weave_v5
+    window = _specs(4, 64, 16) + [_i32(4, dtype=jnp.uint32), _i32(4)]
+    if name == "merge_wave":
+        return [(bm5, _specs(4, 512, 64),
+                 dict(u_max=256, k_max=256, with_k=True)),
+                (_digest_fn(), [_i32(4, 512)] * 3
+                 + [_i32(4, 512, dtype=jnp.bool_)], {})]
+    if name == "delta_wave":
+        return [(jaxwd.batched_delta_weave, window,
+                 dict(u_max=64, k_max=64))]
+    if name == "tree_full_level":
+        return [(jaxwd.batched_weave_digest, _specs(3, 512, 64),
+                 dict(u_max=512, k_max=512, with_k=True))]
+    if name == "tree_delta_window":
+        return [(jaxwd.batched_delta_weave,
+                 _specs(2, 128, 32) + [_i32(2, dtype=jnp.uint32), _i32(2)],
+                 dict(u_max=128, k_max=128))]
+    if name == "session_wave":
+        cols = ("hi", "lo", "cci", "vc", "valid", "seg")
+        dev = {k: _i32(2, 256, dtype=jnp.bool_ if k == "valid"
+                       else jnp.int32) for k in cols}
+        dl = {k: _i32(2, 2, 8, dtype=jnp.bool_ if k == "valid"
+                      else jnp.int32) for k in cols}
+        return [(bm5, _specs(2, 256, 32),
+                 dict(u_max=128, k_max=128, with_k=True)),
+                (_apply_deltas, [dev, dl, _i32(2, 2), _i32(2, 2), _i32(2),
+                                 _i32(2)], {}),
+                (jaxwd.splice_ranks,
+                 [_i32(2, 256), _i32(2, 256, dtype=jnp.bool_), _i32(2, 64),
+                  _i32(2, 64, dtype=jnp.bool_), _i32(2, 2), _i32(2, 2),
+                  _i32(2)], {})]
+    if name == "weave_arrays_v5":
+        return [(jaxw5.merge_weave_kernel_v5_jit, _specs(None, 256, 64),
+                 dict(u_max=72, k_max=72))]
+    if name == "sharded_v5_step":
+        step = mesh._sharded_step_v5(mesh.make_mesh(4), 128, 128)
+        return [(step, _specs(8, 256, 32), {})]
+    assert name == "map_wave_v5"
+    return [(bm5, _specs(4, 128, 32), dict(u_max=64, k_max=64))]
 
 
-def test_v5f_pipeline_exports_for_tpu(monkeypatch):
-    """The full fused-token-pipeline program (jaxw5f: K1 + K2 +
-    euler_walk + K4 + fphase plus the XLA glue) must lower via Mosaic
-    — the exact program BENCH_KERNEL=v5f dispatches. Covers the
-    in-kernel bitonic networks, MXU identity flips, one-hot chunk
-    gathers, roll-based cumulative ops, window expansion, and the
-    fori row loops with pl.ds I/O in all three new kernels."""
-    from cause_tpu.weaver import pallas_befuse, pallas_fphase
-    from cause_tpu.weaver import pallas_ops as pops
-    from cause_tpu import benchgen
-    from cause_tpu.benchgen import LANE_KEYS5
-    from cause_tpu.weaver.jaxw5f import batched_merge_weave_v5f
-
-    monkeypatch.setattr(pallas_befuse, "_interpret", lambda: False)
-    monkeypatch.setattr(pallas_fphase, "_interpret", lambda: False)
-    monkeypatch.setattr(pops, "_interpret", lambda: False)
-    batch = benchgen.batched_pair_lanes(
-        n_replicas=4, n_base=120, n_div=40, capacity=256, hide_every=8
-    )
-    v5 = benchgen.batched_v5_inputs(batch, 256)
-    u = benchgen.v5_token_budget(v5)
-    args = [jnp.asarray(v5[k]) for k in LANE_KEYS5]
-
-    def f(*a):
-        return batched_merge_weave_v5f(*a, u_max=u, k_max=u)
-
-    batched_merge_weave_v5f.clear_cache()
-    try:
-        jax.export.export(jax.jit(f), platforms=["tpu"])(*args)
-    finally:
-        batched_merge_weave_v5f.clear_cache()
+@pytest.mark.parametrize("name", [
+    "merge_wave", "delta_wave", "tree_full_level", "tree_delta_window",
+    "session_wave", "weave_arrays_v5", "sharded_v5_step", "map_wave_v5"])
+def test_shipped_program_exports_for_tpu(name):
+    """Each shipped program lowers for the TPU as plain XLA: no Mosaic
+    custom call anywhere in it."""
+    for fn, args, kw in _shipped(name):
+        text = jax.export.export(fn, platforms=["tpu"])(
+            *args, **kw).mlir_module()
+        assert "tpu_custom_call" not in text
+        assert "custom_call @tpu" not in text

@@ -188,30 +188,3 @@ def test_digest_invariant_to_input_overlap():
     assert (digest == digest[0]).all()
 
 
-def test_sharded_step_cache_keys_on_switch_config(monkeypatch):
-    """Regression (found by causelint TID003): the lru_cached sharded
-    steps trace CAUSE_TPU_* switches via resolve(), so a cache keyed on
-    (mesh, budgets) alone kept serving the step traced under the OLD
-    switch config after a flip. The raw_switch_key() snapshot is now
-    part of every step's key: a flip must mint a distinct step, and
-    flipping back must hit the original cache entry again."""
-    from cause_tpu.parallel import mesh as pm
-    from cause_tpu.switches import TRACE_SWITCHES, raw_switch_key
-
-    for k in TRACE_SWITCHES:
-        monkeypatch.delenv(k, raising=False)
-    mesh = make_mesh()
-    steps = {
-        "v1": lambda: pm._sharded_step(mesh, 0, "v1", raw_switch_key()),
-        "v4": lambda: pm._sharded_step_v4(mesh, 64, raw_switch_key()),
-        "v5": lambda: pm._sharded_step_v5(mesh, 64, 64, "v5",
-                                          raw_switch_key()),
-    }
-    defaults = {name: make() for name, make in steps.items()}
-    monkeypatch.setenv("CAUSE_TPU_SORT", "bitonic")
-    flipped = {name: make() for name, make in steps.items()}
-    for name in steps:
-        assert flipped[name] is not defaults[name], name
-    monkeypatch.delenv("CAUSE_TPU_SORT")
-    for name, make in steps.items():
-        assert make() is defaults[name], name  # cache hit, not retrace
