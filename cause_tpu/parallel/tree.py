@@ -29,21 +29,25 @@ TREE LEVEL as one device burst —
   the per-level digest fetch synchronizes (the per-level convergence
   evidence the flight recorder records);
 - **the root** materializes ONCE: prefix weave ++ root-window weave
-  (the PR-7 factorization, n-way), with the same append-only node
-  union validation any fold of ``merge`` performs. Intermediate
-  winners never pay host materialization — the O(doc)-per-level
-  Python cost that makes the flat fold slow.
+  (the PR-7 factorization, n-way) after a delta level, the final rank
+  row after a full one, with the same append-only node union
+  validation any fold of ``merge`` performs, run once over every
+  leaf. Intermediate winners never pay host materialization — the
+  O(doc)-per-level Python cost that makes the flat fold slow: a full
+  level's winners stay lane views too (the pair's two views and its
+  rank row), unioned into one view only where the next level runs
+  full width.
 
 A level whose window outgrows ``w_budget`` (or whose establishment
 fails: no shared prefix, tombstoned anchor, out-of-domain causes)
-**bounces** to a full-width batched level — materialize the survivors,
-run the document-width kernel, re-establish — and later levels ride
-delta again. Convergence is bit-identical to the pairwise fold in
-every regime (the weave is a pure function of the node set; pinned in
-tests/test_merge_tree.py), and every level lands ``tree.level`` +
-``wave.digest(source="tree")`` semantic events and a per-level
-``wave.cost`` join (``python -m cause_tpu.obs gap`` renders the
-per-level decomposition).
+**bounces** to a full-width batched level — one union view per
+survivor, the document-width kernel, re-establishment — and later
+levels ride delta again. Convergence is bit-identical to the pairwise
+fold in every regime (the weave is a pure function of the node set;
+pinned in tests/test_merge_tree.py), and every level lands
+``tree.level`` + ``wave.digest(source="tree")`` semantic events and a
+per-level ``wave.cost`` join (``python -m cause_tpu.obs gap`` renders
+the per-level decomposition).
 
 Round count: ceil(log2(n)) levels (odd survivor counts carry a bye
 lane to the next level). This is also the multi-chip seam (ROADMAP
@@ -66,8 +70,7 @@ from ..weaver.arrays import I32_MAX, next_pow2
 from ..weaver.segments import (SEG_LANE_KEYS, _TABLE_DTYPES,
                                concat_seg_tables, tree_segments)
 from . import recovery as _recovery
-from .wave import (WaveResult, _assemble_rows, delta_domain_ok,
-                   dispatch_full_rows)
+from .wave import _assemble_rows, delta_domain_ok, dispatch_full_rows
 
 __all__ = [
     "merge_tree",
@@ -164,11 +167,15 @@ def _merge_sides(x: _Side, y: _Side) -> _Side:
 
 
 class _Sub:
-    """One surviving subtree. ``handle`` is set for materialized
-    survivors (leaves, full-level winners); symbolic delta-level
-    winners carry instead the pooled ``side`` plus the level output
-    that can materialize them on demand (``ranks``/``wcap``/
-    ``sides_lr`` — window ranks stay ON DEVICE until someone asks)."""
+    """One surviving subtree: ``members`` are its leaf handles, ``views``
+    the lane views the levels read. A leaf carries its ``handle``; a
+    winner carries none until it is the root. A full-level winner keeps
+    the pair's two input views and its fetched rank row over them
+    (``ranks``, ``wcap`` = the level's cap, no ``sides_lr``); a later
+    full level collapses them into their one union view. A delta-level
+    winner pools its members' views and carries the pooled ``side``
+    plus the level output that builds the root (``ranks``/``wcap``/
+    ``sides_lr`` — window ranks stay ON DEVICE until the root asks)."""
 
     __slots__ = ("handle", "members", "views", "side", "ranks", "wcap",
                  "sides_lr")
@@ -204,22 +211,69 @@ def _union_nodes(members) -> dict:
     return nodes
 
 
+def _outside_domain():
+    """The error a fleet the device lanes cannot hold raises."""
+    return s.CausalError(
+        "fleet outside the device domain (PackSpec overflow "
+        "or map-shaped tree)",
+        {"causes": {"outside-domain"}},
+    )
+
+
+def _union(sub: _Sub) -> None:
+    """Collapse a winner's views into their one union view, the view a
+    full level reads: counted ``tree.winner.union``."""
+    union = lanecache.union_views_many(sub.views)
+    if union is None:
+        raise _outside_domain()
+    sub.views = [union]
+    obs.counter("tree.winner.union").inc()
+
+
+def _view_of(sub: _Sub):
+    """The one lane view a full level reads for ``sub``. A winner whose
+    union its producing level did not build (a delta winner at a
+    window bounce, a bye carried through a delta level, an injected
+    budget exhaustion) builds it here, under ``tree.materialize``."""
+    if len(sub.views) > 1:
+        with obs.span("tree.materialize", members=len(sub.members)) as sp:
+            _union(sub)
+            sp.set(nodes=sub.views[0].n)
+    return sub.views[0]
+
+
 def _materialize(sub: _Sub, state: Optional[dict]):
-    """A subtree's host handle: the prefix weave ++ its window weave
-    (the PR-7 factorization, n-way) — paid once per materialized
-    subtree, which in the steady state means ONCE, for the root."""
+    """The root's host handle — the one subtree that becomes one: its
+    weave from the final level's ranks (a full-width rank row, or the
+    prefix weave ++ the window weave, the PR-7 factorization n-way),
+    its nodes validated over every leaf."""
     if sub.handle is not None:
         return sub.handle
-    assert state is not None and sub.ranks is not None
     with obs.span("tree.materialize", members=len(sub.members)) as sp:
-        handle = _build_handle(sub, state)
+        if sub.sides_lr is None:
+            weave = _full_weave(sub)
+        else:
+            weave = _window_weave(sub, state)
+        handle = _build_handle(sub, weave)
         sp.set(nodes=len(handle.ct.nodes))
     return handle
 
 
-def _build_handle(sub: _Sub, state: dict):
-    """``_materialize``'s work: the weave from the window ranks, the
-    validated node union and the union lane view, set on ``sub``."""
+def _full_weave(sub: _Sub) -> list:
+    """A full-level winner's weave: its pair's lanes in rank order."""
+    va, vb = sub.views
+    cap = sub.wcap
+    rank_row = sub.ranks
+    keep = np.flatnonzero(rank_row < 2 * cap)
+    order = keep[np.argsort(rank_row[keep], kind="stable")]
+    an, bn = va.arena.nodes, vb.arena.nodes
+    return [an[j] if j < cap else bn[j - cap] for j in order.tolist()]
+
+
+def _window_weave(sub: _Sub, state: dict) -> list:
+    """A delta-level winner's weave: the prefix weave ++ the window
+    weave from its window ranks."""
+    assert state is not None and sub.ranks is not None
     wcap = sub.wcap
     n_w = 2 * wcap
     rw = np.asarray(sub.ranks)
@@ -238,7 +292,12 @@ def _build_handle(sub: _Sub, state: dict):
     v0 = state["prefix_view"]
     weave = [v0.arena.nodes[int(j)] for j in prefix_order]
     weave += [win_nodes[int(i)] for i in order]
+    return weave
 
+
+def _build_handle(sub: _Sub, weave: list):
+    """``_materialize``'s handle: ``weave`` with the validated node
+    union of the leaves and the union lane view, set on ``sub``."""
     nodes = _union_nodes(sub.members)
     union = lanecache.union_views_many(sub.views)
     if union is not None and union.n != len(weave):  # pragma: no cover
@@ -260,15 +319,13 @@ def _build_handle(sub: _Sub, state: dict):
     ct = first.ct.evolve(nodes=nodes, yarns=yarns, weave=weave,
                          lamport_ts=lamport, lanes=union)
     sub.handle = type(first)(ct)
-    sub.members = [sub.handle]
-    sub.views = [union] if union is not None else sub.views
     return sub.handle
 
 
 # ----------------------------------------------------- establishment
 
 
-def _establish(check_views, survivor_views, rank0, vis0, cap) -> Optional[dict]:
+def _establish(check_views, rank0, vis0, cap) -> Optional[dict]:
     """Freeze the fleet delta frontier from a completed full level:
     the shared converged lane prefix across EVERY view that feeds
     later levels, its weave-final anchor (derived from pair 0's output
@@ -276,10 +333,10 @@ def _establish(check_views, survivor_views, rank0, vis0, cap) -> Optional[dict]:
     runs), and the prefix's frozen digest contribution. None when any
     check fails — later levels then run full width (correct, O(doc)).
 
-    ``check_views`` are the level's INPUT views (pair sides + byes):
-    pair 0's rank row indexes input lanes, and the delta domain must
-    hold for every divergent lane that exists anywhere in the fleet.
-    ``survivor_views`` are the views the sides will slice — their
+    ``check_views`` are the level's INPUT views (pair sides + byes'
+    views): pair 0's rank row indexes input lanes, the delta domain
+    must hold for every divergent lane that exists anywhere in the
+    fleet, and every survivor's sides slice these same views — their
     first ``s`` lanes must BE the prefix."""
     from .mesh import mix32_np
 
@@ -288,9 +345,6 @@ def _establish(check_views, survivor_views, rank0, vis0, cap) -> Optional[dict]:
     for v in check_views[1:]:
         sp = min(sp, lanecache.shared_prefix_len(v0, v))
         if sp < 1:
-            return None
-    for v in survivor_views:
-        if lanecache.shared_prefix_len(v0, v) < sp:
             return None
     ra = rank0[:sp]
     rb = rank0[cap:cap + sp]
@@ -305,9 +359,6 @@ def _establish(check_views, survivor_views, rank0, vis0, cap) -> Optional[dict]:
     if int(arena.vclass[anchor]) > 0:
         return None
     for v in check_views:
-        if not delta_domain_ok(v, sp, anchor):
-            return None
-    for v in survivor_views:
         if not delta_domain_ok(v, sp, anchor):
             return None
     keep_a = ra < 2 * cap
@@ -503,29 +554,32 @@ def _delta_level(pairs, state, level, uuid, byes, final):
     return new_subs, digests, stats
 
 
-def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
-    """One full-width level (first contact / bounce): materialize both
-    sides of every pair, run the fused document-width kernel+digest
-    over the whole level in one dispatch, materialize the winners, and
-    (re-)establish the delta frontier for the levels that follow."""
-    handles_pairs = [(_materialize(a, state), _materialize(b, state))
-                     for a, b in pairs]
-    views_pairs = []
-    for ha, hb in handles_pairs:
-        va = lanecache.view_for(ha.ct)
-        vb = lanecache.view_for(hb.ct)
-        if va is not None and vb is not None \
-                and not lanecache.compatible((va, vb)):
-            va = lanecache.build_view(ha.ct.nodes, ha.ct.uuid)
-            vb = lanecache.build_view(hb.ct.nodes, hb.ct.uuid)
-        if va is None or vb is None or not lanecache.compatible(
-                (va, vb)):
-            raise s.CausalError(
-                "fleet outside the device domain (PackSpec overflow "
-                "or map-shaped tree)",
-                {"causes": {"outside-domain"}},
-            )
-        views_pairs.append((va, vb))
+def _side_of_views(views, sp: int, anchor: int) -> _Side:
+    """A subtree's side over all its views: each view's side, merged."""
+    side = _side_of(views[0], sp, anchor)
+    for v in views[1:]:
+        side = _merge_sides(side, _side_of(v, sp, anchor))
+    return side
+
+
+def _outgrows(subs, w_budget: Optional[int]) -> bool:
+    """Whether the subtrees' pooled windows outgrow ``w_budget``."""
+    return w_budget is not None and \
+        1 + max(len(sub.side) for sub in subs) > int(w_budget)
+
+
+def _full_level(pairs, state, level, uuid, byes, bye_subs, final,
+                w_budget):
+    """One full-width level (first contact / bounce): one lane view per
+    side of every pair, the fused document-width kernel+digest over the
+    whole level in one dispatch, and the winners kept symbolic (leaf
+    members, the pair's two views, the rank row). The delta frontier is
+    (re-)established for the levels that follow; a winner's views are
+    unioned only when the next level runs full width too."""
+    views_pairs = [(_view_of(a), _view_of(b)) for a, b in pairs]
+    check_views = [v for vp in views_pairs for v in vp]
+    if not lanecache.compatible(check_views):
+        raise _outside_domain()
     P = len(pairs)
     cap = next_pow2(max(max(va.n, vb.n) for va, vb in views_pairs))
     if obs.enabled():
@@ -533,21 +587,17 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
 
         _cm.wave_begin("tree")
     # nodes: the real lanes of both sides of every pair; merged: the
-    # real lanes of the winners (each pair's node union)
+    # real lanes of the winners (each pair's node union, the lanes its
+    # rank row keeps)
     with obs.span("tree.level", level=level, pairs=P, cap=int(cap),
                   path="full",
-                  nodes=sum(va.n + vb.n for va, vb in views_pairs)) as lsp:
+                  nodes=sum(v.n for v in check_views)) as lsp:
         with obs.span("tree.assemble", level=level):
             lanes = _assemble_rows(views_pairs, cap)
         with obs.span("tree.dispatch", level=level, path="full") as dsp:
             rank, vis, dig, info = dispatch_full_rows(lanes, site="tree")
             dsp.set(u_max=info["u_max"], k=info["k"])
-        res = WaveResult(handles_pairs, views_pairs, cap, rank, vis,
-                         dig, {}, "v5",
-                         digest_valid=np.ones(P, bool))
-        with obs.span("tree.winners", level=level, pairs=P):
-            winners = [res.merged(i) for i in range(P)]
-        lsp.set(merged=sum(len(w.ct.nodes) for w in winners))
+        lsp.set(merged=int((rank[:P] < 2 * cap).sum()))
     obs.counter("tree.level.full").inc()
     delta_ops = sum(
         (va.n - state["s"]) + (vb.n - state["s"])
@@ -565,39 +615,26 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
                       delta_ops=delta_ops,
                       overflow_retries=info["retried"], semantic=sem,
                       path="full", level=level)
-    new_subs = []
-    for w, (va, vb) in zip(winners, views_pairs):
-        wv = lanecache.view_for(w.ct)
-        new_subs.append(_Sub(handle=w, members=[w],
-                             views=[wv] if wv is not None else [va, vb]))
-    # (re-)establish the frontier for the levels that follow — over
-    # the level's INPUT views (the rank row indexes them) plus every
-    # view that survives (winners and byes)
-    check_views = [v for vp in views_pairs for v in vp]
-    survivor_views = []
-    bye_views = []
-    for sub in new_subs:
-        survivor_views.extend(sub.views)
-    for sub in bye_subs:
-        h = _materialize(sub, state)
-        v = lanecache.view_for(h.ct)
-        if v is None:
-            raise s.CausalError(
-                "fleet outside the device domain",
-                {"causes": {"outside-domain"}},
-            )
-        sub.views = [v]
-        bye_views.append(v)
-        survivor_views.append(v)
+    new_subs = [
+        _Sub(members=a.members + b.members, views=[va, vb],
+             ranks=rank[i], wcap=cap)
+        for i, ((a, b), (va, vb)) in enumerate(zip(pairs, views_pairs))
+    ]
+    obs.counter("tree.winner.deferred").inc(P)
+    survivors = new_subs + list(bye_subs)
     new_state = None
-    if len(new_subs) + len(bye_subs) > 1:
+    if len(survivors) > 1:
+        # (re-)establish the frontier for the levels that follow — over
+        # the level's INPUT views (the rank row indexes them) and the
+        # byes' views: every survivor's views are among them
+        bye_views = [v for sub in bye_subs for v in sub.views]
         with obs.span("tree.establish", level=level) as esp:
-            new_state = _establish(check_views + bye_views,
-                                   survivor_views, rank[0], vis[0], cap)
+            new_state = _establish(check_views + bye_views, rank[0],
+                                   vis[0], cap)
             if new_state is not None:
                 sp, anchor = new_state["s"], new_state["anchor"]
-                for sub in new_subs + list(bye_subs):
-                    sub.side = _side_of(sub.views[0], sp, anchor)
+                for sub in survivors:
+                    sub.side = _side_of_views(sub.views, sp, anchor)
             esp.set(ok=int(new_state is not None))
         if new_state is None:
             obs.counter("tree.establish_fail").inc()
@@ -608,6 +645,15 @@ def _full_level(pairs, state, level, uuid, byes, bye_subs, final):
                 _recovery.step("tree", "delta", "full",
                                "establish-fail", uuid=uuid,
                                level=level)
+    # the winners' host work: a next level at full width reads one
+    # view per survivor, so union each symbolic one's views now
+    next_full = len(survivors) > 1 and (
+        new_state is None or _outgrows(survivors, w_budget))
+    with obs.span("tree.winners", level=level, pairs=P):
+        if next_full:
+            for sub in survivors:
+                if len(sub.views) > 1:
+                    _union(sub)
     stats = {"level": level, "pairs": P, "byes": byes, "path": "full",
              "window": int(cap), "delta_ops": int(delta_ops),
              "distinct": len(set(int(d) for d in dig)),
@@ -631,11 +677,7 @@ def _merge_tree_impl(handles, w_budget: Optional[int]):
     for sub in subs:
         v = lanecache.view_for(sub.handle.ct)
         if v is None:
-            raise s.CausalError(
-                "fleet outside the device domain (PackSpec overflow "
-                "or map-shaped tree)",
-                {"causes": {"outside-domain"}},
-            )
+            raise _outside_domain()
         sub.views = [v]
     if not lanecache.compatible([sub.views[0] for sub in subs]):
         rebuilt = [lanecache.build_view(sub.handle.ct.nodes,
@@ -643,10 +685,7 @@ def _merge_tree_impl(handles, w_budget: Optional[int]):
                    for sub in subs]
         if any(v is None for v in rebuilt) or not lanecache.compatible(
                 rebuilt):
-            raise s.CausalError(
-                "fleet outside the device domain",
-                {"causes": {"outside-domain"}},
-            )
+            raise _outside_domain()
         for sub, v in zip(subs, rebuilt):
             sub.views = [v]
     state = None
@@ -663,20 +702,16 @@ def _merge_tree_impl(handles, w_budget: Optional[int]):
                 state is not None
                 and all(sub.side is not None for sub in subs)
             )
-            if use_delta and w_budget is not None:
-                wmax = max(len(sub.side) for sub in subs)
-                if 1 + wmax > int(w_budget):
-                    # mid-tree full-width bounce: the pooled windows
-                    # outgrew the budget — run this level at document
-                    # width and re-establish for the rest (the old
-                    # state stays live: the symbolic survivors still
-                    # materialize through it)
-                    obs.counter("tree.window_bounce").inc()
-                    if obs.enabled():
-                        _recovery.step("tree", "delta", "full",
-                                       "window-budget", uuid=uuid,
-                                       level=level)
-                    use_delta = False
+            if use_delta and _outgrows(subs, w_budget):
+                # mid-tree full-width bounce: the pooled windows
+                # outgrew the budget — run this level at document
+                # width and re-establish for the rest
+                obs.counter("tree.window_bounce").inc()
+                if obs.enabled():
+                    _recovery.step("tree", "delta", "full",
+                                   "window-budget", uuid=uuid,
+                                   level=level)
+                use_delta = False
             if use_delta and _chaos.enabled() \
                     and _chaos.budget_exhaust("tree"):
                 # injected window-budget exhaustion: identical ladder
@@ -694,7 +729,8 @@ def _merge_tree_impl(handles, w_budget: Optional[int]):
                 out = None
             if out is None:
                 new_subs, state, _dig, stats = _full_level(
-                    pairs, state, level, uuid, byes, bye_subs, final)
+                    pairs, state, level, uuid, byes, bye_subs, final,
+                    w_budget)
                 subs = new_subs + bye_subs
             else:
                 new_subs, _dig, stats = out

@@ -24,7 +24,9 @@ Pins the tentpole contract:
   full width (establishment fails, ``tree.establish`` carries
   ``ok=0``), tail appends one full level then delta; ``tree.level``'s
   ``nodes``/``merged`` are the handles' lane counts; a forced token
-  overflow is a counted ``tree.fallback``.
+  overflow is a counted ``tree.fallback``;
+- full-level winners stay symbolic: one host handle, at the root, whose
+  node union still catches a body conflict between any two replicas.
 """
 
 import functools
@@ -36,6 +38,7 @@ import pytest
 import cause_tpu as c
 from cause_tpu import obs
 from cause_tpu.collections import clist as c_list
+from cause_tpu.collections.shared import CausalError
 from cause_tpu.collections.clist import CausalList
 from cause_tpu.ids import new_site_id
 from cause_tpu.obs import costmodel, semantic
@@ -418,9 +421,9 @@ def test_tail_appends_ride_one_full_level_then_delta():
     assert counters["v5.runs.full"] == 1
     assert "v5.runs.narrow" not in counters
     # the root is the one subtree built from its window ranks; its
-    # members are level 0's winners and its bye
+    # members are the replicas themselves
     (mat,) = [e["attrs"] for e in spans if e["name"] == "tree.materialize"]
-    assert mat["members"] == 7 and mat["nodes"] == len(root.ct.nodes)
+    assert mat["members"] == 13 and mat["nodes"] == len(root.ct.nodes)
 
 
 @pytest.mark.parametrize("edits", ["tail", "anywhere"])
@@ -466,3 +469,91 @@ def test_full_levels_rank_runs_below_the_token_budget():
     assert all(d["k"] < d["u_max"] for d in disp), disp
     assert counters["v5.runs.narrow"] == 3
     assert "v5.runs.full" not in counters
+
+
+# ------------------------------------------- symbolic full-level winners
+
+
+def clashing(base, fleet, i, j):
+    """``fleet`` with replica ``j`` replaced by one that mints, on
+    replica ``i``'s site, the id of ``i``'s first op with another body."""
+    out = list(fleet)
+    out[j] = CausalList(base.ct.evolve(
+        site_id=fleet[i].ct.site_id)).conj("clash")
+    return out
+
+
+@pytest.mark.parametrize("edits,j,paths", [
+    ("tail", 1, ["full", "delta", "delta"]),      # meet at level 0
+    ("tail", 2, ["full", "delta", "delta"]),      # meet at a delta level
+    ("anywhere", 2, ["full", "full", "full"]),    # meet at a full level
+])
+def test_body_conflicts_raise_wherever_the_replicas_meet(edits, j, paths):
+    base = make_base(30)
+    if edits == "tail":
+        fleet = make_fleet(base, 8, n_div=3)
+    else:
+        fleet = make_editors(base, 8, 3, seed=8)
+    fleet = clashing(base, fleet, 0, j)
+    with pytest.raises(CausalError) as want:
+        fold(fleet)
+    obs.configure(enabled=True)
+    with pytest.raises(CausalError) as got:
+        tree_mod.merge_tree(fleet)
+    levels = [e["attrs"]["path"] for e in obs.events()
+              if e.get("ev") == "span" and e["name"] == "tree.level"]
+    obs.configure(enabled=False)
+    assert levels == paths
+    with pytest.raises(CausalError) as got_all:
+        c.merge_all(fleet[0], *fleet[1:])
+    for err in (got.value, got_all.value):
+        assert err.info["causes"] == want.value.info["causes"] == \
+            {"append-only", "edits-not-allowed"}
+        assert err.info["existing_node"] == want.value.info["existing_node"]
+
+
+@pytest.mark.parametrize("edits", ["tail", "anywhere", "bounce"])
+def test_full_level_winners_stay_symbolic_until_the_root(edits):
+    base = make_base(30)
+    w_budget = None
+    if edits == "tail":
+        fleet = make_fleet(base, 13, n_div=3, hide_every=2)
+    elif edits == "anywhere":
+        fleet = make_editors(base, 13, 2, seed=13)
+    else:
+        # level 1 rides delta with level 0's third winner as its bye;
+        # level 2's windows outgrow the budget and bounce to full width
+        fleet = make_fleet(base, 6, n_div=2)
+        w_budget = 6
+    obs.configure(enabled=True)
+    root, rep = tree_mod.merge_tree_report(fleet, w_budget=w_budget)
+    spans = [e for e in obs.events() if e.get("ev") == "span"]
+    counters = obs.counters_snapshot()["counters"]
+    obs.configure(enabled=False)
+    assert_identical(root, pure_fold(fleet))
+    paths = [lv["path"] for lv in rep["levels"]]
+    pairs = [lv["pairs"] for lv in rep["levels"]]
+    assert counters["tree.winner.deferred"] == sum(
+        p for p, path in zip(pairs, paths) if path == "full")
+    mats = [e["attrs"] for e in spans if e["name"] == "tree.materialize"]
+    # the root's is the last span the tree closes
+    assert spans[-1]["name"] == "tree.materialize"
+    assert mats[-1] == {"members": len(fleet),
+                        "nodes": len(root.ct.nodes)}
+    if edits == "tail":
+        assert paths == ["full", "delta", "delta", "delta"]
+        assert counters["tree.winner.deferred"] == 6
+        assert "tree.winner.union" not in counters
+        assert len(mats) == 1
+    elif edits == "anywhere":
+        # every winner but the root's feeds a full level: its level
+        # unions its two views, inside that level's tree.winners span
+        assert paths == ["full"] * 4
+        assert counters["tree.winner.union"] == 6 + 3 + 2
+        assert len(mats) == 1
+    else:
+        # the bounce level unions its inputs itself: the delta winner
+        # and the bye carried through the delta level
+        assert paths == ["full", "delta", "full"]
+        assert counters["tree.winner.union"] == 2
+        assert [m["members"] for m in mats] == [4, 2, 6]

@@ -181,7 +181,8 @@ def test_tree_spans_reach_the_profiler_trace(tmp_path, obs_on):
     assert [e["path"] for e in evs["tree.level"]] == \
         ["full", "delta", "delta"]
     assert evs["tree.establish"] == [{"level": 0, "ok": 1}]
-    assert evs["tree.materialize"][-1]["members"] == 3
+    # the root's members are the five replicas themselves
+    assert evs["tree.materialize"][-1]["members"] == 5
     ring = {e["name"] for e in obs.events() if e.get("ev") == "span"}
     if obs_on:
         assert set(TREE_SPANS) <= ring
